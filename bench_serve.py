@@ -459,6 +459,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
+    from lambdagap_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     print(f"building {args.trees}-tree forest "
           f"({args.feats} features, backend={jax.default_backend()})...",
           file=sys.stderr)

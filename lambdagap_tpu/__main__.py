@@ -76,6 +76,6 @@ def _early_distributed_init(argv) -> None:
 
 _early_distributed_init(sys.argv[1:])
 
-from .cli import main  # noqa: E402  (must follow the distributed init)
+from .cli import run  # noqa: E402  (must follow the distributed init)
 
-raise SystemExit(main())
+raise SystemExit(run())

@@ -495,5 +495,15 @@ def main(argv=None) -> int:
     return 0
 
 
+def run() -> int:
+    """Process entry (``python -m lambdagap_tpu``): place the persistent
+    compile cache, then :func:`main`. Kept out of ``main`` so a program
+    (or a test) that calls ``main(argv)`` in-process keeps its own JAX
+    configuration."""
+    from .utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
+    return main()
+
+
 if __name__ == "__main__":
-    raise SystemExit(main())
+    raise SystemExit(run())

@@ -363,8 +363,7 @@ class Config:
     infer_quant: str = "auto"            # threshold/bitset palette code width: auto / u8 / u16 (u8|u16 error instead of widening)
     infer_prune: bool = True             # drop branches no input can reach (exact path-interval analysis)
     infer_merge_trees: bool = True       # trees with identical pruned structure share one traversal
-    infer_node_block_kb: int = 512       # node-table bytes per breadth-first block (the traversal kernel's VMEM working set)
-    infer_row_block: int = 256           # rows per traversal-kernel grid step; 0 = default
+    infer_node_block_kb: int = 512       # node-table bytes per breadth-first block (one traversal walk's working set)
     serve_pack_models: bool = False      # pack resident compiled models into ONE executable; mixed per-tenant batches dispatch once
 
     # -- serve (task=serve / Booster.as_server; docs/serving.md) ----------
@@ -675,7 +674,6 @@ class Config:
              f"unknown infer_quant {self.infer_quant!r}"),
             (self.infer_node_block_kb >= 1,
              "infer_node_block_kb must be >= 1"),
-            (self.infer_row_block >= 0, "infer_row_block must be >= 0"),
             (self.serve_max_batch >= 1, "serve_max_batch must be >= 1"),
             (self.serve_max_delay_ms >= 0, "serve_max_delay_ms must be >= 0"),
             (all(b > 0 for b in self.serve_buckets),

@@ -4,9 +4,9 @@ Runs the serving-shaped artifact :mod:`lambdagap_tpu.infer.compile` emits.
 Where the tensor engine (ops/predict_tensor.py) gathers over the stacked
 TRAINING-shaped node tables — 4-byte thresholds, split-order nodes, one
 flat gather lattice per depth step — this engine walks the compiled form:
-per VMEM-budgeted node block, a Pallas kernel carries a ``[rows, groups]``
-node lattice through the block's breadth-first level slabs, decoding u8/u16
-palette codes back to the exact f32 thresholds in-kernel. Merged trees are
+per node block, a ``[rows, groups]`` node lattice is carried through the
+block's breadth-first level slabs, decoding u8/u16 palette codes back to
+the exact f32 thresholds on the way. Merged trees are
 traversed ONCE per structure group; the per-tree leaf payloads are gathered
 afterwards through the compile-time ``group_of_tree`` map.
 
@@ -26,9 +26,12 @@ model's trees — a mixed FairQueue batch costs one dispatch instead of one
 per tenant. Masked trees contribute an exact ``+0.0``, so each row's scores
 stay value-identical to its model served alone.
 
-Off TPU the kernel runs in Pallas interpret mode (pure XLA semantics, slow
-but exact) like ops/hist_pallas.py — CPU tier-1 parity tests exercise the
-code path the TPU default takes.
+The table walk is plain XLA under the caller's jit on every backend. It
+was first written as a Pallas kernel; Mosaic refuses its data-dependent
+table gathers (``feat[idx]`` with a ``[rows, groups]`` index lattice over
+a 1-D node table: "NotImplementedError: Only 2D gather is supported",
+jax 0.9.0 — Mosaic gathers only ``take_along_axis`` between equal shapes),
+and interpret mode lowered to these same XLA ops anyway.
 """
 from __future__ import annotations
 
@@ -45,30 +48,14 @@ from ..ops.predict import K_ZERO_THRESHOLD, MT_NAN, MT_ZERO
 from .compile import (FLAG_CATEGORICAL, FLAG_DEFAULT_LEFT, FLAG_MT_SHIFT,
                       ForestArtifact)
 
-try:  # pallas is TPU-only at runtime; import-guarded for CPU-only setups
-    from jax.experimental import pallas as pl
-    HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAS_PALLAS = False
-
-
-def _interpret() -> bool:
-    """Mosaic compiles only for TPU; everywhere else the kernel runs in
-    interpret mode (slow, exact — the CPU tier-1 parity path)."""
-    return jax.default_backend() != "tpu"
-
-
-def default_row_block() -> int:
-    return 256
-
-
 # ---------------------------------------------------------------------------
-# traversal kernel: one node block, [row_block, groups] lattice
+# traversal: one node block, [rows, groups] lattice
 # ---------------------------------------------------------------------------
-def _traverse_kernel(x_ref, feat_ref, thr_ref, flags_ref, catc_ref,
-                     left_ref, right_ref, thr_tab_ref, cat_tab_ref,
-                     root_ref, out_ref, *, depth: int, cat_words: int):
-    """Carry every row through every structure group of ONE node block.
+def _traverse_block(x: jax.Array, tables, depth: int) -> jax.Array:
+    """Carry every row through every structure group of ONE node block ->
+    node carry [R, Gb] (every live entry is ``~leaf``; a non-negative
+    survivor means the block's recorded depth was wrong — compile-time
+    invariant, not a runtime case).
 
     Node tables arrive level-major (compile-time BFS packing), so the whole
     lattice's step-d gathers land in the block's depth-d slab — the "one
@@ -77,21 +64,18 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, flags_ref, catc_ref,
     for decision (NaN->0 conversion, missing routing, categorical bitset
     word math); only the node id space differs (block-local breadth-first
     ids, palette-coded thresholds decoded through ``thr_tab``)."""
-    x = x_ref[...]                                     # [RB, F]
-    feat = feat_ref[0].astype(jnp.int32)
-    thr_code = thr_ref[0].astype(jnp.int32)
-    flags = flags_ref[0].astype(jnp.int32)
-    catc = catc_ref[0].astype(jnp.int32)
-    left = left_ref[0]
-    right = right_ref[0]
-    thr_tab = thr_tab_ref[0]
-    cat_bits = cat_tab_ref[...].reshape(-1)            # [C * W] u32
-    root = root_ref[0]                                 # [Gb] i32
-    RB = x.shape[0]
-    node0 = jnp.broadcast_to(root[None, :], (RB, root.shape[0]))
+    (feat, thr_code, flags, catc, left, right, thr_tab, cat_tab,
+     root) = tables                                    # root: [Gb] i32
+    feat = feat.astype(jnp.int32)
+    thr_code = thr_code.astype(jnp.int32)
+    flags = flags.astype(jnp.int32)
+    catc = catc.astype(jnp.int32)
+    cat_words = cat_tab.shape[1]
+    cat_bits = cat_tab.reshape(-1)                     # [C * W] u32
+    node0 = jnp.broadcast_to(root[None, :], (x.shape[0], root.shape[0]))
 
     def body(_, node):
-        idx = jnp.maximum(node, 0)                     # [RB, Gb]
+        idx = jnp.maximum(node, 0)                     # [R, Gb]
         f = feat[idx]
         fl = flags[idx]
         dl = (fl & FLAG_DEFAULT_LEFT) != 0
@@ -116,44 +100,18 @@ def _traverse_kernel(x_ref, feat_ref, thr_ref, flags_ref, catc_ref,
         nxt = jnp.where(go, left[idx], right[idx])
         return jnp.where(node < 0, node, nxt)
 
-    out_ref[...] = lax.fori_loop(0, depth, body, node0.astype(jnp.int32))
+    return lax.fori_loop(0, depth, body, node0.astype(jnp.int32))
 
 
-def _traverse_block(x: jax.Array, tables, depth: int,
-                    row_block: int) -> jax.Array:
-    """One node block over all (padded) rows -> node carry [R, Gb] (every
-    live entry is ``~leaf``; a non-negative survivor means the block's
-    recorded depth was wrong — compile-time invariant, not a runtime
-    case)."""
-    R, F = x.shape
-    root = tables[-1]
-    Gb = root.shape[1]
-    specs = [pl.BlockSpec((row_block, F), lambda i: (i, 0))]
-    for t in tables:
-        specs.append(pl.BlockSpec(t.shape, lambda i, nd=t.ndim: (0,) * nd))
-    return pl.pallas_call(
-        functools.partial(_traverse_kernel, depth=depth,
-                          cat_words=tables[-2].shape[1]),
-        grid=(R // row_block,),
-        in_specs=specs,
-        out_specs=pl.BlockSpec((row_block, Gb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, Gb), jnp.int32),
-        interpret=_interpret(),
-    )(x, *tables)
-
-
-def _traverse_all(x: jax.Array, blocks, depths: Tuple[int, ...],
-                  row_block: int) -> jax.Array:
+def _traverse_all(x: jax.Array, blocks,
+                  depths: Tuple[int, ...]) -> jax.Array:
     """Every node block over every row -> [R, G] node carry (blocks hold
     contiguous group ranges, so concatenation restores group order). All B
-    kernel calls live inside the caller's jit: one executable, one
+    block walks live inside the caller's jit: one executable, one
     dispatch."""
-    R = x.shape[0]
-    Rp = -(-R // row_block) * row_block
-    xp = jnp.pad(x, ((0, Rp - R), (0, 0))) if Rp != R else x
-    outs = [_traverse_block(xp, tb, depths[i], row_block)
+    outs = [_traverse_block(x, tb, depths[i])
             for i, tb in enumerate(blocks)]
-    return jnp.concatenate(outs, axis=1)[:R]
+    return jnp.concatenate(outs, axis=1)
 
 
 def _leaf_values(x: jax.Array, node: jax.Array, group_of_tree: jax.Array,
@@ -218,16 +176,16 @@ def _accumulate(vals: jax.Array, tree_class: jax.Array, carry,
 
 @functools.partial(jax.jit,
                    static_argnames=("depths", "num_class", "early_stop_freq",
-                                    "has_linear", "row_block"))
+                                    "has_linear"))
 def _predict_compiled(x, blocks, group_of_tree, tree_class, leaf,
                       early_stop_margin, *, depths, num_class,
-                      early_stop_freq, has_linear, row_block):
+                      early_stop_freq, has_linear):
     """One compiled forest over one row batch -> [num_class, R] raw f32.
     Every artifact buffer arrives as an ARGUMENT (never closed over), so
     the executable is shared across forests of the same shape instead of
     baking each forest's tables in as constants."""
     R = x.shape[0]
-    node = _traverse_all(x, blocks, depths, row_block)
+    node = _traverse_all(x, blocks, depths)
     vals = _leaf_values(x, node, group_of_tree, leaf, has_linear)
     carry = (jnp.zeros((num_class, R), jnp.float32),
              jnp.zeros(R, dtype=bool), jnp.int32(0))
@@ -236,11 +194,9 @@ def _predict_compiled(x, blocks, group_of_tree, tree_class, leaf,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("depths", "num_class", "has_linear",
-                                    "row_block"))
+                   static_argnames=("depths", "num_class", "has_linear"))
 def _predict_packed(x, row_model, blocks, group_of_tree, tree_class,
-                    tree_model, leaf, *, depths, num_class, has_linear,
-                    row_block):
+                    tree_model, leaf, *, depths, num_class, has_linear):
     """Many packed forests, one mixed row batch, ONE dispatch.
 
     Every row traverses every model's blocks; the mask then zeroes the
@@ -249,7 +205,7 @@ def _predict_packed(x, row_model, blocks, group_of_tree, tree_class,
     scores are value-identical to its model predicted alone (early stop is
     excluded from packs; its tree-count replay is per-model by nature)."""
     R = x.shape[0]
-    node = _traverse_all(x, blocks, depths, row_block)
+    node = _traverse_all(x, blocks, depths)
     vals = _leaf_values(x, node, group_of_tree, leaf, has_linear)
     vals = jnp.where(tree_model[None, :] == row_model[:, None], vals,
                      jnp.float32(0.0))
@@ -268,36 +224,33 @@ def _predict_packed(x, row_model, blocks, group_of_tree, tree_class,
 # ---------------------------------------------------------------------------
 def _device_blocks(buffers) -> Tuple[tuple, Tuple[int, ...]]:
     """Slice an artifact's block-major node tables into per-block device
-    tuples (each table 2-D ``[1, n]`` for kernel-block friendliness;
-    palette dtypes kept narrow — decode happens in-kernel). A node-less
-    block (all member groups are stumps) gets one dead placeholder node:
-    its depth is 0, so the kernel body never gathers it."""
+    tuples (palette dtypes kept narrow — decode happens in the walk). A
+    node-less block (all member groups are stumps) gets one dead
+    placeholder node: its depth is 0, so the walk never gathers it."""
     b = buffers
     lo = np.asarray(b["block_node_lo"])
     glo = np.asarray(b["block_group_lo"])
     depths = tuple(int(d) for d in np.asarray(b["block_depth"]))
-    thr_tab = jnp.asarray(np.asarray(b["thr_table"]).reshape(1, -1))
+    thr_tab = jnp.asarray(b["thr_table"])
     cat_tab = jnp.asarray(b["cat_table"])
     blocks = []
     for i in range(len(depths)):
         s = slice(int(lo[i]), int(lo[i + 1]))
         if s.stop == s.start:
-            feat = jnp.zeros((1, 1), b["node_feat"].dtype)
-            thr = jnp.zeros((1, 1), b["node_thr"].dtype)
-            flags = jnp.zeros((1, 1), np.uint8)
-            catc = jnp.zeros((1, 1), b["node_cat"].dtype)
-            left = jnp.full((1, 1), -1, jnp.int32)
-            right = jnp.full((1, 1), -1, jnp.int32)
+            feat = jnp.zeros(1, b["node_feat"].dtype)
+            thr = jnp.zeros(1, b["node_thr"].dtype)
+            flags = jnp.zeros(1, np.uint8)
+            catc = jnp.zeros(1, b["node_cat"].dtype)
+            left = jnp.full(1, -1, jnp.int32)
+            right = jnp.full(1, -1, jnp.int32)
         else:
-            feat = jnp.asarray(b["node_feat"][s].reshape(1, -1))
-            thr = jnp.asarray(b["node_thr"][s].reshape(1, -1))
-            flags = jnp.asarray(b["node_flags"][s].reshape(1, -1))
-            catc = jnp.asarray(b["node_cat"][s].reshape(1, -1))
-            left = jnp.asarray(b["node_left"][s].reshape(1, -1))
-            right = jnp.asarray(b["node_right"][s].reshape(1, -1))
-        root = jnp.asarray(
-            np.asarray(b["root"][int(glo[i]):int(glo[i + 1])]
-                       ).reshape(1, -1))
+            feat = jnp.asarray(b["node_feat"][s])
+            thr = jnp.asarray(b["node_thr"][s])
+            flags = jnp.asarray(b["node_flags"][s])
+            catc = jnp.asarray(b["node_cat"][s])
+            left = jnp.asarray(b["node_left"][s])
+            right = jnp.asarray(b["node_right"][s])
+        root = jnp.asarray(b["root"][int(glo[i]):int(glo[i + 1])])
         blocks.append((feat, thr, flags, catc, left, right,
                        thr_tab, cat_tab, root))
     return tuple(blocks), depths
@@ -313,8 +266,8 @@ class CompiledForest:
     cache), exactly where the other engines leave it."""
 
     def __init__(self, artifact: ForestArtifact, *,
-                 early_stop_freq: int = 0, early_stop_margin: float = 0.0,
-                 row_block: int = 0) -> None:
+                 early_stop_freq: int = 0,
+                 early_stop_margin: float = 0.0) -> None:
         self.artifact = artifact
         m = artifact.meta
         self.num_class = int(m["num_class"])
@@ -323,8 +276,6 @@ class CompiledForest:
         self.has_linear = bool(m["has_linear"])
         self.early_stop_freq = int(early_stop_freq)
         self._es_margin = float(early_stop_margin)
-        self.row_block = int(row_block) if row_block > 0 \
-            else default_row_block()
         b = artifact.buffers
         self._blocks, self._depths = _device_blocks(b)
         self._group_of_tree = jnp.asarray(b["group_of_tree"])
@@ -346,7 +297,7 @@ class CompiledForest:
              self._leaf, jnp.float32(self._es_margin)),
             dict(depths=self._depths, num_class=self.num_class,
                  early_stop_freq=self.early_stop_freq,
-                 has_linear=self.has_linear, row_block=self.row_block),
+                 has_linear=self.has_linear),
             bucket=int(x.shape[0]), phase="predict")
         return out
 
@@ -392,7 +343,6 @@ class PackedForests:
         self.num_class = max(cf.num_class for cf in cfs)
         self.width = max(cf.width for cf in cfs)
         self.has_linear = any(cf.has_linear for cf in cfs)
-        self.row_block = max(cf.row_block for cf in cfs)
         self._blocks = tuple(blk for cf in cfs for blk in cf._blocks)
         self._depths = tuple(d for cf in cfs for d in cf._depths)
         goff = 0
@@ -418,7 +368,7 @@ class PackedForests:
             x, jnp.asarray(row_model, jnp.int32), self._blocks,
             self._group_of_tree, self._tree_class, self._tree_model,
             self._leaf, depths=self._depths, num_class=self.num_class,
-            has_linear=self.has_linear, row_block=self.row_block)
+            has_linear=self.has_linear)
 
     @property
     def nbytes(self) -> int:
@@ -475,5 +425,5 @@ from ..analysis.ir.contracts import register_program
 
 register_program(
     "engine._predict_compiled", collective_free=True,
-    notes="compiled-forest palette kernel; steady-state predict replays "
+    notes="compiled-forest palette walk; steady-state predict replays "
           "the one trace")

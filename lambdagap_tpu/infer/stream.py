@@ -632,7 +632,7 @@ def _cached_scorer(gb, idx, trees, es_freq, mesh, binned, has_linear,
             if mesh is not None else None)
     key = (gb.generation, len(gb.models), idx[0], idx[-1], len(idx),
            cfg.predict_engine, es_freq, bool(binned), bool(raw_score),
-           geom, cfg.predict_tree_tile, cfg.infer_row_block)
+           geom, cfg.predict_tree_tile)
     cache = getattr(gb, "_pstream_cache", None)
     if cache is None or cache[0] != key:
         gb._pstream_cache = (key, _build_scorer(

@@ -1120,15 +1120,14 @@ class GBDT:
                num_iteration, es_freq,
                float(cfg.pred_early_stop_margin), cfg.infer_quant,
                cfg.infer_prune, cfg.infer_merge_trees,
-               cfg.infer_node_block_kb, cfg.infer_row_block)
+               cfg.infer_node_block_kb)
         cache = getattr(self, "_compiled_cache", None)
         if cache is None or cache[0] != key:
             from ..infer import CompiledForest, compile_forest
             artifact = compile_forest(self, start_iteration, num_iteration)
             self._compiled_cache = (key, CompiledForest(
                 artifact, early_stop_freq=es_freq,
-                early_stop_margin=float(cfg.pred_early_stop_margin),
-                row_block=cfg.infer_row_block))
+                early_stop_margin=float(cfg.pred_early_stop_margin)))
         return self._compiled_cache[1]
 
     def _fast_forest(self, idx, trees):

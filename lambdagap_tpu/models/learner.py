@@ -319,15 +319,10 @@ class SerialTreeLearner:
         'auto' = Pallas on TPU, where Mosaic compiles it; one-hot
         elsewhere. An explicit 'pallas' off-TPU runs the kernel in
         interpret mode — exact but slow, the tier-1 CPU parity path)."""
-        from ..ops.hist_pallas import HAS_PALLAS
         if impl == "auto":
-            return ("pallas" if HAS_PALLAS and jax.default_backend() == "tpu"
-                    else "onehot")
+            return "pallas" if jax.default_backend() == "tpu" else "onehot"
         if impl not in ("onehot", "pallas"):
             log.fatal("tpu_hist_impl must be auto/onehot/pallas, got %r", impl)
-        if impl == "pallas" and not HAS_PALLAS:
-            log.fatal("tpu_hist_impl=pallas but jax.experimental.pallas is "
-                      "unavailable in this jax build")
         return impl
 
     def _resolve_layout(self, config: Config) -> str:

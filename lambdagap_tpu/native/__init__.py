@@ -2,15 +2,18 @@
 
 The reference keeps its performance-critical host IO in C++
 (reference: src/io/parser.cpp, src/io/dataset_loader.cpp); this package is
-the equivalent. Compilation is lazy and cached next to the source; if no
-compiler is available the callers fall back to Python parsing.
+the equivalent. Compilation is lazy and cached next to the source under a
+name keyed on the CONTENT of the tracked ``.cpp`` files and the compile
+command, so a binary built from other sources (a stale one copied along
+with the tree, say) can never be loaded; if no compiler is available the
+callers fall back to Python parsing.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-import tempfile
 from typing import Optional
 
 from ..utils import log
@@ -18,26 +21,40 @@ from ..utils import log
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_SOURCES = ("parser.cpp", "treeshap.cpp", "binner.cpp", "fastpred.cpp",
+            "capi.cpp")
+_COMPILE = ("g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread")
+
+
+def artefact_path() -> str:
+    """``_lg_native.<sha256 of compile command + sources>.so`` beside the
+    sources."""
+    h = hashlib.sha256(" ".join(_COMPILE).encode())
+    for name in _SOURCES:
+        with open(os.path.join(_HERE, name), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, f"_lg_native.{h.hexdigest()[:16]}.so")
+
 
 def _build_lib() -> Optional[str]:
-    here = os.path.dirname(__file__)
-    srcs = [os.path.join(here, "parser.cpp"),
-            os.path.join(here, "treeshap.cpp"),
-            os.path.join(here, "binner.cpp"),
-            os.path.join(here, "fastpred.cpp"),
-            os.path.join(here, "capi.cpp")]
-    out = os.path.join(here, "_lg_native.so")
-    if os.path.exists(out) and all(
-            os.path.getmtime(out) >= os.path.getmtime(s) for s in srcs):
+    out = artefact_path()
+    if os.path.exists(out):
         return out
+    tmp = f"{out[:-3]}.tmp{os.getpid()}.so"
     try:
-        subprocess.run(["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                        "-pthread", *srcs, "-o", out],
+        subprocess.run([*_COMPILE,
+                        *(os.path.join(_HERE, n) for n in _SOURCES),
+                        "-o", tmp],
                        check=True, capture_output=True, timeout=120)
-        return out
-    except (subprocess.SubprocessError, FileNotFoundError) as e:
+        os.replace(tmp, out)      # atomic: a concurrent loader sees all or none
+    except (subprocess.SubprocessError, OSError) as e:
         log.warning("Native build failed (%s); using Python fallback", e)
         return None
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    return out
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

@@ -50,7 +50,9 @@ SCHEMA_VERSION = 1
 # 197 TFLOP/s / 819 GB/s / 16 GiB; v4 275/1228/32; v5p 459/2765/95);
 # ``measured`` False marks placeholders (the CPU container) whose
 # roofline fractions are indicative only — the ledger's flops/bytes stay
-# exact there, which is all the CI gate consumes.
+# exact there, which is all the CI gate consumes. A device_kind no row
+# names gets NO peaks (``peaks()`` says so and ``attribution()`` reports
+# no roofline numbers) — never another device's row.
 _PEAK_TABLE: Tuple[Tuple[Tuple[str, ...], Dict[str, Any]], ...] = (
     (("v5 lite", "v5e"), {"name": "tpu-v5e", "flops": 197e12,
                           "bandwidth": 819e9, "hbm": 16 * 2**30,
@@ -295,7 +297,8 @@ class CostPlane:
     # -- attribution -----------------------------------------------------
     def peaks(self) -> Dict[str, Any]:
         """The active peak row: ``cost_plane_peaks="flops:bw:hbm"``
-        override, else the table row matched on device_kind."""
+        override, else the table row matched on device_kind. An unknown
+        device_kind returns a row whose peaks are ``None``."""
         if self._peaks_override:
             try:
                 f, bw, hbm = (float(x) for x in
@@ -306,23 +309,22 @@ class CostPlane:
                 log.warning("cost plane: bad cost_plane_peaks %r (want "
                             "'flops:bandwidth:hbm_bytes'); using the "
                             "table", self._peaks_override)
-        kind = "cpu"
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind.lower()
-        except Exception as e:  # pragma: no cover - backendless process
-            log.debug("cost plane: no device kind (%s); using cpu row", e)
+        import jax
+        kind = jax.devices()[0].device_kind
         for needles, row in _PEAK_TABLE:
-            if any(n in kind for n in needles):
+            if any(n in kind.lower() for n in needles):
                 return dict(row)
-        return dict(_PEAK_TABLE[-1][1])
+        return {"name": "unknown", "device_kind": kind, "flops": None,
+                "bandwidth": None, "hbm": None, "measured": False}
 
     def attribution(self) -> Dict[str, Any]:
         """Per-phase roofline join: total analytic flops/bytes (entry x
         observed calls) vs the peak table, against the measured wall.
         ``bound`` says which roofline arm dominates; ``roofline_s`` is the
         attainable floor; ``fraction_of_roofline`` = floor / wall (1.0 =
-        the phase runs at the machine's analytic limit)."""
+        the phase runs at the machine's analytic limit). On a device the
+        peak table does not name, the three are absent and the document
+        carries a ``roofline`` note saying why."""
         peaks = self.peaks()
         with self._lock:
             entries = {k: dict(v) for k, v in self.entries.items()}
@@ -338,24 +340,30 @@ class CostPlane:
             agg["bytes"] += e["bytes_accessed"] * n
             agg["calls"] += n
         out: Dict[str, Any] = {"peaks": peaks, "phases": {}}
+        known = peaks["flops"] is not None
+        if not known:
+            out["roofline"] = ("not computed: no peak row for device_kind "
+                               f"{peaks['device_kind']!r}")
         for ph, agg in sorted(phases.items()):
-            t_flop = agg["flops"] / peaks["flops"]
-            t_byte = agg["bytes"] / peaks["bandwidth"]
-            roofline_s = max(t_flop, t_byte)
             rec: Dict[str, Any] = {
                 "flops_total": agg["flops"],
                 "bytes_total": agg["bytes"],
                 "calls": int(agg["calls"]),
-                "bound": "flop" if t_flop >= t_byte else "byte",
-                "roofline_s": round(roofline_s, 6),
             }
             wall = walls.get(ph, {}).get("seconds", 0.0)
             if wall > 0:
                 rec["wall_s"] = round(wall, 6)
-                rec["fraction_of_roofline"] = round(
-                    min(roofline_s / wall, 1.0), 4)
-                rec["fraction_of_roofline_uncapped"] = round(
-                    roofline_s / wall, 4)
+            if known:
+                t_flop = agg["flops"] / peaks["flops"]
+                t_byte = agg["bytes"] / peaks["bandwidth"]
+                roofline_s = max(t_flop, t_byte)
+                rec["bound"] = "flop" if t_flop >= t_byte else "byte"
+                rec["roofline_s"] = round(roofline_s, 6)
+                if wall > 0:
+                    rec["fraction_of_roofline"] = round(
+                        min(roofline_s / wall, 1.0), 4)
+                    rec["fraction_of_roofline_uncapped"] = round(
+                        roofline_s / wall, 4)
             out["phases"][ph] = rec
         return out
 

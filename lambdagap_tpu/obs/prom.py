@@ -299,8 +299,9 @@ def render_costplane(plane=None) -> str:
     name = p + "phase_roofline_seconds"
     w.sample_header(name, "Analytic roofline floor per phase (s)", "gauge")
     for phase, rec in attr["phases"].items():
-        w.sample(name, rec["roofline_s"], {"phase": phase,
-                                           "bound": rec["bound"]})
+        if "roofline_s" in rec:     # absent on a device with no peak row
+            w.sample(name, rec["roofline_s"], {"phase": phase,
+                                               "bound": rec["bound"]})
     name = p + "phase_roofline_fraction"
     w.sample_header(name, "Achieved fraction of the analytic roofline "
                     "(wall-joined phases only)", "gauge")

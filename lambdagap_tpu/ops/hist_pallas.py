@@ -30,8 +30,7 @@ zero-channel. Callers still zero the channels of rows excluded by a
 bagging mask (that information is per-row, not a prefix).
 
 Off TPU the kernel runs in Pallas interpret mode (pure XLA semantics, slow
-but exact), which keeps the tier-1 CPU parity tests honest about the code
-path the TPU default actually takes.
+but exact) — the CPU tier-1 parity path only; on TPU Mosaic compiles it.
 """
 from __future__ import annotations
 
@@ -40,19 +39,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .histogram import split_bf16
 
 HIST_C = 3
 
 # int8 gradient levels fit signed int8: the hard cap on num_grad_quant_bins
 # (config validation names the knob; see exact_accum_limit)
 MAX_QUANT_BINS = 127
-
-try:  # pallas is TPU-only at runtime; import-guarded for CPU-only setups
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAS_PALLAS = False
 
 
 def exact_accum_limit(hist_impl: str) -> int:
@@ -78,6 +74,10 @@ def _interpret() -> bool:
 
 def _hist_kernel(count_ref, bins_ref, gh_ref, out_ref, acc_ref, *,
                  num_bins: int, fblk: int, blk: int, nrb: int):
+    """One ``[row_tile, feature_tile]`` grid cell. The channel dtype picks
+    the arithmetic: bf16 channels -> bf16 one-hot, f32 accumulation;
+    int8 channels (quantized levels) -> int8 one-hot, exact int32
+    accumulation."""
     r = pl.program_id(1)
 
     @pl.when(r == 0)
@@ -94,15 +94,15 @@ def _hist_kernel(count_ref, bins_ref, gh_ref, out_ref, acc_ref, *,
         bins = bins_ref[:].astype(jnp.int32)                # [BLK, FBLK]
         live = count_ref[0] - r * blk
         rmask = lax.broadcasted_iota(jnp.int32, (blk, 1), 0) < live
-        gh = jnp.where(rmask, gh_ref[:], 0)                 # [BLK, 8] bf16
+        gh = jnp.where(rmask, gh_ref[:], 0)                 # [BLK, 8]
         iota_b = lax.broadcasted_iota(jnp.int32, (1, num_bins), 1)
         B = num_bins
         for f in range(fblk):
-            onehot = (bins[:, f:f + 1] == iota_b).astype(jnp.bfloat16)
+            onehot = (bins[:, f:f + 1] == iota_b).astype(gh.dtype)
             acc_ref[:, f * B:(f + 1) * B] += lax.dot_general(
                 gh, onehot,
                 dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [8, B]
+                preferred_element_type=acc_ref.dtype)       # [8, B]
 
     # one HBM flush per [row_tile, feature_tile] grid column
     @pl.when(r == nrb - 1)
@@ -110,29 +110,80 @@ def _hist_kernel(count_ref, bins_ref, gh_ref, out_ref, acc_ref, *,
         out_ref[:] = acc_ref[:]
 
 
+# Block-size bounds Mosaic was shown to take on a v5e (docs/performance.md
+# "Histogram kernel blocks"): the per-feature loop is unrolled, so the
+# feature tile bounds compile time (~0.1 s per feature) as well as VMEM;
+# past ~48k accumulator elements the compiler materializes the whole
+# [BLK, FBLK*B] one-hot at once and the scoped allocation passes 64 MB.
+_MAX_FBLK = 192
+_MAX_ACC_ELEMS = 192 * 256
+_LANES = 128
+
+
 def _pick_blocks(F: int, B: int, P: int):
-    """Row block 1024 (2048 for small feature counts); feature block sized
-    so the VMEM accumulator block [8, FBLK*B] f32 stays ~2 MB."""
+    """Row block 1024 (2048 for small feature counts); the feature tile is
+    the whole feature axis when it fits the bounds above, else one
+    128-lane tile of the bins block (the only narrower tile Mosaic's
+    (8, 128) block rule admits; the feature axis is padded to it)."""
     blk = 2048 if F * B <= 8192 else 1024
     blk = min(blk, max(256, P))
-    fblk = max(1, min(F, (2 * 1024 * 1024 // 4) // (8 * B)))
+    fblk = F if F <= min(_MAX_FBLK, _MAX_ACC_ELEMS // B) else _LANES
     return blk, fblk
 
 
-def _grid_spec(P: int, Fp: int, B: int, blk: int, fblk: int, acc_dtype):
-    return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Fp // fblk, P // blk),
-        in_specs=[
-            pl.BlockSpec((blk, fblk), lambda f, r, c: (r, f),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((blk, 8), lambda f, r, c: (r, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((8, fblk * B), lambda f, r, c: (0, f),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((8, fblk * B), acc_dtype)],
-    )
+def _vmem_limit(blk: int, fblk: int, B: int, bins_itemsize: int) -> int:
+    """Scoped-VMEM request from the block arithmetic: the accumulator, the
+    double-buffered output/bins/channel blocks, and the worst case the
+    compiler was seen to want — every feature's one-hot live at once, one
+    byte per element. Never below Mosaic's 16 MiB default, never above
+    what a v5e core has."""
+    acc = 8 * fblk * B * 4
+    io = 2 * (acc + blk * fblk * bins_itemsize + blk * _LANES * 2)
+    need = acc + io + blk * fblk * B
+    return int(min(max(need * 5 // 4, 16 << 20), 100 << 20))
+
+
+def _hist_call(bins: jax.Array, gh: jax.Array, num_bins: int, count,
+               acc_dtype) -> jax.Array:
+    """Pad to the block grid, run the kernel, return ``[8, F, B]`` channel
+    sums in ``acc_dtype``."""
+    P, F = bins.shape
+    B = num_bins
+    blk, fblk = _pick_blocks(F, B, P)
+    if P % blk != 0:
+        pad = blk - P % blk
+        bins = jnp.pad(bins, ((0, pad), (0, 0)))
+        gh = jnp.pad(gh, ((0, pad), (0, 0)))
+        P += pad
+    Fp = ((F + fblk - 1) // fblk) * fblk
+    if Fp != F:
+        # padded feature columns produce junk histograms, sliced off below
+        bins = jnp.pad(bins, ((0, 0), (0, Fp - F)))
+    count = jnp.asarray([P if count is None else count], jnp.int32)
+
+    out = pl.pallas_call(
+        functools.partial(_hist_kernel, num_bins=B, fblk=fblk, blk=blk,
+                          nrb=P // blk),
+        out_shape=jax.ShapeDtypeStruct((8, Fp * B), acc_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Fp // fblk, P // blk),
+            in_specs=[
+                pl.BlockSpec((blk, fblk), lambda f, r, c: (r, f),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((blk, 8), lambda f, r, c: (r, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((8, fblk * B), lambda f, r, c: (0, f),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((8, fblk * B), acc_dtype)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(blk, fblk, B,
+                                         bins.dtype.itemsize)),
+        interpret=_interpret(),
+    )(count, bins, gh)
+    return out.reshape(8, Fp, B)[:, :F]
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins",))
@@ -150,29 +201,7 @@ def hist_pallas(bins: jax.Array, gh8: jax.Array, num_bins: int,
            compute, so heavily padded calls cost ~DMA only
     Returns f32 [F, B, 3] (sum_grad, sum_hess, count).
     """
-    P, F = bins.shape
-    B = num_bins
-    blk, fblk = _pick_blocks(F, B, P)
-    if P % blk != 0:
-        pad = blk - P % blk
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        gh8 = jnp.pad(gh8, ((0, pad), (0, 0)))
-        P += pad
-    Fp = ((F + fblk - 1) // fblk) * fblk
-    if Fp != F:
-        # padded feature columns produce junk histograms, sliced off below
-        bins = jnp.pad(bins, ((0, 0), (0, Fp - F)))
-    count = jnp.asarray([P if count is None else count], jnp.int32)
-
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel, num_bins=B, fblk=fblk, blk=blk,
-                          nrb=P // blk),
-        out_shape=jax.ShapeDtypeStruct((8, Fp * B), jnp.float32),
-        grid_spec=_grid_spec(P, Fp, B, blk, fblk, jnp.float32),
-        interpret=_interpret(),
-    )(count, bins, gh8)
-
-    out = out.reshape(8, Fp, B)[:, :F]                      # [8, F, B]
+    out = _hist_call(bins, gh8, num_bins, count, jnp.float32)
     sg = out[0] + out[1]
     sh = out[2] + out[3]
     cnt = out[4]
@@ -183,10 +212,8 @@ def pack_gh8(grad: jax.Array, hess: jax.Array, valid: jax.Array) -> jax.Array:
     """Split-precision channel packing for :func:`hist_pallas`."""
     g = jnp.where(valid, grad, 0.0)
     h = jnp.where(valid, hess, 0.0)
-    g_hi = g.astype(jnp.bfloat16)
-    g_lo = (g - g_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    h_hi = h.astype(jnp.bfloat16)
-    h_lo = (h - h_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    g_hi, g_lo = split_bf16(g)
+    h_hi, h_lo = split_bf16(h)
     cnt = valid.astype(jnp.bfloat16)
     zero = jnp.zeros_like(cnt)
     return jnp.stack([g_hi, g_lo, h_hi, h_lo, cnt, zero, zero, zero], axis=1)
@@ -204,34 +231,6 @@ def pack_gh8(grad: jax.Array, hess: jax.Array, valid: jax.Array) -> jax.Array:
 # (larger N/F or full-speed MXU).
 # ---------------------------------------------------------------------------
 
-def _hist_kernel_q(count_ref, bins_ref, gh_ref, out_ref, acc_ref, *,
-                   num_bins: int, fblk: int, blk: int, nrb: int):
-    r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(r * blk < count_ref[0])
-    def _():
-        bins = bins_ref[:].astype(jnp.int32)                # [BLK, FBLK]
-        live = count_ref[0] - r * blk
-        rmask = lax.broadcasted_iota(jnp.int32, (blk, 1), 0) < live
-        gh = jnp.where(rmask, gh_ref[:], 0)                 # [BLK, 8] int8
-        iota_b = lax.broadcasted_iota(jnp.int32, (1, num_bins), 1)
-        B = num_bins
-        for f in range(fblk):
-            onehot = (bins[:, f:f + 1] == iota_b).astype(jnp.int8)
-            acc_ref[:, f * B:(f + 1) * B] += lax.dot_general(
-                gh, onehot,
-                dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)           # [8, B] i32
-
-    @pl.when(r == nrb - 1)
-    def _():
-        out_ref[:] = acc_ref[:]
-
-
 @functools.partial(jax.jit, static_argnames=("num_bins",))
 def hist_pallas_q(bins: jax.Array, ghq8: jax.Array, num_bins: int,
                   count=None) -> jax.Array:
@@ -242,27 +241,7 @@ def hist_pallas_q(bins: jax.Array, ghq8: jax.Array, num_bins: int,
     carry the next leaf's rows there). Returns int32 [F, B, 3]
     (sum_gq, sum_hq, count).
     """
-    P, F = bins.shape
-    B = num_bins
-    blk, fblk = _pick_blocks(F, B, P)
-    if P % blk != 0:
-        pad = blk - P % blk
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        ghq8 = jnp.pad(ghq8, ((0, pad), (0, 0)))
-        P += pad
-    Fp = ((F + fblk - 1) // fblk) * fblk
-    if Fp != F:
-        bins = jnp.pad(bins, ((0, 0), (0, Fp - F)))
-    count = jnp.asarray([P if count is None else count], jnp.int32)
-
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel_q, num_bins=B, fblk=fblk, blk=blk,
-                          nrb=P // blk),
-        out_shape=jax.ShapeDtypeStruct((8, Fp * B), jnp.int32),
-        grid_spec=_grid_spec(P, Fp, B, blk, fblk, jnp.int32),
-        interpret=_interpret(),
-    )(count, bins, ghq8)
-    out = out.reshape(8, Fp, B)[:, :F]
+    out = _hist_call(bins, ghq8, num_bins, count, jnp.int32)
     return jnp.stack([out[0], out[1], out[2]], axis=-1)     # [F, B, 3] i32
 
 

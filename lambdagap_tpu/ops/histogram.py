@@ -28,6 +28,19 @@ from jax import lax
 HIST_CHANNELS = 3  # (sum_grad, sum_hess, count)
 
 
+def split_bf16(x: jax.Array):
+    """``x = hi + lo`` with both halves exact in bf16.
+
+    ``hi`` comes from ``lax.reduce_precision``, not from a convert
+    round-trip: XLA's TPU pipeline treats ``f32 -> bf16 -> f32`` as
+    removable excess precision, which turns ``x - f32(bf16(x))`` into an
+    exact zero and silently drops the low half (measured on a v5e, PR 21:
+    split-precision sums came back with bf16 error, ~1e-3 relative). On
+    CPU the two spellings round identically."""
+    hi = lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
+
+
 def gh_contract(gh: jax.Array, onehot2d: jax.Array,
                 precision: str) -> jax.Array:
     """Contract per-row (grad, hess, count) channels with a one-hot matrix on
@@ -52,14 +65,14 @@ def gh_contract(gh: jax.Array, onehot2d: jax.Array,
         return lax.dot_general(
             gh.T, onehot2d.astype(jnp.float32),
             dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST,   # TPU default is one bf16 pass
             preferred_element_type=jnp.float32)
     if precision == "bf16":
         return lax.dot_general(
             gh.astype(jnp.bfloat16).T, onehot2d,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-    hi = gh.astype(jnp.bfloat16)
-    lo = (gh - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    hi, lo = split_bf16(gh)
     ghs = jnp.concatenate([hi, lo], axis=1)          # [R, 2C]
     part = lax.dot_general(
         ghs.T, onehot2d,

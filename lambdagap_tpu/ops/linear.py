@@ -120,9 +120,14 @@ def accumulate_leaf_moments(X: jax.Array, leaf_idx: jax.Array,
         oh = onehot.astype(jnp.float32)
         # the MXU contraction: per-leaf sum of h-weighted outer products
         # — one [ (L+1)*P x W ] @ [ W x P ] matmul per chunk
+        # precision=HIGHEST: the TPU default for f32 operands is a single
+        # bf16 pass, which the normal equations do not survive (CPU is
+        # exact f32 either way)
         vh = v * h[:, None]                    # [W, P]
-        XtHX = XtHX + jnp.einsum("wl,wp,wq->lpq", oh, vh, v)
-        Xtg = Xtg + jnp.einsum("wl,wp->lp", oh, v * g[:, None])
+        XtHX = XtHX + jnp.einsum("wl,wp,wq->lpq", oh, vh, v,
+                                 precision=lax.Precision.HIGHEST)
+        Xtg = Xtg + jnp.einsum("wl,wp->lp", oh, v * g[:, None],
+                               precision=lax.Precision.HIGHEST)
         cnt = cnt + jnp.sum(oh, axis=0)
         return (XtHX, Xtg, cnt), None
 

@@ -18,6 +18,15 @@ For multi-HOST clusters the same worker command runs on each host with
 ``machines=<coordinator_ip>:<port> num_machines=K machine_rank=r`` — this
 launcher automates the single-host multi-process case and documents the
 multi-host invocation it generates (``verbose_command``).
+
+On accelerators this is a CPU / one-process-per-host facility: the caller
+has imported the package (which initializes the JAX backend, so on a TPU
+host the parent already holds the chips) and every worker inherits the
+parent's environment, so N workers on one host would each try to claim
+every local chip — a chip belongs to one process at a time. Use it with
+``worker_env={"JAX_PLATFORMS": "cpu", ...}`` on one host, or run the
+generated command once per host; one process drives all of a host's chips
+through ``tree_learner=data``.
 """
 from __future__ import annotations
 

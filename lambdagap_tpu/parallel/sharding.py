@@ -36,17 +36,8 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401  (re-exported to the learners)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_old(*args, **kwargs)
 
 # the axis universe. Rows of the training matrix shard over "data"
 # (histograms psum over it); columns shard over "feature" (histogram
@@ -238,7 +229,8 @@ def make_mesh(num_devices: int = 0, devices: Optional[Sequence] = None,
     ``mesh_shape=""`` places ``num_devices`` (0 = all visible) on
     ``shard_axis`` — the learner's natural 1-D geometry: data/voting
     learners shard rows (``(D, 1)``), feature learners shard columns
-    (``(1, D)``). An explicit ``mesh_shape`` overrides both knobs —
+    (``(1, D)``); asking for more devices than are visible raises, naming
+    ``tpu_num_devices``. An explicit ``mesh_shape`` overrides both knobs —
     including genuine 2-D ``dd x ff`` grids, executed by the fused 2-D
     learner (rows shard over ``data``, histogram columns over
     ``feature``; parallel/fused_parallel.py Fused2DTreeLearner).
@@ -249,6 +241,10 @@ def make_mesh(num_devices: int = 0, devices: Optional[Sequence] = None,
     shape = resolve_mesh_shape(mesh_shape, len(devices))
     if shape is None:
         if num_devices and num_devices > 0:
+            if num_devices > len(devices):
+                raise ValueError(
+                    f"tpu_num_devices={num_devices} needs {num_devices} "
+                    f"devices, have {len(devices)}")
             devices = devices[:num_devices]
         d = len(devices)
         shape = (d, 1) if shard_axis == DATA_AXIS else (1, d)

@@ -178,8 +178,7 @@ class CompiledForestCache:
             self.artifact_hash = art.hash
             self._compiled = CompiledForest(
                 art, early_stop_freq=self._es_freq,
-                early_stop_margin=self._es_margin,
-                row_block=int(cfg.infer_row_block))
+                early_stop_margin=self._es_margin)
         self._warm: set = set()
         self._warm_lock = threading.Lock()
         self.build_time_s = 0.0

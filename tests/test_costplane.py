@@ -143,6 +143,33 @@ def test_peaks_override_and_fallback():
     assert p["name"] == "cpu-container" and p["measured"] is False
 
 
+def test_unknown_device_kind_gets_no_peaks(monkeypatch):
+    """A device the table does not name must not borrow another device's
+    peaks: no roofline numbers, and the document says why."""
+    class _Dev:
+        platform, device_kind = "tpu", "TPU v9 hypothetical"
+    monkeypatch.setattr("jax.devices", lambda *a: [_Dev()])
+    _arm()
+    p = PLANE.peaks()
+    assert p["name"] == "unknown" and p["flops"] is None
+    assert p["device_kind"] == "TPU v9 hypothetical"
+    PLANE.entries["p|1"] = {"program": "p", "bucket": "1", "phase": "ph",
+                            "flops": 2e9, "bytes_accessed": 1e9,
+                            "peak_hbm_bytes": 10}
+    PLANE.calls["p|1"] = 2
+    PLANE.note_wall("ph", 8.0)
+    attr = PLANE.attribution()
+    assert "TPU v9 hypothetical" in attr["roofline"]
+    rec = attr["phases"]["ph"]
+    assert rec["flops_total"] == 4e9 and rec["wall_s"] == 8.0
+    assert not {"roofline_s", "bound", "fraction_of_roofline"} & set(rec)
+    from lambdagap_tpu.obs import prom
+    monkeypatch.setattr("jax.device_count", lambda: 1)
+    text = prom.render_costplane()           # exposition survives it
+    assert "phase_roofline_fraction{" not in text
+    assert "phase_wall_seconds" in text
+
+
 # -- attribution math ---------------------------------------------------
 def test_attribution_roofline_join():
     _arm(_peaks_override="1e9:1e9:1e9")

@@ -69,14 +69,12 @@ def test_parity_binary_nan_default_left():
     _assert_engine_parity(b, X, raw_score=True)
 
 
-@pytest.mark.parametrize("row_block", [32, 100, 256])
-def test_parity_ragged_row_tiles(row_block):
-    """Odd row counts vs the traversal kernel's row_block grid: padding
-    rows are sliced off exactly, whatever the remainder."""
+def test_parity_ragged_row_counts():
+    """Odd row counts (no power-of-two or tile multiple) walk the node
+    blocks exactly like the scan oracle."""
     X, y = _data(rows=601)
-    b = _train({"objective": "binary", "num_leaves": 15,
-                "infer_row_block": row_block}, X, y)
-    _assert_engine_parity(b, X)           # 601 % row_block != 0 for all
+    b = _train({"objective": "binary", "num_leaves": 15}, X, y)
+    _assert_engine_parity(b, X)
     _assert_engine_parity(b, X[:599])
 
 

@@ -22,6 +22,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -61,6 +62,15 @@ def test_make_mesh_accepts_2d_grids():
         m = make_mesh(mesh_shape=shape)
         assert dict(m.shape) == {"data": want[0], "feature": want[1]}, shape
         assert tuple(m.axis_names) == ("data", "feature")
+
+
+def test_make_mesh_raises_on_more_devices_than_visible():
+    """tpu_num_devices beyond what is visible used to be truncated by a
+    slice — a 4-chip job would run on 1 and say nothing."""
+    n = len(jax.devices())
+    assert make_mesh(n).devices.size == n
+    with pytest.raises(ValueError, match=f"tpu_num_devices={n + 1}"):
+        make_mesh(n + 1)
 
 
 def test_mesh_shape_wildcards_and_rejections_name_the_knob():

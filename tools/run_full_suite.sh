@@ -204,7 +204,7 @@ if ! env JAX_PLATFORMS=cpu python tools/loop_gate.py; then
     exit 1
 fi
 echo "=== G1 $(date)"
-python -m pytest tests/test_binning.py tests/test_split_math.py tests/test_efb.py tests/test_capi.py tests/test_fast_predict.py tests/test_predict_tensor.py tests/test_misc_api.py tests/test_graftlint.py tests/test_graftir.py tests/test_costplane.py tests/test_profile.py -q 2>&1 | tail -1
+python -m pytest tests/test_binning.py tests/test_bringup.py tests/test_split_math.py tests/test_efb.py tests/test_capi.py tests/test_fast_predict.py tests/test_predict_tensor.py tests/test_misc_api.py tests/test_graftlint.py tests/test_graftir.py tests/test_costplane.py tests/test_profile.py -q 2>&1 | tail -1
 echo "=== G2 $(date)"
 python -m pytest tests/test_train.py tests/test_rank.py tests/test_cli_io.py -q 2>&1 | tail -1
 echo "=== G3 $(date)"
