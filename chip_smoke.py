@@ -21,7 +21,10 @@ failing leg raises, the exit code is non-zero and no result line is printed.
 
 This is not a benchmark: the seconds it prints are information, under no
 metric's name. The report and the telemetry JSONL land in
-``chiprun_out/chip_smoke/``; the last line of stdout is one JSON object.
+``chiprun_out/chip_smoke/``. The second-to-last line of stdout is the
+summary (per-leg pass, resolved learner, ``"claim":null``); the last line is
+the driver's contract and holds exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 
     python chip_smoke.py                      # on the chip
     python chip_smoke.py --require-multichip  # on the four-chip host
@@ -431,10 +434,8 @@ def main(argv=None) -> int:
     rep = Smoke(ap.parse_args(argv)).run()
     legs = rep["legs"]
     main_leg = legs["train"]
-    # the contract's last line: one compact JSON object
-    print(json.dumps({
-        "ok": True,
-        "device": rep["device"],
+    # the summary: one compact JSON object a reader can grep for
+    log("summary " + json.dumps({
         "rehearsal": rep["rehearsal"],
         "legs": {k: ("pass" if v.get("ran", True) else "not run")
                  for k, v in legs.items()},
@@ -445,6 +446,9 @@ def main(argv=None) -> int:
         "seconds": rep["seconds"],
         "claim": None,
     }, separators=(",", ":")))
+    # the contract's last line: exactly these keys, nothing else — the
+    # driver refuses a result line that carries more
+    print(json.dumps({"ok": True, "device": rep["device"]}), flush=True)
     return 0
 
 

@@ -86,13 +86,20 @@ def test_chip_smoke_refuses_without_a_tpu():
 def test_chip_smoke_cpu_rehearsal():
     r = _smoke("--rehearse-cpu")
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-    last = json.loads(r.stdout.strip().splitlines()[-1])
-    assert last["ok"] is True and last["rehearsal"] is True
-    assert last["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
-    assert last["legs"] == {"kernels": "pass", "train": "pass",
+    lines = r.stdout.strip().splitlines()
+    # the last line is the driver's contract: exactly these keys
+    last = json.loads(lines[-1])
+    assert last == {"ok": True, "device": {"platform": "cpu", "kind": "cpu",
+                                           "count": 1}}
+    # the line before it is the summary, stamped as a rehearsal
+    tag = "[chip_smoke] summary "
+    assert lines[-2].startswith(tag)
+    summ = json.loads(lines[-2][len(tag):])
+    assert summ["rehearsal"] is True
+    assert summ["legs"] == {"kernels": "pass", "train": "pass",
                             "parity": "pass", "predict": "pass",
                             "rank": "pass", "multichip": "not run"}
     assert "multichip: not run (1 device)" in r.stdout
-    assert (last["learner"], last["hist_impl"], last["layout"]) == \
+    assert (summ["learner"], summ["hist_impl"], summ["layout"]) == \
         ("FusedTreeLearner", "pallas", "sorted")
-    assert list(last)[-1] == "claim" and last["claim"] is None
+    assert list(summ)[-1] == "claim" and summ["claim"] is None
