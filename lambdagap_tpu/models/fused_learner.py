@@ -29,6 +29,7 @@ from jax import lax
 
 from ..config import Config
 from ..data.dataset import BinnedDataset
+from ..obs.telemetry import device_scope as _scope
 from ..ops.histogram import gh_contract
 from ..ops.partition import decision_go_left
 from ..ops.split import (K_MIN_SCORE, SplitParams, calculate_leaf_output,
@@ -59,6 +60,16 @@ class DeviceTree(NamedTuple):
     leaf_parent_node: jax.Array  # i32 [L]
     num_leaves: jax.Array        # i32 scalar
     row_leaf: jax.Array          # i32 [N] leaf id per training row
+    # i32 [NODES, 2] per realised split: local rows the partition pass
+    # visited, local rows of the child whose histogram was built. Read by
+    # the telemetry's work counts alone (FusedTreeLearner.work_counts) and
+    # dropped with row_leaf; the host-streamed builds leave it out
+    work: Optional[jax.Array] = None
+
+
+# what materialization fetches: everything that describes the tree
+_HOST_FIELDS = tuple(f for f in DeviceTree._fields
+                     if f not in ("row_leaf", "work"))
 
 
 class FusedTreeLearner(SerialTreeLearner):
@@ -325,11 +336,12 @@ class FusedTreeLearner(SerialTreeLearner):
         cannot persist across trees. The W trailing pad rows let every
         window read in the fused program be a clamp-free dynamic slice
         (the same invariant as the permutation buffer's)."""
-        packed = self._pack_rows(grad, hess, row_mask, x_rows, gq, hq,
-                                 has_mask)
-        W = self._window(x_rows.shape[0])
-        return jnp.concatenate(
-            [packed, jnp.zeros((W, packed.shape[1]), packed.dtype)])
+        with _scope("layout_apply"):
+            packed = self._pack_rows(grad, hess, row_mask, x_rows, gq, hq,
+                                     has_mask)
+            W = self._window(x_rows.shape[0])
+            return jnp.concatenate(
+                [packed, jnp.zeros((W, packed.shape[1]), packed.dtype)])
 
     @staticmethod
     def _chunk_override() -> Optional[int]:
@@ -429,7 +441,7 @@ class FusedTreeLearner(SerialTreeLearner):
         if not recs:
             return []
         stacked = {k: jnp.stack([getattr(r, k) for r in recs])
-                   for k in DeviceTree._fields if k != "row_leaf"}
+                   for k in _HOST_FIELDS}
         h = jax.device_get(stacked)
         return [self._tree_from_host({k: v[i] for k, v in h.items()})
                 for i in range(len(recs))]
@@ -441,9 +453,28 @@ class FusedTreeLearner(SerialTreeLearner):
         # learner: one compact O(leaves) struct transfer per tree builds
         # the host model; scores already updated on device, so this is the
         # only per-tree D2H of the sync-free path
-        h = jax.device_get({k: v for k, v in rec._asdict().items()
-                            if k != "row_leaf"})
+        h = jax.device_get({k: getattr(rec, k) for k in _HOST_FIELDS})
         return self._tree_from_host(h)
+
+    def work_counts(self, host) -> dict:
+        """The telemetry's per-tree work counts from ``(DeviceTree.work,
+        num_leaves)`` fetched to the host: realised splits, the rows and
+        ``while`` trips of the partition passes, and of the histogram
+        passes (the root's plus the smaller child's of every split).
+        Python ints: 10.5M rows x 254 splits do not fit int32."""
+        work, num_leaves = host
+        n = int(getattr(self, "n_loc", self.num_data))   # this shard's rows
+        w = self._window(n)
+        splits = max(int(num_leaves) - 1, 0)
+        part, small = np.asarray(work[:splits], np.int64).T
+
+        def trips(rows):
+            return int(((rows + w - 1) // w).sum())
+        return {"splits": splits,
+                "partition_rows": int(part.sum()),
+                "partition_trips": trips(part),
+                "hist_rows": n + int(small.sum()),
+                "hist_trips": -(-n // w) + trips(small)}
 
     def _tree_from_host(self, h) -> Tree:
         L = int(h["num_leaves"])
@@ -533,8 +564,9 @@ class FusedTreeLearner(SerialTreeLearner):
         # monotone_constraints.hpp:560-850 IntermediateLeafConstraints)
         inter = mono_on and self.mono_method == "intermediate"
         NPW_N = (NODES + 31) // 32 if inter else 1
-        lane = jnp.arange(W, dtype=jnp.int32)
-        bin_iota = jnp.arange(Bb, dtype=x_rows.dtype)
+        with _scope("tree_init"):
+            lane = jnp.arange(W, dtype=jnp.int32)
+            bin_iota = jnp.arange(Bb, dtype=x_rows.dtype)
         quant = self.quant
         qexact = self.quant_exact
         # physical row layout (docs/performance.md). gather: grad+hess (and
@@ -553,8 +585,9 @@ class FusedTreeLearner(SerialTreeLearner):
             packed_rows = None          # rows live in the carried srows
             SW = srows.shape[1]
         else:
-            packed_rows = self._pack_rows(grad, hess, row_mask, x_rows,
-                                          gq, hq, has_mask)
+            with _scope("tree_init"):
+                packed_rows = self._pack_rows(grad, hess, row_mask, x_rows,
+                                              gq, hq, has_mask)
 
         def unpack(prow):
             """u32 lanes -> bin-dtype columns (no-op when pack32 is off)."""
@@ -647,7 +680,6 @@ class FusedTreeLearner(SerialTreeLearner):
             # jax.named_scope labels below tag the traced ops so profiler
             # windows (obs/profile.py) show the same histogram/partition/
             # split phase structure the host-side telemetry reports
-            nch = (count + W - 1) // W
 
             def body(st):
                 c, acc = st
@@ -655,7 +687,8 @@ class FusedTreeLearner(SerialTreeLearner):
 
             acc_dtype = (jnp.int32 if qexact and self.hist_impl == "pallas"
                          else jnp.float32)
-            with jax.named_scope("histogram"):
+            with _scope("histogram"):
+                nch = (count + W - 1) // W
                 _, hist = lax.while_loop(
                     lambda st: st[0] < nch, body,
                     (jnp.int32(0), jnp.zeros((C, Bb, HIST_C), acc_dtype)))
@@ -670,10 +703,12 @@ class FusedTreeLearner(SerialTreeLearner):
                 # Voting mode keeps histograms LOCAL: the collective moves
                 # into best_of as a top-k vote + psum of only the voted
                 # columns (reference: voting_parallel_tree_learner.cpp).
-                hist = lax.psum(hist, self.axis)
+                with _scope("hist_allreduce"):
+                    hist = lax.psum(hist, self.axis)
             if qexact and not self.voting:
-                hist = hist.astype(jnp.float32) * jnp.stack(
-                    [gs, hs, jnp.float32(1.0)])
+                with _scope("histogram"):
+                    hist = hist.astype(jnp.float32) * jnp.stack(
+                        [gs, hs, jnp.float32(1.0)])
             # voting + quant_exact: keep RAW level sums — the exact integer
             # reduction happens per voted column inside best_of, scales after
             return hist
@@ -792,7 +827,8 @@ class FusedTreeLearner(SerialTreeLearner):
                       dl[fl].astype(jnp.int32),
                       sl(is_cat_arr)[fl].astype(jnp.int32), bits[fl],
                       lg[fl], lh[fl], lc[fl], lout_l, rout_l)
-            gathered = [lax.all_gather(x, fax) for x in fields]   # [D, ...]
+            with _scope("hist_allreduce"):        # [D, ...] each
+                gathered = [lax.all_gather(x, fax) for x in fields]
             win = jnp.argmax(gathered[0], axis=0).astype(jnp.int32)
             gw = gathered[0][win]
             g = gw - shift
@@ -846,13 +882,14 @@ class FusedTreeLearner(SerialTreeLearner):
                     default_bins, missing_types, is_cat_arr, fm, p, has_cat,
                     rand_thresholds=rand_t)
                 _, local_top = lax.top_k(lgain, vote_k)
-                votes = lax.all_gather(local_top.astype(jnp.int32),
-                                       self.axis, tiled=True)     # [D*k]
-                # in quant_exact mode this psum reduces raw integer level
-                # sums (exact, order-independent — the voted-column analog
-                # of the full-histogram integer reduction in leaf_hist);
-                # scales apply after
-                hist_v = lax.psum(hist[votes], self.axis)
+                with _scope("hist_allreduce"):
+                    votes = lax.all_gather(local_top.astype(jnp.int32),
+                                           self.axis, tiled=True)     # [D*k]
+                    # in quant_exact mode this psum reduces raw integer level
+                    # sums (exact, order-independent — the voted-column
+                    # analog of the full-histogram integer reduction in
+                    # leaf_hist); scales apply after
+                    hist_v = lax.psum(hist[votes], self.axis)
                 if quant and qexact:
                     hist_v = hist_v.astype(jnp.float32) * qsc
                 cons_v = (mono_arr[votes], lo, hi) if mono_on else None
@@ -928,244 +965,257 @@ class FusedTreeLearner(SerialTreeLearner):
         # node_i columns: feature, threshold, default_left, is_cat, left, right
         # W rows of padding let every window read be a clamped-free
         # dynamic slice; pad rows point at row 0 and are always masked
-        perm0 = jnp.concatenate([jnp.arange(N, dtype=jnp.int32),
-                                 jnp.zeros(W, jnp.int32)])
+        with _scope("tree_init"):
+            perm0 = jnp.concatenate([jnp.arange(N, dtype=jnp.int32),
+                                     jnp.zeros(W, jnp.int32)])
         hist_root = leaf_hist(perm0, srows, jnp.int32(0), jnp.int32(N))
-        totals = jnp.sum(hist_root[0], axis=0)
-        if fax is not None and self.axis is not None:
-            # 2-D data x feature execution: hist_root[0] is each feature
-            # shard's LOCAL column 0, so the f32 bin-sum above adds the
-            # same rows in a different (bin-grouping) order per shard —
-            # ulp-divergent parent sums would make the per-shard scans
-            # disagree. Broadcast shard 0's totals so every shard scans
-            # with bit-identical aggregates (exact under quantization,
-            # and the contract the stream mirror replays).
-            fidx = lax.axis_index(fax)
-            totals = lax.psum(
-                jnp.where(fidx == 0, totals, jnp.zeros_like(totals)), fax)
-        if voting:
-            # local root hist: global parent sums need their own (tiny) psum
-            totals = lax.psum(totals, self.axis)
-            if quant and qexact:
-                # raw level sums -> gradient units (voting defers scaling
-                # until after its collectives; see leaf_hist)
-                totals = totals.astype(jnp.float32) * jnp.stack(
-                    [gs, hs, jnp.float32(1.0)])
-        root_out = calculate_leaf_output(totals[0], totals[1], p, totals[2],
-                                         0.0)
-        neg_inf = jnp.float32(-jnp.inf)
-        pos_inf = jnp.float32(jnp.inf)
-        # ekey carries TWO independent streams: [0] extra_trees random
-        # thresholds, [1] by-node column sampling (separate seeds, like the
-        # host learner's _extra_rng vs _col_rng)
-        need_keys = extra_on or bynode_on
-        xkey, bkey = ekey[0], ekey[1]
-        root_key = jax.random.fold_in(xkey, NODES) if need_keys else xkey
-        if ic_on or bynode_on:
-            fm0 = node_fmask(jnp.zeros(PW, jnp.uint32),
-                             jax.random.fold_in(bkey, NODES))
-        else:
-            fm0 = fmask
-        (bg0, bf0, bt0, bdl0, bcat0, bbits0, blg0, blh0, blc0, blout0,
-         brout0) = best_of(hist_root, totals[0], totals[1], totals[2],
-                           root_out, neg_inf, pos_inf, jnp.int32(0),
-                           root_key, fm0)
+        with _scope("tree_init"):
+            totals = jnp.sum(hist_root[0], axis=0)
+            if fax is not None and self.axis is not None:
+                # 2-D data x feature execution: hist_root[0] is each feature
+                # shard's LOCAL column 0, so the f32 bin-sum above adds the
+                # same rows in a different (bin-grouping) order per shard —
+                # ulp-divergent parent sums would make the per-shard scans
+                # disagree. Broadcast shard 0's totals so every shard scans
+                # with bit-identical aggregates (exact under quantization,
+                # and the contract the stream mirror replays).
+                fidx = lax.axis_index(fax)
+                totals = lax.psum(
+                    jnp.where(fidx == 0, totals, jnp.zeros_like(totals)), fax)
+            if voting:
+                # local root hist: global parent sums need their own
+                # (tiny) psum
+                totals = lax.psum(totals, self.axis)
+                if quant and qexact:
+                    # raw level sums -> gradient units (voting defers scaling
+                    # until after its collectives; see leaf_hist)
+                    totals = totals.astype(jnp.float32) * jnp.stack(
+                        [gs, hs, jnp.float32(1.0)])
+            root_out = calculate_leaf_output(totals[0], totals[1], p,
+                                             totals[2], 0.0)
+            neg_inf = jnp.float32(-jnp.inf)
+            pos_inf = jnp.float32(jnp.inf)
+            # ekey carries TWO independent streams: [0] extra_trees random
+            # thresholds, [1] by-node column sampling (separate seeds, like the
+            # host learner's _extra_rng vs _col_rng)
+            need_keys = extra_on or bynode_on
+            xkey, bkey = ekey[0], ekey[1]
+            root_key = jax.random.fold_in(xkey, NODES) if need_keys else xkey
+            if ic_on or bynode_on:
+                fm0 = node_fmask(jnp.zeros(PW, jnp.uint32),
+                                 jax.random.fold_in(bkey, NODES))
+            else:
+                fm0 = fmask
+        with _scope("split_scan"):
+            (bg0, bf0, bt0, bdl0, bcat0, bbits0, blg0, blh0, blc0, blout0,
+             brout0) = best_of(hist_root, totals[0], totals[1], totals[2],
+                               root_out, neg_inf, pos_inf, jnp.int32(0),
+                               root_key, fm0)
 
-        iota_l1 = jnp.arange(L + 1, dtype=jnp.int32)
-        f32 = jnp.float32
-        i32 = jnp.int32
-        leaf_f = jnp.zeros((L + 1, 12), f32)
-        leaf_f = leaf_f.at[:, 4].set(K_MIN_SCORE) \
-                       .at[:, 10].set(-jnp.inf).at[:, 11].set(jnp.inf)
-        leaf_f = leaf_f.at[0].set(jnp.stack(
-            [totals[0], totals[1], totals[2], root_out, bg0, blg0, blh0,
-             blc0, blout0, brout0, neg_inf, pos_inf]))
-        leaf_i = jnp.zeros((L + 1, 9), i32)
-        # inactive leaves carry out-of-range begins so the final
-        # position->leaf searchsorted never matches them
-        leaf_i = leaf_i.at[:, 0].set(N + iota_l1).at[:, 3].set(-1)
-        leaf_i = leaf_i.at[0].set(jnp.stack(
-            [i32(0), i32(N), i32(0), i32(-1), i32(0), bf0, bt0,
-             bdl0.astype(i32), bcat0.astype(i32)]))
-        leaf_bits = jnp.zeros((L + 1, 8), jnp.uint32).at[0].set(bbits0)
-        node_f = jnp.zeros((NODES + 1, 4), f32)
-        node_i = jnp.zeros((NODES + 1, 6), i32).at[:, 4:6].set(~0)
-        node_bits = jnp.zeros((NODES + 1, 8), jnp.uint32)
-        state = dict(
-            perm=perm0,
-            perm_buf=jnp.zeros(N + W, jnp.int32),
-            leaf_f=leaf_f, leaf_i=leaf_i, leaf_bits=leaf_bits,
-            node_f=node_f, node_i=node_i, node_bits=node_bits,
-            hist=jnp.zeros((L + 1, C, Bb, HIST_C), f32).at[0].set(hist_root),
-            num_leaves=jnp.int32(1),
-        )
-        if layout_sorted:
-            # the leaf-ordered payload + its partition double buffer ride
-            # the carry so each split's permutation delta applies in place
-            state["srows"] = srows
-            state["srows_buf"] = jnp.zeros_like(srows)
-        if ic_on:
-            state["path"] = jnp.zeros((L + 1, PW), jnp.uint32)
-        if inter:
-            # per-leaf bin-space boxes ([lo, hi) per feature, root = full
-            # range), per-leaf ancestor-node bitsets, the stale-scan marks,
-            # and node parent/side pointers for the up-walk
-            state["box_lo"] = jnp.zeros((L + 1, F), jnp.int32)
-            state["box_hi"] = jnp.zeros((L + 1, F),
-                                        jnp.int32).at[0].set(num_bins)
-            state["npath"] = jnp.zeros((L + 1, NPW_N), jnp.uint32)
-            state["stale"] = jnp.zeros(L + 1, bool)
-            state["node_par"] = jnp.full(NODES + 1, -1, jnp.int32)
-            state["node_side"] = jnp.zeros(NODES + 1, jnp.int32)
+        with _scope("tree_init"):
+            iota_l1 = jnp.arange(L + 1, dtype=jnp.int32)
+            f32 = jnp.float32
+            i32 = jnp.int32
+            leaf_f = jnp.zeros((L + 1, 12), f32)
+            leaf_f = leaf_f.at[:, 4].set(K_MIN_SCORE) \
+                           .at[:, 10].set(-jnp.inf).at[:, 11].set(jnp.inf)
+            leaf_f = leaf_f.at[0].set(jnp.stack(
+                [totals[0], totals[1], totals[2], root_out, bg0, blg0, blh0,
+                 blc0, blout0, brout0, neg_inf, pos_inf]))
+            leaf_i = jnp.zeros((L + 1, 9), i32)
+            # inactive leaves carry out-of-range begins so the final
+            # position->leaf searchsorted never matches them
+            leaf_i = leaf_i.at[:, 0].set(N + iota_l1).at[:, 3].set(-1)
+            leaf_i = leaf_i.at[0].set(jnp.stack(
+                [i32(0), i32(N), i32(0), i32(-1), i32(0), bf0, bt0,
+                 bdl0.astype(i32), bcat0.astype(i32)]))
+            leaf_bits = jnp.zeros((L + 1, 8), jnp.uint32).at[0].set(bbits0)
+            node_f = jnp.zeros((NODES + 1, 4), f32)
+            node_i = jnp.zeros((NODES + 1, 8), i32).at[:, 4:6].set(~0)
+            node_bits = jnp.zeros((NODES + 1, 8), jnp.uint32)
+            state = dict(
+                perm=perm0,
+                perm_buf=jnp.zeros(N + W, jnp.int32),
+                leaf_f=leaf_f, leaf_i=leaf_i, leaf_bits=leaf_bits,
+                node_f=node_f, node_i=node_i, node_bits=node_bits,
+                hist=jnp.zeros((L + 1, C, Bb, HIST_C),
+                               f32).at[0].set(hist_root),
+                num_leaves=jnp.int32(1),
+            )
+            if layout_sorted:
+                # the leaf-ordered payload + its partition double buffer ride
+                # the carry so each split's permutation delta applies in place
+                state["srows"] = srows
+                state["srows_buf"] = jnp.zeros_like(srows)
+            if ic_on:
+                state["path"] = jnp.zeros((L + 1, PW), jnp.uint32)
+            if inter:
+                # per-leaf bin-space boxes ([lo, hi) per feature, root = full
+                # range), per-leaf ancestor-node bitsets, the stale-scan marks,
+                # and node parent/side pointers for the up-walk
+                state["box_lo"] = jnp.zeros((L + 1, F), jnp.int32)
+                state["box_hi"] = jnp.zeros((L + 1, F),
+                                            jnp.int32).at[0].set(num_bins)
+                state["npath"] = jnp.zeros((L + 1, NPW_N), jnp.uint32)
+                state["stale"] = jnp.zeros(L + 1, bool)
+                state["node_par"] = jnp.full(NODES + 1, -1, jnp.int32)
+                state["node_side"] = jnp.zeros(NODES + 1, jnp.int32)
 
-        forced = self.forced_seq
-        if forced is not None:
-            f_leaf = jnp.asarray(forced[0])
-            f_feat = jnp.asarray(forced[1])
-            f_thr = jnp.asarray(forced[2])
-            f_on = jnp.asarray(forced[3])
-            state["forcing"] = jnp.asarray(True)
+            forced = self.forced_seq
+            if forced is not None:
+                f_leaf = jnp.asarray(forced[0])
+                f_feat = jnp.asarray(forced[1])
+                f_thr = jnp.asarray(forced[2])
+                f_on = jnp.asarray(forced[3])
+                state["forcing"] = jnp.asarray(True)
 
         # ------------------------------------------------------ split step
         def split_step(k, st):
-            if inter:
-                # eager re-scan of every leaf whose bounds the previous
-                # split's propagation tightened (the host learner re-scans
-                # them inside apply_split; here the re-scan runs at the
-                # start of the next step — before the argmax, so the
-                # choice sees only fresh gains). Loop trips are derived
-                # from replicated state, so every shard runs the same
-                # number of (collective-bearing, under voting) re-scans.
-                def rescan_cond(rst):
-                    return jnp.any(rst[3][:L])
+            with _scope("leaf_select"):
+                if inter:
+                    # eager re-scan of every leaf whose bounds the previous
+                    # split's propagation tightened (the host learner re-scans
+                    # them inside apply_split; here the re-scan runs at the
+                    # start of the next step — before the argmax, so the
+                    # choice sees only fresh gains). Loop trips are derived
+                    # from replicated state, so every shard runs the same
+                    # number of (collective-bearing, under voting) re-scans.
+                    def rescan_cond(rst):
+                        return jnp.any(rst[3][:L])
 
-                def rescan_body(rst):
-                    lf_c, li_c, lb_c, stale_c = rst
-                    rl = jnp.argmax(stale_c[:L]).astype(jnp.int32)
-                    lfr = lf_c[rl]
-                    lir = li_c[rl]
-                    if need_keys:
-                        rk = jax.random.fold_in(
-                            jax.random.fold_in(xkey, NODES + 1),
-                            k * (L + 1) + rl)
-                    else:
-                        rk = xkey
-                    if ic_on or bynode_on:
-                        cp = (st["path"][rl] if ic_on
-                              else jnp.zeros(PW, jnp.uint32))
-                        fm_l = node_fmask(cp, jax.random.fold_in(
-                            jax.random.fold_in(bkey, NODES + 1),
-                            k * (L + 1) + rl))
-                    else:
-                        fm_l = fmask
-                    (rg, rf, rt, rdl, rcat, rbits, rlg, rlh, rlc, rlout,
-                     rrout) = best_of(st["hist"][rl], lfr[0], lfr[1],
-                                      lfr[2], lfr[3], lfr[10], lfr[11],
-                                      lir[2], rk, fm_l)
-                    new_lf = jnp.stack([lfr[0], lfr[1], lfr[2], lfr[3],
-                                        rg, rlg, rlh, rlc, rlout, rrout,
-                                        lfr[10], lfr[11]])
-                    new_li = jnp.stack([lir[0], lir[1], lir[2], lir[3],
-                                        lir[4], rf, rt,
-                                        rdl.astype(jnp.int32),
-                                        rcat.astype(jnp.int32)])
-                    return (lf_c.at[rl].set(new_lf),
-                            li_c.at[rl].set(new_li),
-                            lb_c.at[rl].set(rbits),
-                            stale_c.at[rl].set(False))
+                    def rescan_body(rst):
+                        lf_c, li_c, lb_c, stale_c = rst
+                        rl = jnp.argmax(stale_c[:L]).astype(jnp.int32)
+                        lfr = lf_c[rl]
+                        lir = li_c[rl]
+                        if need_keys:
+                            rk = jax.random.fold_in(
+                                jax.random.fold_in(xkey, NODES + 1),
+                                k * (L + 1) + rl)
+                        else:
+                            rk = xkey
+                        if ic_on or bynode_on:
+                            cp = (st["path"][rl] if ic_on
+                                  else jnp.zeros(PW, jnp.uint32))
+                            fm_l = node_fmask(cp, jax.random.fold_in(
+                                jax.random.fold_in(bkey, NODES + 1),
+                                k * (L + 1) + rl))
+                        else:
+                            fm_l = fmask
+                        (rg, rf, rt, rdl, rcat, rbits, rlg, rlh, rlc, rlout,
+                         rrout) = best_of(st["hist"][rl], lfr[0], lfr[1],
+                                          lfr[2], lfr[3], lfr[10], lfr[11],
+                                          lir[2], rk, fm_l)
+                        new_lf = jnp.stack([lfr[0], lfr[1], lfr[2], lfr[3],
+                                            rg, rlg, rlh, rlc, rlout, rrout,
+                                            lfr[10], lfr[11]])
+                        new_li = jnp.stack([lir[0], lir[1], lir[2], lir[3],
+                                            lir[4], rf, rt,
+                                            rdl.astype(jnp.int32),
+                                            rcat.astype(jnp.int32)])
+                        return (lf_c.at[rl].set(new_lf),
+                                li_c.at[rl].set(new_li),
+                                lb_c.at[rl].set(rbits),
+                                stale_c.at[rl].set(False))
 
-                leaf_f, leaf_i, leaf_bits, stale = lax.while_loop(
-                    rescan_cond, rescan_body,
-                    (st["leaf_f"], st["leaf_i"], st["leaf_bits"],
-                     st["stale"]))
-            else:
-                leaf_f, leaf_i = st["leaf_f"], st["leaf_i"]
-                leaf_bits = st["leaf_bits"]
-            leaf = jnp.argmax(leaf_f[:L, 4]).astype(jnp.int32)
-            forcing_next = None
-            fon = use_f = None
-            if forced is not None:
-                # gather the forced split's stats from the forced leaf's
-                # histogram; if it is invalid (no positive gain / depth),
-                # forcing aborts and THIS step falls back to the argmax best
-                # split, so an abort costs no split budget (matching the
-                # serial ForceSplits abort_last_forced_split behavior)
-                fon = f_on[k] & st["forcing"]
-                fleaf = f_leaf[k]
-                flf = leaf_f[fleaf]
-                fli = leaf_i[fleaf]
-                hist_leaf = st["hist"][fleaf]
-                if bundled:
-                    from ..ops.histogram import unbundle_hist
-                    histF = unbundle_hist(hist_leaf, self.ub_src, self.ub_kind,
-                                          flf[0], flf[1], flf[2])
+                    leaf_f, leaf_i, leaf_bits, stale = lax.while_loop(
+                        rescan_cond, rescan_body,
+                        (st["leaf_f"], st["leaf_i"], st["leaf_bits"],
+                         st["stale"]))
                 else:
-                    histF = hist_leaf
-                fk = f_feat[k]
-                res = gather_threshold_split(
-                    histF[fk], flf[0], flf[1], flf[2], flf[3], fk, f_thr[k],
-                    num_bins[fk], default_bins[fk], missing_types[fk],
-                    is_cat_arr[fk], p,
-                    bounds=(flf[10], flf[11]) if mono_on else None)
-                fok = res.gain > 0.0
-                if max_depth > 0:
-                    fok = fok & (fli[2] < max_depth)
-                forcing_next = st["forcing"] & jnp.where(f_on[k], fok, True)
-                use_f = fon & fok
-                leaf = jnp.where(use_f, fleaf, leaf)
-            lf = leaf_f[leaf]
-            li = leaf_i[leaf]
-            ok = lf[4] > 0.0
+                    leaf_f, leaf_i = st["leaf_f"], st["leaf_i"]
+                    leaf_bits = st["leaf_bits"]
+                leaf = jnp.argmax(leaf_f[:L, 4]).astype(jnp.int32)
+                forcing_next = None
+                fon = use_f = None
+                if forced is not None:
+                    # gather the forced split's stats from the forced leaf's
+                    # histogram; if it is invalid (no positive gain / depth),
+                    # forcing aborts and THIS step falls back to the argmax
+                    # best split, so an abort costs no split budget (matching
+                    # the
+                    # serial ForceSplits abort_last_forced_split behavior)
+                    fon = f_on[k] & st["forcing"]
+                    fleaf = f_leaf[k]
+                    flf = leaf_f[fleaf]
+                    fli = leaf_i[fleaf]
+                    hist_leaf = st["hist"][fleaf]
+                    if bundled:
+                        from ..ops.histogram import unbundle_hist
+                        histF = unbundle_hist(hist_leaf, self.ub_src,
+                                              self.ub_kind, flf[0], flf[1],
+                                              flf[2])
+                    else:
+                        histF = hist_leaf
+                    fk = f_feat[k]
+                    res = gather_threshold_split(
+                        histF[fk], flf[0], flf[1], flf[2], flf[3], fk,
+                        f_thr[k],
+                        num_bins[fk], default_bins[fk], missing_types[fk],
+                        is_cat_arr[fk], p,
+                        bounds=(flf[10], flf[11]) if mono_on else None)
+                    fok = res.gain > 0.0
+                    if max_depth > 0:
+                        fok = fok & (fli[2] < max_depth)
+                    forcing_next = st["forcing"] & jnp.where(f_on[k], fok,
+                                                             True)
+                    use_f = fon & fok
+                    leaf = jnp.where(use_f, fleaf, leaf)
+                lf = leaf_f[leaf]
+                li = leaf_i[leaf]
+                ok = lf[4] > 0.0
 
-            # the chosen split: the leaf's stored best, unless this step is
-            # a (valid) forced one — then the gathered fixed split
-            bgain = lf[4]
-            feat = li[5]
-            thrv, dlv, catv = li[6], li[7].astype(bool), li[8].astype(bool)
-            bitsv = leaf_bits[leaf]
-            blg, blh, blc = lf[5], lf[6], lf[7]
-            blout, brout = lf[8], lf[9]
-            if forced is not None:
-                ok = jnp.where(use_f, True, ok)
-                bgain = jnp.where(use_f, res.gain, bgain)
-                feat = jnp.where(use_f, fk, feat)
-                thrv = jnp.where(use_f, f_thr[k], thrv)
-                dlv = jnp.where(use_f, res.default_left, dlv)
-                catv = jnp.where(use_f, res.is_categorical, catv)
-                bitsv = jnp.where(use_f, res.cat_bitset, bitsv)
-                blg = jnp.where(use_f, res.left_sum_g, blg)
-                blh = jnp.where(use_f, res.left_sum_h, blh)
-                blc = jnp.where(use_f, res.left_count, blc)
-                blout = jnp.where(use_f, res.left_output, blout)
-                brout = jnp.where(use_f, res.right_output, brout)
+                # the chosen split: the leaf's stored best, unless this step is
+                # a (valid) forced one — then the gathered fixed split
+                bgain = lf[4]
+                feat = li[5]
+                thrv, dlv, catv = li[6], li[7].astype(bool), li[8].astype(bool)
+                bitsv = leaf_bits[leaf]
+                blg, blh, blc = lf[5], lf[6], lf[7]
+                blout, brout = lf[8], lf[9]
+                if forced is not None:
+                    ok = jnp.where(use_f, True, ok)
+                    bgain = jnp.where(use_f, res.gain, bgain)
+                    feat = jnp.where(use_f, fk, feat)
+                    thrv = jnp.where(use_f, f_thr[k], thrv)
+                    dlv = jnp.where(use_f, res.default_left, dlv)
+                    catv = jnp.where(use_f, res.is_categorical, catv)
+                    bitsv = jnp.where(use_f, res.cat_bitset, bitsv)
+                    blg = jnp.where(use_f, res.left_sum_g, blg)
+                    blh = jnp.where(use_f, res.left_sum_h, blh)
+                    blc = jnp.where(use_f, res.left_count, blc)
+                    blout = jnp.where(use_f, res.left_output, blout)
+                    brout = jnp.where(use_f, res.right_output, brout)
 
-            begin = li[0]
-            count_eff = jnp.where(ok, li[1], 0)
-            srows_cur = st["srows"] if layout_sorted else None
-            if layout_sorted:
-                # the split feature's bin value is decoded from the sorted
-                # window itself inside pbody — no column gather, and no
-                # column-major matrix at all (x_cols is a placeholder)
-                col = None
-                colidx = self.bcol[feat] if bundled else feat
-            elif fax is not None:
-                # the winning feature's column lives on ONE shard: psum
-                # broadcasts it for the (row-replicated) partition — the
-                # analog of the reference's best-split partition broadcast
-                # (feature_parallel_tree_learner.cpp SyncUp + split apply)
-                C_loc_p = x_cols.shape[0]
-                f_loc = feat - lax.axis_index(fax) * C_loc_p
-                owned = (f_loc >= 0) & (f_loc < C_loc_p)
-                col_l = x_cols[jnp.clip(f_loc, 0, C_loc_p - 1)]
-                # psum in the native bin dtype: exactly one shard is
-                # nonzero, so no overflow — and the wire moves 1-2 B per
-                # row instead of 4 (pbody casts to i32 as it reads)
-                col = lax.psum(
-                    jnp.where(owned, col_l, jnp.zeros_like(col_l)), fax)
-            else:
-                col = x_cols[self.bcol[feat] if bundled else feat]  # [N]
-            nch = (count_eff + W - 1) // W
-            perm_in = st["perm"]
+                begin = li[0]
+                count_eff = jnp.where(ok, li[1], 0)
+                srows_cur = st["srows"] if layout_sorted else None
+                if layout_sorted:
+                    # the split feature's bin value is decoded from the sorted
+                    # window itself inside pbody — no column gather, and no
+                    # column-major matrix at all (x_cols is a placeholder)
+                    col = None
+                    colidx = self.bcol[feat] if bundled else feat
+                elif fax is not None:
+                    # the winning feature's column lives on ONE shard: psum
+                    # broadcasts it for the (row-replicated) partition — the
+                    # analog of the reference's best-split partition broadcast
+                    # (feature_parallel_tree_learner.cpp SyncUp + split apply)
+                    C_loc_p = x_cols.shape[0]
+                    f_loc = feat - lax.axis_index(fax) * C_loc_p
+                    owned = (f_loc >= 0) & (f_loc < C_loc_p)
+                    col_l = x_cols[jnp.clip(f_loc, 0, C_loc_p - 1)]
+                    # psum in the native bin dtype: exactly one shard is
+                    # nonzero, so no overflow — and the wire moves 1-2 B per
+                    # row instead of 4 (pbody casts to i32 as it reads)
+                    with _scope("hist_allreduce"):
+                        col = lax.psum(
+                            jnp.where(owned, col_l, jnp.zeros_like(col_l)),
+                            fax)
+                else:
+                    col = x_cols[self.bcol[feat] if bundled else feat]  # [N]
+                nch = (count_eff + W - 1) // W
+                perm_in = st["perm"]
 
             # -- chunked stable partition into perm_buf ----------------
             # under the sorted layout the SAME scatter positions route the
@@ -1179,43 +1229,50 @@ class FusedTreeLearner(SerialTreeLearner):
                     c, lcur, rcur, pbuf, sbuf = s
                 else:
                     c, lcur, rcur, pbuf = s
-                live = jnp.clip(count_eff - c * W, 0, W)
-                valid = lane < live
-                rows = perm_slice(perm_in, begin + c * W)
+                with _scope("partition_decide"):
+                    live = jnp.clip(count_eff - c * W, 0, W)
+                    valid = lane < live
+                    rows = perm_slice(perm_in, begin + c * W)
+                    if layout_sorted:
+                        dw = srow_slice(srows_cur, begin + c * W)
+                        cv = jnp.take(unpack(dw), colidx,
+                                      axis=1).astype(jnp.int32)
+                    else:
+                        cv = col[rows].astype(jnp.int32)
+                    if bundled:
+                        # rank-decode the feature's bin out of its bundle
+                        # column
+                        r = cv - self.boff[feat]
+                        d = default_bins[feat]
+                        in_r = (r >= 0) & (r < num_bins[feat] - 1)
+                        cv = jnp.where(self.bsingle[feat], cv,
+                                       jnp.where(in_r, r + (r >= d), d))
+                    gl = decision_go_left(
+                        cv, thrv, dlv, default_bins[feat],
+                        missing_types[feat], num_bins[feat], catv,
+                        bitsv) & valid
+                    cums_gl = jnp.cumsum(gl.astype(jnp.int32))
+                    nl = cums_gl[W - 1]
+                    # valid lanes are a prefix, so the right-side rank needs
+                    # no second cumsum
+                    prefix_valid = jnp.minimum(lane + 1, live)
+                    lpos = lcur + cums_gl - 1
+                    # rights fill backward from the slice end: stable within
+                    # a chunk, chunk order reversed on the right side — a
+                    # deterministic permutation, only affecting later gather
+                    # order
+                    rpos = rcur - (prefix_valid - cums_gl)
+                    pos = jnp.where(gl, lpos, jnp.where(valid, rpos, N))
+                    nxt = (c + 1, lcur + nl, rcur - (live - nl))
+                with _scope("partition_scatter"):
+                    pbuf = pbuf.at[pos].set(rows, mode="drop")
+                    if layout_sorted:
+                        sbuf = sbuf.at[pos].set(dw, mode="drop")
                 if layout_sorted:
-                    dw = srow_slice(srows_cur, begin + c * W)
-                    cv = jnp.take(unpack(dw), colidx,
-                                  axis=1).astype(jnp.int32)
-                else:
-                    cv = col[rows].astype(jnp.int32)
-                if bundled:
-                    # rank-decode the feature's bin out of its bundle column
-                    r = cv - self.boff[feat]
-                    d = default_bins[feat]
-                    in_r = (r >= 0) & (r < num_bins[feat] - 1)
-                    cv = jnp.where(self.bsingle[feat], cv,
-                                   jnp.where(in_r, r + (r >= d), d))
-                gl = decision_go_left(
-                    cv, thrv, dlv, default_bins[feat],
-                    missing_types[feat], num_bins[feat], catv, bitsv) & valid
-                cums_gl = jnp.cumsum(gl.astype(jnp.int32))
-                nl = cums_gl[W - 1]
-                # valid lanes are a prefix, so the right-side rank needs no
-                # second cumsum
-                prefix_valid = jnp.minimum(lane + 1, live)
-                lpos = lcur + cums_gl - 1
-                # rights fill backward from the slice end: stable within a
-                # chunk, chunk order reversed on the right side — a
-                # deterministic permutation, only affecting later gather order
-                rpos = rcur - (prefix_valid - cums_gl)
-                pos = jnp.where(gl, lpos, jnp.where(valid, rpos, N))
-                pbuf = pbuf.at[pos].set(rows, mode="drop")
-                if layout_sorted:
-                    sbuf = sbuf.at[pos].set(dw, mode="drop")
-                    return c + 1, lcur + nl, rcur - (live - nl), pbuf, sbuf
-                return c + 1, lcur + nl, rcur - (live - nl), pbuf
+                    return nxt + (pbuf, sbuf)
+                return nxt + (pbuf,)
 
-            with jax.named_scope("partition"):
+            with _scope("partition"):
                 if layout_sorted:
                     _, lend, _, pbuf, sbuf = lax.while_loop(
                         lambda s: s[0] < nch, pbody,
@@ -1227,8 +1284,9 @@ class FusedTreeLearner(SerialTreeLearner):
                         (jnp.int32(0), begin, begin + count_eff,
                          st["perm_buf"]))
                     sbuf = None
-            left_count = lend - begin
-            right_count = count_eff - left_count
+            with _scope("split_state"):
+                left_count = lend - begin
+                right_count = count_eff - left_count
 
             # copy the partitioned slice back into perm (chunked); both reads
             # and the write are contiguous-window DMAs, with the stale tail
@@ -1254,7 +1312,7 @@ class FusedTreeLearner(SerialTreeLearner):
                     return c + 1, pm, sr
                 return c + 1, pm
 
-            with jax.named_scope("partition_copyback"):
+            with _scope("partition_copyback"):
                 if layout_sorted:
                     _, perm, srows_new = lax.while_loop(
                         lambda s: s[0] < nch, cbody,
@@ -1264,97 +1322,103 @@ class FusedTreeLearner(SerialTreeLearner):
                                              (jnp.int32(0), perm_in))
                     srows_new = None
 
-            # -- masked write indices (dump rows swallow no-op steps) --
-            # nodes are indexed by the number of REALIZED splits, not the
-            # loop counter: a no-op step (e.g. an aborted forced split)
-            # must not leave a hole in the node array
-            new_leaf = st["num_leaves"]
-            nidx = new_leaf - 1
-            wl = jnp.where(ok, leaf, L)
-            wn = jnp.where(ok, new_leaf, L)
-            wk = jnp.where(ok, nidx, NODES)
+            with _scope("split_state"):
+                # -- masked write indices (dump rows swallow no-op steps) --
+                # nodes are indexed by the number of REALIZED splits, not the
+                # loop counter: a no-op step (e.g. an aborted forced split)
+                # must not leave a hole in the node array
+                new_leaf = st["num_leaves"]
+                nidx = new_leaf - 1
+                wl = jnp.where(ok, leaf, L)
+                wn = jnp.where(ok, new_leaf, L)
+                wk = jnp.where(ok, nidx, NODES)
 
-            # parent node's child pointer now points at node k
-            pnode = li[3]
-            was_left = li[4].astype(bool)
-            safe_p = jnp.where((pnode >= 0) & ok, pnode, NODES)
-            prow = st["node_i"][safe_p]
-            prow = jnp.where(was_left, prow.at[4].set(nidx),
-                             prow.at[5].set(nidx))
-            node_i = st["node_i"].at[safe_p].set(prow)
+                # parent node's child pointer now points at node k
+                pnode = li[3]
+                was_left = li[4].astype(bool)
+                safe_p = jnp.where((pnode >= 0) & ok, pnode, NODES)
+                prow = st["node_i"][safe_p]
+                prow = jnp.where(was_left, prow.at[4].set(nidx),
+                                 prow.at[5].set(nidx))
+                node_i = st["node_i"].at[safe_p].set(prow)
 
-            # aggregates
-            pg, ph, pc = lf[0], lf[1], lf[2]
-            lg, lh, lc = blg, blh, blc
-            rg, rh, rc = pg - lg, ph - lh, pc - lc
-            lout, rout = blout, brout
-            depth = li[2] + 1
+                # aggregates
+                pg, ph, pc = lf[0], lf[1], lf[2]
+                lg, lh, lc = blg, blh, blc
+                rg, rh, rc = pg - lg, ph - lh, pc - lc
+                lout, rout = blout, brout
+                depth = li[2] + 1
 
-            # children's monotone bounds. basic: the mid of the two outputs
-            # caps the subtree on the constrained side; intermediate: each
-            # child is capped by its SIBLING's output — looser, recovered
-            # accuracy is the method's point (reference:
-            # UpdateConstraintsWithOutputs, monotone_constraints.hpp:545)
-            pmin, pmax = lf[10], lf[11]
-            mono_f = mono_arr[feat]
-            if inter:
-                lcap, rcap = rout, lout
-            else:
-                lcap = rcap = (lout + rout) * 0.5
-            lmin = jnp.where(mono_f < 0, jnp.maximum(pmin, lcap), pmin)
-            lmax = jnp.where(mono_f > 0, jnp.minimum(pmax, lcap), pmax)
-            rmin = jnp.where(mono_f > 0, jnp.maximum(pmin, rcap), pmin)
-            rmax = jnp.where(mono_f < 0, jnp.minimum(pmax, rcap), pmax)
+                # which child's histogram is built (the smaller; the larger
+                # comes by subtraction)
+                if self.axis is None:
+                    small_is_left = left_count <= right_count
+                else:
+                    # the side choice must be identical on every shard (each
+                    # shard's local hist feeds one psum); local partition
+                    # counts differ per shard, the scan's global (in-bag)
+                    # counts do not
+                    small_is_left = lc <= pc - lc
+                sb = jnp.where(small_is_left, begin, begin + left_count)
+                sc = jnp.where(small_is_left, left_count, right_count)
 
-            node_f = st["node_f"].at[wk].set(
-                jnp.stack([bgain, lf[3], ph, pc]))
-            node_i = node_i.at[wk].set(jnp.stack(
-                [feat, thrv, dlv.astype(jnp.int32), catv.astype(jnp.int32),
-                 ~leaf, ~new_leaf]))
-            node_bits = st["node_bits"].at[wk].set(bitsv)
+                # children's monotone bounds. basic: the mid of the two outputs
+                # caps the subtree on the constrained side; intermediate: each
+                # child is capped by its SIBLING's output — looser, recovered
+                # accuracy is the method's point (reference:
+                # UpdateConstraintsWithOutputs, monotone_constraints.hpp:545)
+                pmin, pmax = lf[10], lf[11]
+                mono_f = mono_arr[feat]
+                if inter:
+                    lcap, rcap = rout, lout
+                else:
+                    lcap = rcap = (lout + rout) * 0.5
+                lmin = jnp.where(mono_f < 0, jnp.maximum(pmin, lcap), pmin)
+                lmax = jnp.where(mono_f > 0, jnp.minimum(pmax, lcap), pmax)
+                rmin = jnp.where(mono_f > 0, jnp.maximum(pmin, rcap), pmin)
+                rmax = jnp.where(mono_f < 0, jnp.minimum(pmax, rcap), pmax)
+
+                node_f = st["node_f"].at[wk].set(
+                    jnp.stack([bgain, lf[3], ph, pc]))
+                node_i = node_i.at[wk].set(jnp.stack(
+                    [feat, thrv, dlv.astype(jnp.int32), catv.astype(jnp.int32),
+                     ~leaf, ~new_leaf, count_eff, sc]))
+                node_bits = st["node_bits"].at[wk].set(bitsv)
 
             # -- children histograms (smaller built, larger by subtraction)
-            if self.axis is None:
-                small_is_left = left_count <= right_count
-            else:
-                # the side choice must be identical on every shard (each
-                # shard's local hist feeds one psum); local partition counts
-                # differ per shard, the scan's global (in-bag) counts do not
-                small_is_left = lc <= pc - lc
-            sb = jnp.where(small_is_left, begin, begin + left_count)
-            sc = jnp.where(small_is_left, left_count, right_count)
             hist_small = leaf_hist(perm, srows_new, sb, sc)
-            hist_large = st["hist"][leaf] - hist_small
-            hist_left = jnp.where(small_is_left, hist_small, hist_large)
-            hist_right = jnp.where(small_is_left, hist_large, hist_small)
-            hist = st["hist"].at[wl].set(hist_left).at[wn].set(hist_right)
+            with _scope("hist_subtract"):
+                hist_large = st["hist"][leaf] - hist_small
+                hist_left = jnp.where(small_is_left, hist_small, hist_large)
+                hist_right = jnp.where(small_is_left, hist_large, hist_small)
+                hist = st["hist"].at[wl].set(hist_left).at[wn].set(hist_right)
 
             # -- both children's best splits in one vmapped scan -------
-            if extra_on or bynode_on:
-                xstep = jax.random.fold_in(xkey, k)
-                bstep = jax.random.fold_in(bkey, k)
-                child_keys = jnp.stack([jax.random.fold_in(xstep, 0),
-                                        jax.random.fold_in(xstep, 1)])
-            else:
-                bstep = bkey
-                child_keys = jnp.zeros((2,) + xkey.shape, xkey.dtype)
-            if ic_on:
-                # children inherit the path plus the feature just split on
-                pbit = jnp.where(
-                    jnp.arange(PW, dtype=jnp.uint32)
-                    == (feat // 32).astype(jnp.uint32),
-                    jnp.left_shift(jnp.uint32(1),
-                                   (feat % 32).astype(jnp.uint32)),
-                    jnp.uint32(0))
-                child_path = st["path"][leaf] | pbit
-            if ic_on or bynode_on:
-                cp = child_path if ic_on else jnp.zeros(PW, jnp.uint32)
-                fms = jnp.stack([
-                    node_fmask(cp, jax.random.fold_in(bstep, 2)),
-                    node_fmask(cp, jax.random.fold_in(bstep, 3))])
-            else:
-                fms = jnp.broadcast_to(fmask, (2, F))
-            with jax.named_scope("split_scan"):
+            with _scope("split_scan"):
+                if extra_on or bynode_on:
+                    xstep = jax.random.fold_in(xkey, k)
+                    bstep = jax.random.fold_in(bkey, k)
+                    child_keys = jnp.stack([jax.random.fold_in(xstep, 0),
+                                            jax.random.fold_in(xstep, 1)])
+                else:
+                    bstep = bkey
+                    child_keys = jnp.zeros((2,) + xkey.shape, xkey.dtype)
+                if ic_on:
+                    # children inherit the path plus the feature just split on
+                    pbit = jnp.where(
+                        jnp.arange(PW, dtype=jnp.uint32)
+                        == (feat // 32).astype(jnp.uint32),
+                        jnp.left_shift(jnp.uint32(1),
+                                       (feat % 32).astype(jnp.uint32)),
+                        jnp.uint32(0))
+                    child_path = st["path"][leaf] | pbit
+                if ic_on or bynode_on:
+                    cp = child_path if ic_on else jnp.zeros(PW, jnp.uint32)
+                    fms = jnp.stack([
+                        node_fmask(cp, jax.random.fold_in(bstep, 2)),
+                        node_fmask(cp, jax.random.fold_in(bstep, 3))])
+                else:
+                    fms = jnp.broadcast_to(fmask, (2, F))
                 (bg2, bf2, bt2, bdl2, bcat2, bbits2, blg2, blh2, blc2,
                  blout2, brout2) = best_children(
                     jnp.stack([hist_left, hist_right]),
@@ -1363,221 +1427,230 @@ class FusedTreeLearner(SerialTreeLearner):
                     jnp.stack([lmin, rmin]), jnp.stack([lmax, rmax]), depth,
                     child_keys, fms)
 
-            i32 = jnp.int32
-            lrow_f = jnp.stack([lg, lh, lc, lout, bg2[0], blg2[0], blh2[0],
-                                blc2[0], blout2[0], brout2[0], lmin, lmax])
-            rrow_f = jnp.stack([rg, rh, rc, rout, bg2[1], blg2[1], blh2[1],
-                                blc2[1], blout2[1], brout2[1], rmin, rmax])
-            lrow_i = jnp.stack([begin, left_count, depth, nidx, i32(1),
-                                bf2[0], bt2[0], bdl2[0].astype(i32),
-                                bcat2[0].astype(i32)])
-            rrow_i = jnp.stack([begin + left_count, right_count, depth, nidx,
-                                i32(0), bf2[1], bt2[1], bdl2[1].astype(i32),
-                                bcat2[1].astype(i32)])
+            with _scope("split_state"):
+                i32 = jnp.int32
+                lrow_f = jnp.stack([lg, lh, lc, lout, bg2[0], blg2[0], blh2[0],
+                                    blc2[0], blout2[0], brout2[0], lmin, lmax])
+                rrow_f = jnp.stack([rg, rh, rc, rout, bg2[1], blg2[1], blh2[1],
+                                    blc2[1], blout2[1], brout2[1], rmin, rmax])
+                lrow_i = jnp.stack([begin, left_count, depth, nidx, i32(1),
+                                    bf2[0], bt2[0], bdl2[0].astype(i32),
+                                    bcat2[0].astype(i32)])
+                rrow_i = jnp.stack([begin + left_count, right_count, depth,
+                                    nidx, i32(0), bf2[1], bt2[1],
+                                    bdl2[1].astype(i32),
+                                    bcat2[1].astype(i32)])
 
-            if inter:
-                # -- intermediate constraint propagation ---------------
-                # The reference walks up from the new node; at every
-                # monotone numeric ancestor it tightens the bounds of
-                # leaves in the opposite subtree that stay contiguous to
-                # the split leaf, using the new children's outputs
-                # (GoUpToFindLeavesToUpdate / GoDownToFindLeavesToUpdate,
-                # monotone_constraints.hpp:560-850). Here the recursive
-                # down-walk collapses to vectorized [L] box tests: the
-                # contiguity pruning is interval overlap between each
-                # leaf's bin-space box and the split leaf's PRE-split box
-                # on the features crossed so far, and the use-left/right
-                # output choice is overlap with each child's range on the
-                # split feature. Tightened leaves are marked stale and
-                # eagerly re-scanned at the next step's start.
-                plo_vec = st["box_lo"][leaf]           # [F] pre-split box
-                phi_vec = st["box_hi"][leaf]
-                lo_col = st["box_lo"]                  # [L+1, F]
-                hi_col = st["box_hi"]
-                sf_lo = lo_col[:, feat]                # [L+1] on the new
-                sf_hi = hi_col[:, feat]                # split's feature
-                # active leaves whose cached best split is still viable:
-                # the reference skips leaves with best gain == kMinScore
-                # (e.g. at max_depth) — tightening a dead leaf's bounds
-                # only buys pointless re-scan loop trips (each bearing
-                # collectives under voting), and bounds can never turn an
-                # unsplittable leaf splittable (they only shrink gain)
-                splittable = leaf_f[:, 4] > K_MIN_SCORE
-                if max_depth > 0:
-                    splittable &= leaf_i[:, 2] < max_depth
-                row_ok = (iota_l1 < L) & ok & splittable
-                npath_s = st["npath"]
-                BIGB = jnp.int32(1 << 30)
+                if inter:
+                    # -- intermediate constraint propagation ---------------
+                    # The reference walks up from the new node; at every
+                    # monotone numeric ancestor it tightens the bounds of
+                    # leaves in the opposite subtree that stay contiguous to
+                    # the split leaf, using the new children's outputs
+                    # (GoUpToFindLeavesToUpdate / GoDownToFindLeavesToUpdate,
+                    # monotone_constraints.hpp:560-850). Here the recursive
+                    # down-walk collapses to vectorized [L] box tests: the
+                    # contiguity pruning is interval overlap between each
+                    # leaf's bin-space box and the split leaf's PRE-split box
+                    # on the features crossed so far, and the use-left/right
+                    # output choice is overlap with each child's range on the
+                    # split feature. Tightened leaves are marked stale and
+                    # eagerly re-scanned at the next step's start.
+                    plo_vec = st["box_lo"][leaf]           # [F] pre-split box
+                    phi_vec = st["box_hi"][leaf]
+                    lo_col = st["box_lo"]                  # [L+1, F]
+                    hi_col = st["box_hi"]
+                    sf_lo = lo_col[:, feat]                # [L+1] on the new
+                    sf_hi = hi_col[:, feat]                # split's feature
+                    # active leaves whose cached best split is still viable:
+                    # the reference skips leaves with best gain == kMinScore
+                    # (e.g. at max_depth) — tightening a dead leaf's bounds
+                    # only buys pointless re-scan loop trips (each bearing
+                    # collectives under voting), and bounds can never turn an
+                    # unsplittable leaf splittable (they only shrink gain)
+                    splittable = leaf_f[:, 4] > K_MIN_SCORE
+                    if max_depth > 0:
+                        splittable &= leaf_i[:, 2] < max_depth
+                    row_ok = (iota_l1 < L) & ok & splittable
+                    npath_s = st["npath"]
+                    BIGB = jnp.int32(1 << 30)
 
-                def wbody(wst):
-                    a, child_left, crossed, keep, lf_c, stale_c = wst
-                    g = node_i[a, 0]
-                    t_a = node_i[a, 1]
-                    is_num_a = node_i[a, 3] == 0
-                    m_g = mono_arr[g]
-                    opposite_ok = is_num_a & ~crossed[
-                        g, child_left.astype(jnp.int32)]
-                    in_sub = ((npath_s[:, a // 32]
-                               >> (a % 32).astype(jnp.uint32)) & 1) == 1
-                    opp_side = jnp.where(child_left,
-                                         lo_col[:, g] > t_a,
-                                         hi_col[:, g] <= t_a + 1)
-                    opp = in_sub & opp_side
-                    # which child output applies to leaf M: the reference
-                    # flips use_left/use_right only at sf-splits INSIDE the
-                    # opposite subtree — in box terms, M keeps a side unless
-                    # its own sf-range moved past the new threshold relative
-                    # to the subtree ROOT's range (= the subtree extrema)
-                    alo = jnp.min(jnp.where(opp, sf_lo, BIGB))
-                    ahi = jnp.max(jnp.where(opp, sf_hi, -BIGB))
-                    use_l = catv | (sf_lo <= thrv) | (sf_lo == alo)
-                    use_r = catv | (sf_hi > thrv + 1) | (sf_hi == ahi)
-                    both = use_l & use_r
-                    lo_v = jnp.where(both, jnp.minimum(lout, rout),
-                                     jnp.where(use_r, rout, lout))
-                    hi_v = jnp.where(both, jnp.maximum(lout, rout),
-                                     jnp.where(use_r, rout, lout))
-                    cand = (opp & keep & row_ok
-                            & opposite_ok & (m_g != 0))
-                    update_max = jnp.where(m_g > 0, ~child_left, child_left)
-                    cur_lo = lf_c[:, 10]
-                    cur_hi = lf_c[:, 11]
-                    new_hi = jnp.where(cand & update_max,
-                                       jnp.minimum(cur_hi, lo_v), cur_hi)
-                    new_lo = jnp.where(cand & ~update_max,
-                                       jnp.maximum(cur_lo, hi_v), cur_lo)
-                    changed = (new_hi < cur_hi) | (new_lo > cur_lo)
-                    lf_c = lf_c.at[:, 10].set(new_lo).at[:, 11].set(new_hi)
-                    stale_c = stale_c | changed
-                    # record the crossing + the (one-sided) contiguity
-                    # constraint this up-path entry imposes on leaves seen
-                    # from higher ancestors: leaves past the crossed
-                    # threshold in the crossing's direction are pruned
-                    crossed = crossed.at[g, child_left.astype(
-                        jnp.int32)].set(crossed[g, child_left.astype(
-                            jnp.int32)] | opposite_ok)
-                    entry_keep = jnp.where(child_left,
-                                           lo_col[:, g] <= t_a,
-                                           hi_col[:, g] > t_a + 1)
-                    keep = keep & jnp.where(opposite_ok, entry_keep, True)
-                    return (st["node_par"][a], st["node_side"][a] == 1,
-                            crossed, keep, lf_c, stale_c)
+                    def wbody(wst):
+                        a, child_left, crossed, keep, lf_c, stale_c = wst
+                        g = node_i[a, 0]
+                        t_a = node_i[a, 1]
+                        is_num_a = node_i[a, 3] == 0
+                        m_g = mono_arr[g]
+                        opposite_ok = is_num_a & ~crossed[
+                            g, child_left.astype(jnp.int32)]
+                        in_sub = ((npath_s[:, a // 32]
+                                   >> (a % 32).astype(jnp.uint32)) & 1) == 1
+                        opp_side = jnp.where(child_left,
+                                             lo_col[:, g] > t_a,
+                                             hi_col[:, g] <= t_a + 1)
+                        opp = in_sub & opp_side
+                        # which child output applies to leaf M: the reference
+                        # flips use_left/use_right only at sf-splits INSIDE the
+                        # opposite subtree — in box terms, M keeps a side
+                        # unless its own sf-range moved past the new threshold
+                        # relative to the subtree ROOT's range (= the subtree
+                        # extrema)
+                        alo = jnp.min(jnp.where(opp, sf_lo, BIGB))
+                        ahi = jnp.max(jnp.where(opp, sf_hi, -BIGB))
+                        use_l = catv | (sf_lo <= thrv) | (sf_lo == alo)
+                        use_r = catv | (sf_hi > thrv + 1) | (sf_hi == ahi)
+                        both = use_l & use_r
+                        lo_v = jnp.where(both, jnp.minimum(lout, rout),
+                                         jnp.where(use_r, rout, lout))
+                        hi_v = jnp.where(both, jnp.maximum(lout, rout),
+                                         jnp.where(use_r, rout, lout))
+                        cand = (opp & keep & row_ok
+                                & opposite_ok & (m_g != 0))
+                        update_max = jnp.where(m_g > 0, ~child_left,
+                                               child_left)
+                        cur_lo = lf_c[:, 10]
+                        cur_hi = lf_c[:, 11]
+                        new_hi = jnp.where(cand & update_max,
+                                           jnp.minimum(cur_hi, lo_v), cur_hi)
+                        new_lo = jnp.where(cand & ~update_max,
+                                           jnp.maximum(cur_lo, hi_v), cur_lo)
+                        changed = (new_hi < cur_hi) | (new_lo > cur_lo)
+                        lf_c = lf_c.at[:, 10].set(new_lo).at[:, 11].set(new_hi)
+                        stale_c = stale_c | changed
+                        # record the crossing + the (one-sided) contiguity
+                        # constraint this up-path entry imposes on leaves seen
+                        # from higher ancestors: leaves past the crossed
+                        # threshold in the crossing's direction are pruned
+                        crossed = crossed.at[g, child_left.astype(
+                            jnp.int32)].set(crossed[g, child_left.astype(
+                                jnp.int32)] | opposite_ok)
+                        entry_keep = jnp.where(child_left,
+                                               lo_col[:, g] <= t_a,
+                                               hi_col[:, g] > t_a + 1)
+                        keep = keep & jnp.where(opposite_ok, entry_keep, True)
+                        return (st["node_par"][a], st["node_side"][a] == 1,
+                                crossed, keep, lf_c, stale_c)
 
-                a0 = jnp.where(ok, li[3], -1)
-                (_, _, _, _, leaf_f, stale) = lax.while_loop(
-                    lambda wst: wst[0] >= 0, wbody,
-                    (a0, li[4] == 1,
-                     jnp.zeros((F, 2), bool),
-                     jnp.ones(L + 1, bool), leaf_f, stale))
+                    a0 = jnp.where(ok, li[3], -1)
+                    (_, _, _, _, leaf_f, stale) = lax.while_loop(
+                        lambda wst: wst[0] >= 0, wbody,
+                        (a0, li[4] == 1,
+                         jnp.zeros((F, 2), bool),
+                         jnp.ones(L + 1, bool), leaf_f, stale))
 
-            out = dict(
-                perm=perm, perm_buf=pbuf,
-                leaf_f=leaf_f.at[wl].set(lrow_f).at[wn].set(rrow_f),
-                leaf_i=leaf_i.at[wl].set(lrow_i).at[wn].set(rrow_i),
-                leaf_bits=leaf_bits.at[wl].set(bbits2[0])
-                                   .at[wn].set(bbits2[1]),
-                node_f=node_f, node_i=node_i, node_bits=node_bits,
-                hist=hist,
-                num_leaves=st["num_leaves"] + ok.astype(jnp.int32),
-            )
-            if layout_sorted:
-                out["srows"] = srows_new
-                out["srows_buf"] = sbuf
-            if forced is not None:
-                out["forcing"] = forcing_next
-            if ic_on:
-                out["path"] = st["path"].at[wl].set(child_path) \
-                                        .at[wn].set(child_path)
-            if inter:
-                # children inherit the parent's box narrowed on the split
-                # feature (categorical splits scatter bins to both sides;
-                # keeping the parent box is conservative — matches the
-                # host learner's apply_split)
-                l_hi_box = jnp.where(catv, phi_vec,
-                                     phi_vec.at[feat].set(thrv + 1))
-                r_lo_box = jnp.where(catv, plo_vec,
-                                     plo_vec.at[feat].set(thrv + 1))
-                out["box_lo"] = st["box_lo"].at[wl].set(plo_vec) \
-                                            .at[wn].set(r_lo_box)
-                out["box_hi"] = st["box_hi"].at[wl].set(l_hi_box) \
-                                            .at[wn].set(phi_vec)
-                nbit = jnp.where(
-                    jnp.arange(NPW_N, dtype=jnp.int32) == nidx // 32,
-                    jnp.left_shift(jnp.uint32(1),
-                                   (nidx % 32).astype(jnp.uint32)),
-                    jnp.uint32(0))
-                child_npath = st["npath"][leaf] | nbit
-                out["npath"] = st["npath"].at[wl].set(child_npath) \
-                                          .at[wn].set(child_npath)
-                out["stale"] = stale.at[wl].set(False).at[wn].set(False)
-                out["node_par"] = st["node_par"].at[wk].set(li[3])
-                out["node_side"] = st["node_side"].at[wk].set(li[4])
+                out = dict(
+                    perm=perm, perm_buf=pbuf,
+                    leaf_f=leaf_f.at[wl].set(lrow_f).at[wn].set(rrow_f),
+                    leaf_i=leaf_i.at[wl].set(lrow_i).at[wn].set(rrow_i),
+                    leaf_bits=leaf_bits.at[wl].set(bbits2[0])
+                                       .at[wn].set(bbits2[1]),
+                    node_f=node_f, node_i=node_i, node_bits=node_bits,
+                    hist=hist,
+                    num_leaves=st["num_leaves"] + ok.astype(jnp.int32),
+                )
+                if layout_sorted:
+                    out["srows"] = srows_new
+                    out["srows_buf"] = sbuf
+                if forced is not None:
+                    out["forcing"] = forcing_next
+                if ic_on:
+                    out["path"] = st["path"].at[wl].set(child_path) \
+                                            .at[wn].set(child_path)
+                if inter:
+                    # children inherit the parent's box narrowed on the split
+                    # feature (categorical splits scatter bins to both sides;
+                    # keeping the parent box is conservative — matches the
+                    # host learner's apply_split)
+                    l_hi_box = jnp.where(catv, phi_vec,
+                                         phi_vec.at[feat].set(thrv + 1))
+                    r_lo_box = jnp.where(catv, plo_vec,
+                                         plo_vec.at[feat].set(thrv + 1))
+                    out["box_lo"] = st["box_lo"].at[wl].set(plo_vec) \
+                                                .at[wn].set(r_lo_box)
+                    out["box_hi"] = st["box_hi"].at[wl].set(l_hi_box) \
+                                                .at[wn].set(phi_vec)
+                    nbit = jnp.where(
+                        jnp.arange(NPW_N, dtype=jnp.int32) == nidx // 32,
+                        jnp.left_shift(jnp.uint32(1),
+                                       (nidx % 32).astype(jnp.uint32)),
+                        jnp.uint32(0))
+                    child_npath = st["npath"][leaf] | nbit
+                    out["npath"] = st["npath"].at[wl].set(child_npath) \
+                                              .at[wn].set(child_npath)
+                    out["stale"] = stale.at[wl].set(False).at[wn].set(False)
+                    out["node_par"] = st["node_par"].at[wk].set(li[3])
+                    out["node_side"] = st["node_side"].at[wk].set(li[4])
             return out
 
         if L > 1:
             state = lax.fori_loop(0, NODES, split_step, state)
 
         # -------------------------------------------------- row -> leaf id
-        # leaves with zero (local) rows would duplicate another leaf's begin
-        # offset — push them past the end so searchsorted never picks them
-        # (common under sharding: a leaf can be empty on one shard)
-        leaf_begin = jnp.where(state["leaf_i"][:L, 1] > 0,
-                               state["leaf_i"][:L, 0],
-                               N + jnp.arange(L, dtype=jnp.int32))
-        order = jnp.argsort(leaf_begin)
-        sorted_begin = leaf_begin[order]
-        which = jnp.searchsorted(sorted_begin,
-                                 jnp.arange(N, dtype=jnp.int32),
-                                 side="right") - 1
-        pos_leaf = order[which]
-        row_leaf = jnp.zeros(N, jnp.int32).at[state["perm"][:N]].set(pos_leaf)
+        with _scope("row_leaf"):
+            # leaves with zero (local) rows would duplicate another leaf's
+            # begin offset — push them past the end so searchsorted never
+            # picks them (common under sharding: a leaf can be empty on one
+            # shard)
+            leaf_begin = jnp.where(state["leaf_i"][:L, 1] > 0,
+                                   state["leaf_i"][:L, 0],
+                                   N + jnp.arange(L, dtype=jnp.int32))
+            order = jnp.argsort(leaf_begin)
+            sorted_begin = leaf_begin[order]
+            which = jnp.searchsorted(sorted_begin,
+                                     jnp.arange(N, dtype=jnp.int32),
+                                     side="right") - 1
+            pos_leaf = order[which]
+            row_leaf = jnp.zeros(N, jnp.int32).at[
+                state["perm"][:N]].set(pos_leaf)
 
-        node_f = state["node_f"]
-        node_i = state["node_i"]
-        leaf_f = state["leaf_f"]
-        leaf_i = state["leaf_i"]
-        # an unsplittable tree contributes NOTHING — the reference turns
-        # one-leaf trees into constant-0 trees (gbdt.cpp:408-436
-        # AsConstantTree(0); the host learner matches); without this the
-        # fused fast path would add the root's Newton step every round
-        leaf_value_out = jnp.where(state["num_leaves"] > 1,
-                                   leaf_f[:L, 3],
-                                   jnp.zeros_like(leaf_f[:L, 3]))
-        if quant and cfg.quant_train_renew_leaf:
-            # re-fit leaf outputs with the full-precision gradient sums
-            # (reference: GradientDiscretizer::RenewIntGradTreeOutput)
-            gsum = jax.ops.segment_sum(grad, row_leaf, num_segments=L)
-            hsum = jax.ops.segment_sum(hess, row_leaf, num_segments=L)
-            if self.axis is not None:
-                gsum = lax.psum(gsum, self.axis)
-                hsum = lax.psum(hsum, self.axis)
-            parent_out = node_f[jnp.clip(leaf_i[:L, 3], 0, NODES - 1), 1]
-            renewed = calculate_leaf_output(gsum, hsum, p, leaf_f[:L, 2],
-                                            parent_out)
-            # renew only real trees: a one-leaf tree stays constant-0
-            active = ((jnp.arange(L, dtype=jnp.int32) < state["num_leaves"])
-                      & (state["num_leaves"] > 1))
-            leaf_value_out = jnp.where(active, renewed, leaf_value_out)
-        return DeviceTree(
-            node_feature=node_i[:NODES, 0],
-            node_threshold=node_i[:NODES, 1],
-            node_default_left=node_i[:NODES, 2].astype(bool),
-            node_is_cat=node_i[:NODES, 3].astype(bool),
-            node_cat_bits=state["node_bits"][:NODES],
-            node_left=node_i[:NODES, 4],
-            node_right=node_i[:NODES, 5],
-            node_gain=node_f[:NODES, 0],
-            node_value=node_f[:NODES, 1],
-            node_weight=node_f[:NODES, 2],
-            node_count=node_f[:NODES, 3],
-            leaf_value=leaf_value_out,
-            leaf_weight=leaf_f[:L, 1],
-            leaf_count=leaf_f[:L, 2],
-            leaf_depth=leaf_i[:L, 2],
-            leaf_parent_node=leaf_i[:L, 3],
-            num_leaves=state["num_leaves"],
-            row_leaf=row_leaf,
-        )
+            node_f = state["node_f"]
+            node_i = state["node_i"]
+            leaf_f = state["leaf_f"]
+            leaf_i = state["leaf_i"]
+            # an unsplittable tree contributes NOTHING — the reference turns
+            # one-leaf trees into constant-0 trees (gbdt.cpp:408-436
+            # AsConstantTree(0); the host learner matches); without this the
+            # fused fast path would add the root's Newton step every round
+            leaf_value_out = jnp.where(state["num_leaves"] > 1,
+                                       leaf_f[:L, 3],
+                                       jnp.zeros_like(leaf_f[:L, 3]))
+            if quant and cfg.quant_train_renew_leaf:
+                # re-fit leaf outputs with the full-precision gradient sums
+                # (reference: GradientDiscretizer::RenewIntGradTreeOutput)
+                gsum = jax.ops.segment_sum(grad, row_leaf, num_segments=L)
+                hsum = jax.ops.segment_sum(hess, row_leaf, num_segments=L)
+                if self.axis is not None:
+                    gsum = lax.psum(gsum, self.axis)
+                    hsum = lax.psum(hsum, self.axis)
+                parent_out = node_f[jnp.clip(leaf_i[:L, 3], 0, NODES - 1), 1]
+                renewed = calculate_leaf_output(gsum, hsum, p, leaf_f[:L, 2],
+                                                parent_out)
+                # renew only real trees: a one-leaf tree stays constant-0
+                active = ((jnp.arange(L, dtype=jnp.int32)
+                           < state["num_leaves"])
+                          & (state["num_leaves"] > 1))
+                leaf_value_out = jnp.where(active, renewed, leaf_value_out)
+            return DeviceTree(
+                node_feature=node_i[:NODES, 0],
+                node_threshold=node_i[:NODES, 1],
+                node_default_left=node_i[:NODES, 2].astype(bool),
+                node_is_cat=node_i[:NODES, 3].astype(bool),
+                node_cat_bits=state["node_bits"][:NODES],
+                node_left=node_i[:NODES, 4],
+                node_right=node_i[:NODES, 5],
+                node_gain=node_f[:NODES, 0],
+                node_value=node_f[:NODES, 1],
+                node_weight=node_f[:NODES, 2],
+                node_count=node_f[:NODES, 3],
+                leaf_value=leaf_value_out,
+                leaf_weight=leaf_f[:L, 1],
+                leaf_count=leaf_f[:L, 2],
+                leaf_depth=leaf_i[:L, 2],
+                leaf_parent_node=leaf_i[:L, 3],
+                num_leaves=state["num_leaves"],
+                row_leaf=row_leaf,
+                work=node_i[:NODES, 6:8],
+            )
 
     # ------------------------------------------------------------------
     # data_residency=stream: out-of-core tree build

@@ -31,7 +31,7 @@ from ..ops.predict_tensor import (build_tree_tiles, predict_forest_leaf_tensor,
                                   predict_forest_tensor)
 from ..guard.nonfinite import NULL_GUARD, TrainGuard
 from ..obs import costplane
-from ..obs.telemetry import NULL_TELEMETRY, TrainTelemetry
+from ..obs.telemetry import NULL_TELEMETRY, TrainTelemetry, device_scope
 from ..utils import log
 from .learner import SerialTreeLearner
 from .sample_strategy import create_sample_strategy
@@ -64,6 +64,16 @@ def _cegb_requested(cfg) -> bool:
         cfg.cegb_penalty_split > 0
         or cfg.cegb_penalty_feature_coupled
         or cfg.cegb_penalty_feature_lazy)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _score_update(scores, leaf_values, row_leaf, k: int):
+    """scores[k] += leaf_values[row_leaf]: the zero-sync path's score update
+    as ONE program, so that its ops carry the ``score_update`` scope (an
+    eagerly dispatched op is compiled from a cache that knows no name
+    stack, and a profiler trace then shows an anonymous jit_gather)."""
+    with device_scope("score_update"):
+        return scores.at[k].add(leaf_values[row_leaf])
 
 
 @functools.partial(jax.jit, static_argnames=("num_leaves",))
@@ -591,12 +601,20 @@ class GBDT:
                     rec = self.learner.train_device(grad[k], hess[k],
                                                     row_mask=mask)
                 with tel.phase("score_update"):
+                    # the L-value multiply stays a dispatch of its own:
+                    # inside the program it could contract with the add
+                    # into one rounding, and kill-and-resume rebuilds the
+                    # scores from leaf values rounded on their own
                     lv = rec.leaf_value * self.shrinkage_rate
-                    self.scores = self.scores.at[k].add(lv[rec.row_leaf])
+                    self.scores = _score_update(self.scores, lv,
+                                                rec.row_leaf, k)
+                if rec.work is not None:
+                    tel.defer_counts((rec.work, rec.num_leaves),
+                                     self.learner.work_counts)
                 # drop the O(N) row->leaf map from the kept record: at
                 # 10.5M rows x 500 trees it would pin ~21 GB of HBM that
-                # materialization never reads
-                rec = rec._replace(row_leaf=None)
+                # materialization never reads (the work counts go with it)
+                rec = rec._replace(row_leaf=None, work=None)
                 lazy = _LazyTree(self.learner, rec, self.shrinkage_rate,
                                  init_scores[k])
                 self.models.append(lazy)
@@ -1452,3 +1470,7 @@ from ..analysis.ir.contracts import register_program
 register_program(
     "gbdt._add_tree_score", collective_free=True,
     notes="score accumulation after each tree; device-resident add")
+register_program(
+    "gbdt._score_update", collective_free=True,
+    notes="zero-sync path's score update: leaf values gathered by the "
+          "fused program's row->leaf map")

@@ -16,6 +16,7 @@ import numpy as np
 
 from ..config import Config
 from ..data.dataset import Metadata
+from ..obs.telemetry import device_scope
 from ..utils import log
 
 K_EPSILON = 1e-15
@@ -72,7 +73,8 @@ class ObjectiveFunction:
                 for f, a in zip(fields, arrs):
                     setattr(self, f, a)
                 try:
-                    return self.get_gradients(scores)
+                    with device_scope("gradients"):
+                        return self.get_gradients(scores)
                 finally:
                     for f, s in zip(fields, saved):
                         setattr(self, f, s)

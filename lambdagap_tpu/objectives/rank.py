@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import Config
+from ..obs.telemetry import device_scope
 from ..utils import log
 from .base import K_EPSILON, ObjectiveFunction, register_objective
 
@@ -167,26 +168,28 @@ class RankingBase(ObjectiveFunction):
         has_pos = self.positions is not None
 
         def loop(s, label, positions, pos_biases, key, idxs, auxs):
-            if has_pos:
-                s = s + pos_biases[positions]
-            grad = jnp.zeros(num_data + 1, jnp.float32)
-            hess = jnp.zeros(num_data + 1, jnp.float32)
-            pad_s = jnp.concatenate([s, jnp.asarray([K_MIN_SCORE], s.dtype)])
-            pad_l = jnp.concatenate([label,
-                                     jnp.asarray([0.0], label.dtype)])
-            eff_sum = jnp.float32(0.0)
-            for idx_d, aux in zip(idxs, auxs):
-                sb = pad_s[idx_d]
-                lb = pad_l[idx_d]
-                vb = idx_d < num_data
-                lam, hes, eff = self._bucket_gradients_k(sb, lb, vb, aux,
-                                                         key)
-                grad = grad.at[idx_d.reshape(-1)].add(lam.reshape(-1),
-                                                      mode="drop")
-                hess = hess.at[idx_d.reshape(-1)].add(hes.reshape(-1),
-                                                      mode="drop")
-                eff_sum = eff_sum + jnp.sum(eff)
-            return grad[:-1], hess[:-1], eff_sum
+            with device_scope("gradients"):
+                if has_pos:
+                    s = s + pos_biases[positions]
+                grad = jnp.zeros(num_data + 1, jnp.float32)
+                hess = jnp.zeros(num_data + 1, jnp.float32)
+                pad_s = jnp.concatenate([s, jnp.asarray([K_MIN_SCORE],
+                                                        s.dtype)])
+                pad_l = jnp.concatenate([label,
+                                         jnp.asarray([0.0], label.dtype)])
+                eff_sum = jnp.float32(0.0)
+                for idx_d, aux in zip(idxs, auxs):
+                    sb = pad_s[idx_d]
+                    lb = pad_l[idx_d]
+                    vb = idx_d < num_data
+                    lam, hes, eff = self._bucket_gradients_k(sb, lb, vb, aux,
+                                                             key)
+                    grad = grad.at[idx_d.reshape(-1)].add(lam.reshape(-1),
+                                                          mode="drop")
+                    hess = hess.at[idx_d.reshape(-1)].add(hes.reshape(-1),
+                                                          mode="drop")
+                    eff_sum = eff_sum + jnp.sum(eff)
+                return grad[:-1], hess[:-1], eff_sum
 
         idxs = tuple(jnp.asarray(idx) for (_, _, idx)
                      in self.bucketing.buckets)

@@ -59,6 +59,38 @@ PHASES = ("gradients", "sampling", "layout_apply", "histogram", "split",
           "partition", "tree", "score_update", "eval", "device_wait",
           "h2d_prefetch", "chunk_wait", "d2h_scores")
 
+# the closed vocabulary of ``jax.named_scope`` names on the DEVICE side: every
+# op of the fused tree program, the sorted-layout pre-pass, the gradient
+# program and the score update is traced under exactly one innermost
+# ("leaf") scope of this list (tests/test_scopes.py lowers the programs and
+# checks it), so a profiler window tiles device time by these names the way
+# PHASES tile the host's wall. ``partition_decide`` / ``partition_scatter``
+# open INSIDE ``partition`` (the while body), and the mesh learners'
+# collectives open ``hist_allreduce`` inside whatever scope calls them: a
+# selector on a path component reads the outer scope with what it holds.
+# docs/observability.md has the table of what goes under each.
+DEVICE_SCOPES = ("histogram", "partition", "partition_decide",
+                 "partition_scatter", "partition_copyback", "split_scan",
+                 "tree_init", "leaf_select", "split_state", "hist_subtract",
+                 "hist_allreduce", "row_leaf", "layout_apply", "gradients",
+                 "score_update")
+
+# names on the profiler's clock: one ITER_ANNOTATION per boosting iteration
+# (begin_iteration .. end_iteration, stat ``iter``) and one
+# PHASE_ANNOTATION + <phase> per span, device_wait included
+ITER_ANNOTATION = "lg_iter"
+PHASE_ANNOTATION = "lg_phase:"
+
+
+def device_scope(name: str):
+    """``jax.named_scope(name)`` for a name of DEVICE_SCOPES (and only
+    those: the vocabulary is closed, a typo fails at trace time)."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"{name!r} is not in obs.telemetry.DEVICE_SCOPES")
+    import jax
+    return jax.named_scope(name)
+
+
 # phase -> the utils.timer scope name it replaces (the deprecation shim:
 # the legacy global_timer report keeps its historical row names)
 _LEGACY = {
@@ -84,15 +116,18 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     """One live phase span; see TrainTelemetry.phase."""
-    __slots__ = ("tel", "name", "legacy")
+    __slots__ = ("tel", "name", "legacy", "ann")
 
     def __init__(self, tel: "TrainTelemetry", name: str,
                  legacy: Optional[str]) -> None:
         self.tel = tel
         self.name = name
         self.legacy = legacy
+        self.ann = tel._annotate_phase(name)
 
     def __enter__(self):
+        # the same span on the profiler's clock (a no-op outside a trace)
+        self.ann.__enter__()
         # stack frame: [name, t_enter, child_inclusive_acc]
         self.tel._stack.append([self.name, time.perf_counter(), 0.0])
         return self
@@ -101,6 +136,7 @@ class _Span:
         tel = self.tel
         name, t0, child = tel._stack.pop()
         dt = time.perf_counter() - t0
+        self.ann.__exit__(*exc)
         tel._add_phase(name, dt - child, dt, self.legacy)
         if tel._stack:
             tel._stack[-1][2] += dt
@@ -136,6 +172,12 @@ class TrainTelemetry:
         self.profile = profile
         if not self.enabled:
             return
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self._iter_ann = None
+        # (device arrays, reducer) pairs read after end_iteration's block
+        self._deferred: List[tuple] = []
+        self.work_totals: Dict[str, int] = {}
         self.wall_res = Reservoir(cap=4096, seed=5)
         if out:
             self.run_log = RunLog(out, params=params)
@@ -179,6 +221,20 @@ class TrainTelemetry:
             return _NULL_SPAN
         return _Span(self, name, legacy)
 
+    def _annotate_phase(self, name: str):
+        return self._annotation(PHASE_ANNOTATION + name,
+                                iter=self._cur["iter"])
+
+    def defer_counts(self, arrays: Any, reducer) -> None:
+        """Work counts of the open iteration, without a sync: ``arrays``
+        (device arrays the iteration's programs returned) are fetched after
+        :meth:`end_iteration`'s one ``block_until_ready`` and
+        ``reducer(host_arrays) -> {name: int}`` is summed into the
+        record's ``counts``. Nothing is kept when telemetry is off."""
+        if not self.enabled or self._cur is None:
+            return
+        self._deferred.append((arrays, reducer))
+
     def begin_iteration(self, iteration: int) -> None:
         """Open the record for ``iteration`` (finalizing the previous
         one). Called at the top of ``GBDT.train_one_iter``."""
@@ -196,6 +252,9 @@ class TrainTelemetry:
         self._cur = {"type": "iteration", "iter": int(iteration),
                      "phases": {}}
         self._train_done = False
+        self._iter_ann = self._annotation(ITER_ANNOTATION,
+                                          iter=int(iteration))
+        self._iter_ann.__enter__()
         self._t0 = time.perf_counter()
 
     def end_iteration(self, sync: Any = None) -> None:
@@ -206,17 +265,20 @@ class TrainTelemetry:
         (eval) until the next :meth:`begin_iteration`."""
         if not self.enabled or self._cur is None or self._train_done:
             return
+        import jax
         t = time.perf_counter()
         if sync is not None:
-            try:
-                import jax
-                jax.block_until_ready(sync)
-            except Exception:  # pragma: no cover - deleted buffers etc.
-                pass
+            with self._annotate_phase("device_wait"):
+                try:
+                    jax.block_until_ready(sync)
+                except Exception:  # pragma: no cover - deleted buffers etc.
+                    pass
         now = time.perf_counter()
         self._add_phase("device_wait", now - t, now - t, None)
         self._cur["wall_s"] = now - self._t0
+        self._close_iter_annotation()
         self._stamp_watch()
+        self._read_deferred()
         self._train_done = True
         self.watchdog.set_iteration(None)
 
@@ -260,6 +322,29 @@ class TrainTelemetry:
             log.warning("cost plane: COSTS.json write failed: %s", e)
 
     # -- internals ------------------------------------------------------
+    def _close_iter_annotation(self) -> None:
+        if self._iter_ann is not None:
+            self._iter_ann.__exit__(None, None, None)
+            self._iter_ann = None
+
+    def _read_deferred(self) -> None:
+        """The iteration's work counts: one small D2H of what
+        :meth:`defer_counts` queued, after the iteration's block (the
+        programs that wrote them have finished) and outside every phase
+        and ``wall_s``."""
+        if not self._deferred:
+            return
+        import jax
+        pending, self._deferred = self._deferred, []
+        counts: Dict[str, int] = {}
+        for host, (_, reducer) in zip(
+                jax.device_get([arrays for arrays, _ in pending]), pending):
+            for k, v in reducer(host).items():
+                counts[k] = counts.get(k, 0) + int(v)
+        self._cur["counts"] = counts
+        for k, v in counts.items():
+            self.work_totals[k] = self.work_totals.get(k, 0) + v
+
     def _add_phase(self, name: str, exclusive: float, inclusive: float,
                    legacy: Optional[str]) -> None:
         if self._cur is not None:
@@ -300,7 +385,9 @@ class TrainTelemetry:
         rec = self._cur
         if "wall_s" not in rec:         # end_iteration never ran
             rec["wall_s"] = time.perf_counter() - self._t0
+            self._close_iter_annotation()
             self._stamp_watch()
+            self._deferred = []
         # round phase seconds for a compact JSONL (µs resolution)
         rec["phases"] = {k: round(v, 6) for k, v in rec["phases"].items()}
         rec["wall_s"] = round(rec["wall_s"], 6)
@@ -326,6 +413,8 @@ class TrainTelemetry:
                                        for k, v in sorted(self.totals.items())},
             "iter_wall_s": self.wall_res.percentiles(),
         }
+        if self.work_totals:
+            out["counts_total"] = dict(sorted(self.work_totals.items()))
         out.update({k: v for k, v in self.watchdog.totals().items()
                     if k in ("compiles", "steady_compiles", "transfers",
                              "compile_secs")})

@@ -98,36 +98,24 @@ class XlaWatchdog:
     def uninstall(self) -> None:
         if not self.installed:
             return
-        if not self._uninstall_public():
+        import jax.monitoring
+        pairs = (("unregister_event_listener", "_event_listeners",
+                  self._on_event),
+                 ("unregister_event_duration_listener",
+                  "_event_duration_secs_listeners", self._on_duration))
+        for public, private, callback in pairs:
             try:
-                from jax._src import monitoring as _m
-                _m._unregister_event_listener_by_callback(self._on_event)
-                _m._unregister_event_duration_listener_by_callback(
-                    self._on_duration)
+                unregister = getattr(jax.monitoring, public, None)
+                if unregister is not None:
+                    unregister(callback)
+                else:       # older jax: no unregister API, drop it by hand
+                    from jax._src import monitoring as _m
+                    getattr(_m, private).remove(callback)
             except Exception:  # pragma: no cover - jax internals moved
-                log.warning("could not unregister jax.monitoring "
-                            "listeners; the watchdog callbacks stay "
+                log.warning("could not unregister a jax.monitoring "
+                            "listener; the watchdog callback stays "
                             "registered (harmless but counted across runs)")
         self.installed = False
-
-    def _uninstall_public(self) -> bool:
-        """Prefer a public unregister API when the jax version grows one
-        (the `_src` fallback below is version-coupled); returns True when
-        both listeners were removed publicly."""
-        import jax.monitoring
-        unreg_ev = getattr(jax.monitoring,
-                           "unregister_event_listener_by_callback", None)
-        unreg_dur = getattr(
-            jax.monitoring,
-            "unregister_event_duration_listener_by_callback", None)
-        if unreg_ev is None or unreg_dur is None:
-            return False
-        try:
-            unreg_ev(self._on_event)
-            unreg_dur(self._on_duration)
-            return True
-        except Exception:  # pragma: no cover - listener already gone
-            return False
 
     def set_iteration(self, iteration: Optional[int]) -> None:
         self.iteration = iteration

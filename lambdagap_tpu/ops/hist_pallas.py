@@ -143,6 +143,9 @@ def _vmem_limit(blk: int, fblk: int, B: int, bins_itemsize: int) -> int:
     return int(min(max(need * 5 // 4, 16 << 20), 100 << 20))
 
 
+KERNEL_NAME = "lg_hist"
+
+
 def _hist_call(bins: jax.Array, gh: jax.Array, num_bins: int, count,
                acc_dtype) -> jax.Array:
     """Pad to the block grid, run the kernel, return ``[8, F, B]`` channel
@@ -182,6 +185,9 @@ def _hist_call(bins: jax.Array, gh: jax.Array, num_bins: int, count,
             vmem_limit_bytes=_vmem_limit(blk, fblk, B,
                                          bins.dtype.itemsize)),
         interpret=_interpret(),
+        # the kernel's name in a profiler trace: selects the kernel alone,
+        # apart from the slice/unpack/pack that feed it under ``histogram``
+        name=KERNEL_NAME,
     )(count, bins, gh)
     return out.reshape(8, Fp, B)[:, :F]
 

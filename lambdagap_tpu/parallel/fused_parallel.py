@@ -751,7 +751,7 @@ class Fused2DTreeLearner(FusedTreeLearner):
             self._s2_final_body, in_specs=(st,),
             out_specs=DeviceTree(**{
                 f: spec("row_leaf") if f == "row_leaf" else spec("tree")
-                for f in DeviceTree._fields})))
+                for f in DeviceTree._fields if f != "work"})))
 
     # -- per-device kernel bodies (local views inside shard_map) --------
     def _s2_best_of(self, hist, pg, ph, pc, pout, depth, fm):

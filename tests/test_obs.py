@@ -125,6 +125,81 @@ def test_off_path_no_records_no_hooks():
     assert final == before
 
 
+@pytest.mark.parametrize("public_api", [True, False],
+                         ids=["jax_unregister", "older_jax"])
+def test_watchdog_uninstall_removes_both_listeners(public_api, monkeypatch):
+    """install/uninstall leave jax.monitoring as they found it, through
+    jax's own unregister functions and, on a jax that has none, by taking
+    the callbacks out of its listener lists."""
+    import jax.monitoring
+    from jax._src import monitoring as m
+    from lambdagap_tpu.obs.xla_watch import XlaWatchdog
+    if not public_api:
+        monkeypatch.delattr(jax.monitoring, "unregister_event_listener")
+        monkeypatch.delattr(jax.monitoring,
+                            "unregister_event_duration_listener")
+    before = (list(m.get_event_listeners()),
+              list(m.get_event_duration_listeners()))
+    dogs = [XlaWatchdog(), XlaWatchdog()]
+    for dog in dogs:
+        dog.install()
+    assert len(m.get_event_listeners()) == len(before[0]) + 2
+    for dog in dogs:
+        dog.uninstall()
+        dog.uninstall()                       # idempotent
+        assert not dog.installed
+    assert (list(m.get_event_listeners()),
+            list(m.get_event_duration_listeners())) == before
+
+
+def _host_annotations(trace_dir):
+    """(name, iter) of every ``lg_*`` annotation in a profiler trace."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return [(ev.name, dict(ev.stats).get("iter"))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("lg_")]
+
+
+@pytest.mark.parametrize("telemetry", [True, False], ids=["on", "off"])
+def test_spans_reach_the_profilers_clock(telemetry, tmp_path, monkeypatch):
+    """With telemetry on the program itself annotates the profiler's
+    timeline: one ``lg_iter`` per iteration and one ``lg_phase:<name>`` per
+    span, device_wait included, each with the iteration number. Off: not
+    one annotation, not one span object."""
+    import jax
+    from lambdagap_tpu.obs import telemetry as tmod
+    built = []
+    init = tmod._Span.__init__
+    monkeypatch.setattr(tmod._Span, "__init__", lambda self, *a: (
+        built.append(a[1]), init(self, *a))[1])
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        b = _train({"telemetry": telemetry, "tpu_fused_learner": 1,
+                    "tree_layout": "sorted"}, rounds=3)
+    finally:
+        jax.profiler.stop_trace()
+    found = _host_annotations(str(tmp_path))
+    if not telemetry:
+        assert found == [] and built == []
+        assert not hasattr(b._booster.telemetry, "_annotation")
+        return
+    assert [it for name, it in found if name == tmod.ITER_ANNOTATION] \
+        == [0, 1, 2]
+    for it in range(3):
+        phases = [name[len(tmod.PHASE_ANNOTATION):] for name, i in found
+                  if i == it and name.startswith(tmod.PHASE_ANNOTATION)]
+        assert phases[:6] == ["gradients", "sampling", "tree",
+                              "layout_apply", "score_update",
+                              "device_wait"], phases
+    assert set(built) <= set(tmod.PHASES)
+
+
 # -- Prometheus ---------------------------------------------------------
 _PROM_HEADER = re.compile(r"^# (HELP|TYPE) [a-zA-Z_:][a-zA-Z0-9_:]* .+$")
 _PROM_SAMPLE = re.compile(

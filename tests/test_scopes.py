@@ -1,0 +1,278 @@
+"""The device side of the tracing: the closed vocabulary of named scopes
+tiles the hot programs (obs.telemetry.DEVICE_SCOPES), the histogram kernel
+carries its name, and the iteration record's work counts are exact.
+Counts and names only: nothing here reads a time."""
+import collections
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import lambdagap_tpu as lgb
+from lambdagap_tpu.obs.telemetry import DEVICE_SCOPES, device_scope
+from lambdagap_tpu.ops.hist_pallas import KERNEL_NAME
+
+# ops that move values around and compute nothing: they may sit outside
+# every scope (a constant carries the location of whatever first used it)
+PLUMBING = {"func.func", "func.return", "func.call", "stablehlo.return",
+            "stablehlo.constant", "stablehlo.tuple",
+            "stablehlo.get_tuple_element", "stablehlo.while",
+            "stablehlo.case", "stablehlo.if"}
+# the split loop's own trip counter (``fori_loop`` is opened under no scope:
+# a scope around it would become a path component of every op inside)
+LOOP_COUNTER = re.compile(r"^jit\(\w+\)/while/(cond/lt|body/add)$")
+
+
+def _loc_name(op) -> str:
+    m = re.match(r'loc\("([^"]*)"', str(op.location))
+    return m.group(1) if m else ""
+
+
+def _leaf_scope(name: str):
+    found = [c for c in name.split("/") if c in DEVICE_SCOPES]
+    return found[-1] if found else None
+
+
+def scope_census(lowered):
+    """(ops per innermost scope, [(function, op, location name)] of the ops
+    under none) of a lowered program. An op of a private function (one
+    ``cumsum`` or ``where`` body shared by its callers) counts as scoped
+    when every call of that function is."""
+    ops, calls = [], collections.defaultdict(list)
+
+    def walk(op, fn):
+        for region in op.regions:
+            for block in region.blocks:
+                for child in block.operations:
+                    kind, name = child.operation.name, _loc_name(child)
+                    if kind == "func.func":
+                        walk(child,
+                             str(child.attributes["sym_name"]).strip('"'))
+                        continue
+                    if kind == "func.call":
+                        callee = str(child.attributes["callee"]).lstrip("@")
+                        calls[callee].append((fn, _leaf_scope(name)))
+                    ops.append((fn, kind, name))
+                    walk(child, fn)
+
+    walk(lowered.compiler_ir(dialect="stablehlo").operation, None)
+    memo = {}
+
+    def by_call_site(fn) -> bool:
+        if fn not in calls:
+            return False
+        if fn not in memo:
+            memo[fn] = False
+            memo[fn] = all(scope is not None or by_call_site(caller)
+                           for caller, scope in calls[fn])
+        return memo[fn]
+
+    scoped, outside = collections.Counter(), []
+    for fn, kind, name in ops:
+        scope = _leaf_scope(name)
+        if scope is not None:
+            scoped[scope] += 1
+        elif kind not in PLUMBING and not by_call_site(fn) \
+                and not LOOP_COUNTER.match(name):
+            outside.append((fn, kind, name))
+    return scoped, outside
+
+
+def _learner(layout: str, hist: str = "onehot", rows: int = 600,
+             leaves: int = 8):
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(rows, 5)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] ** 2 > 0.5).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": leaves, "max_bin": 15,
+              "tree_layout": layout, "tpu_fused_learner": 1,
+              "tpu_hist_impl": hist, "min_data_in_leaf": 1, "verbose": -1}
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    learner = bst._booster.learner
+    assert (type(learner).__name__, learner.layout, learner.hist_impl) \
+        == ("FusedTreeLearner", layout, hist)
+    return bst, learner
+
+
+def _lower_tree(learner, has_mask: bool):
+    """The layout program (sorted only) and the tree program, lowered at the
+    learner's own shapes."""
+    n = learner.num_data
+    grad, hess = jnp.zeros(n), jnp.ones(n)
+    mask = jnp.ones(n if has_mask else 1, bool)
+    q = jnp.zeros(1, jnp.int8)
+    out = {}
+    srows = learner._srows_dummy
+    if learner.layout == "sorted":
+        args = (grad, hess, mask, learner.hx_rows, q, q)
+        out["layout"] = learner._layout_jit.lower(*args, has_mask=has_mask)
+        srows = jax.eval_shape(
+            lambda *a: learner._build_sorted_impl(*a, has_mask=has_mask),
+            *args)
+    out["tree"] = learner._train_jit.lower(
+        grad, hess, mask, learner._feature_mask(), learner.hx_rows,
+        learner.x_cols, srows, q, q, jnp.float32(1), jnp.float32(1),
+        jnp.zeros((2, 2), jnp.uint32), has_mask=has_mask)
+    return out
+
+
+@pytest.mark.parametrize("has_mask", [False, True], ids=["all_rows", "mask"])
+@pytest.mark.parametrize("layout", ["gather", "sorted"])
+def test_every_op_of_the_tree_programs_is_under_one_scope(layout, has_mask):
+    _, learner = _learner(layout)
+    lowered = _lower_tree(learner, has_mask)
+    scoped, outside = scope_census(lowered["tree"])
+    assert outside == []
+    # the four names the accepted metrics select, and this PR's
+    assert set(scoped) >= {
+        "histogram", "partition", "partition_copyback", "split_scan",
+        "tree_init", "leaf_select", "partition_decide", "partition_scatter",
+        "split_state", "hist_subtract", "row_leaf"}
+    assert set(scoped) <= set(DEVICE_SCOPES)
+    if layout == "sorted":
+        scoped, outside = scope_census(lowered["layout"])
+        assert outside == [] and set(scoped) == {"layout_apply"}
+
+
+def test_scope_nesting_is_only_where_the_vocabulary_says():
+    """``partition`` holds ``partition_decide`` and ``partition_scatter``;
+    every other pair of scopes is disjoint, so selectors on a path
+    component tile the program."""
+    _, learner = _learner("sorted")
+    module = _lower_tree(learner, False)["tree"].as_text(debug_info=True)
+    pairs = set()
+    for name in set(re.findall(r'loc\("([^"]*)"', module)):
+        found = [c for c in name.split("/") if c in DEVICE_SCOPES]
+        pairs |= {(a, b) for i, a in enumerate(found) for b in found[i + 1:]
+                  if a != b}
+    assert pairs == {("partition", "partition_decide"),
+                     ("partition", "partition_scatter")}
+
+
+@pytest.mark.parametrize("program", ["fn", "loop", "score_update"])
+def test_gradient_and_score_programs_are_scoped(program):
+    if program == "score_update":
+        from lambdagap_tpu.models.gbdt import _score_update
+        lowered = _score_update.lower(
+            jnp.zeros((1, 64)), jnp.zeros(8), jnp.zeros(64, jnp.int32), k=0)
+        want = "score_update"
+    else:
+        rng = np.random.default_rng(1)
+        X = rng.normal(size=(240, 4)).astype(np.float32)
+        if program == "fn":
+            params = {"objective": "binary", "verbose": -1}
+            ds = lgb.Dataset(X, label=(X[:, 0] > 0).astype(np.float32))
+        else:
+            params = {"objective": "lambdarank", "verbose": -1}
+            ds = lgb.Dataset(X, label=rng.integers(0, 4, 240).astype(
+                np.float32), group=[24] * 10)
+        bst = lgb.Booster(params, ds)
+        gb = bst._booster
+        gb.boosting()                       # builds the jitted program
+        obj = gb.objective
+        if program == "fn":
+            lowered = obj._grad_jit.lower(gb.scores, *[
+                getattr(obj, f) for f in obj._GRAD_ARRAY_FIELDS
+                if getattr(obj, f, None) is not None])
+        else:
+            lowered = obj._loop_jit.lower(
+                gb.scores[0], obj.label, jnp.zeros(1, jnp.int32),
+                jnp.zeros(1, jnp.float32), obj._next_key(), obj._loop_idxs,
+                obj._loop_auxs)
+        assert f"jit_{program}" in lowered.as_text()[:200]
+        want = "gradients"
+    scoped, outside = scope_census(lowered)
+    assert outside == [] and set(scoped) == {want}
+
+
+def test_the_vocabulary_is_closed():
+    with pytest.raises(ValueError):
+        device_scope("histogramm")
+    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 15
+
+
+def test_the_histogram_kernel_call_carries_its_name():
+    """``name=`` on the one ``pallas_call``: the kernel is an op of its own
+    name inside the ``histogram`` scope (``%lg_hist`` on the chip)."""
+    assert KERNEL_NAME == "lg_hist"
+    _, learner = _learner("sorted", hist="pallas", rows=256, leaves=4)
+    module = _lower_tree(learner, False)["tree"].as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*)"', module))
+    # ``hist_pallas`` is a function of its own in the module: its ops are
+    # named from ``lg_hist`` down, its one call sits under ``histogram``
+    assert any(re.search(r"(^|/)lg_hist/pallas_call$", n) for n in names)
+    calls = {n for n in names if n.endswith("/jit(hist_pallas)")}
+    assert calls and all("histogram/while/body/" in n for n in calls)
+
+
+# -- work counts --------------------------------------------------------
+def _brute_force_counts(tree, binned, window: int) -> dict:
+    """Rows and window trips of one tree's partition and histogram passes,
+    counted by sending every training row down the host tree."""
+    n = binned.shape[0]
+    node_rows = {0: np.arange(n)}
+    counts = collections.Counter(splits=tree.num_leaves - 1, hist_rows=n,
+                                 hist_trips=-(-n // window))
+    for k in range(tree.num_leaves - 1):      # nodes in order of creation
+        rows = node_rows[k]
+        left = binned[rows, tree.split_feature_inner[k]] \
+            <= tree.threshold_bin[k]
+        for child, side in ((tree.left_child[k], left),
+                            (tree.right_child[k], ~left)):
+            if child >= 0:
+                node_rows[child] = rows[side]
+        small = min(int(left.sum()), int((~left).sum()))
+        counts["partition_rows"] += len(rows)
+        counts["partition_trips"] += -(-len(rows) // window)
+        counts["hist_rows"] += small
+        counts["hist_trips"] += -(-small // window)
+    return dict(counts)
+
+
+@pytest.mark.parametrize("bagging", [False, True], ids=["all_rows", "bagged"])
+def test_work_counts_equal_a_brute_force_count(bagging, monkeypatch):
+    monkeypatch.setenv("LAMBDAGAP_CHUNK", "1024")   # several trips a pass
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(5000, 6)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] + X[:, 2] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 20, "max_bin": 31,
+              "tpu_fused_learner": 1, "tree_layout": "sorted",
+              "enable_bundle": False, "min_data_in_leaf": 5,
+              "telemetry": True, "verbose": -1}
+    if bagging:
+        params.update(bagging_fraction=0.5, bagging_freq=1)
+    bst = lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=3)
+    gb = bst._booster
+    window = gb.learner._window(5000)
+    assert window == 1024
+    records = list(gb.telemetry.records)
+    assert [r["iter"] for r in records] == [0, 1, 2]
+    binned = np.asarray(gb.train_set.binned)
+    total = collections.Counter()
+    for i, rec in enumerate(records):
+        tree = gb._tree(i)
+        assert tree.num_leaves == 20
+        want = _brute_force_counts(tree, binned, window)
+        assert rec["counts"] == want, i
+        assert rec["counts"]["partition_trips"] > rec["counts"]["splits"]
+        total.update(want)
+    assert gb.telemetry.summary()["counts_total"] == dict(total)
+
+
+def test_no_work_counts_and_no_read_with_telemetry_off(monkeypatch):
+    from lambdagap_tpu.models.fused_learner import FusedTreeLearner
+    reads = []
+    monkeypatch.setattr(FusedTreeLearner, "work_counts",
+                        lambda self, host: reads.append(host) or {})
+    X = np.random.default_rng(4).normal(size=(400, 4)).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 6, "verbose": -1,
+              "tpu_fused_learner": 1}
+    bst = lgb.train(params, lgb.Dataset(X, label=(X[:, 0] > 0).astype(
+        np.float32)), num_boost_round=2)
+    tel = bst._booster.telemetry
+    assert not tel.enabled and reads == [] and len(tel.records) == 0
+    assert "counts_total" not in tel.summary()
+    # the kept records hold neither the row map nor the work counts
+    assert all(m.rec.work is None and m.rec.row_leaf is None
+               for m in bst._booster.models if hasattr(m, "rec"))
