@@ -31,7 +31,7 @@ from ..config import Config
 from ..data.dataset import BinnedDataset
 from ..obs.telemetry import device_scope as _scope
 from ..ops.histogram import gh_contract
-from ..ops.partition import decision_go_left
+from ..ops.partition import decision_go_left, route_window
 from ..ops.split import (K_MIN_SCORE, SplitParams, calculate_leaf_output,
                          gather_threshold_split, leaf_gain, per_feature_best)
 from .learner import SerialTreeLearner, _next_pow2
@@ -1218,17 +1218,16 @@ class FusedTreeLearner(SerialTreeLearner):
                 perm_in = st["perm"]
 
             # -- chunked stable partition into perm_buf ----------------
-            # under the sorted layout the SAME scatter positions route the
-            # full packed row payload into srows_buf: the permutation
-            # delta of this split applied physically, over only this
-            # leaf's slice — positions form two monotone runs (lefts
-            # ascending, rights descending), so the writes are two nearly
-            # contiguous streams, not random scatters
+            # a trip's lefts belong at [lcur, lcur+nl) in lane order and its
+            # rights at [rcur-nr, rcur) in REVERSED lane order: two
+            # contiguous runs, which route_window compacts inside the
+            # window and lands as masked window writes, the idiom of cbody
+            # below — no scatter. Under the sorted layout the packed row
+            # payload rides the same compactions into srows_buf: the
+            # permutation delta of this split applied physically, over
+            # only this leaf's slice
             def pbody(s):
-                if layout_sorted:
-                    c, lcur, rcur, pbuf, sbuf = s
-                else:
-                    c, lcur, rcur, pbuf = s
+                c, lcur, rcur, *bufs = s        # perm_buf(, srows_buf)
                 with _scope("partition_decide"):
                     live = jnp.clip(count_eff - c * W, 0, W)
                     valid = lane < live
@@ -1251,26 +1250,17 @@ class FusedTreeLearner(SerialTreeLearner):
                         cv, thrv, dlv, default_bins[feat],
                         missing_types[feat], num_bins[feat], catv,
                         bitsv) & valid
-                    cums_gl = jnp.cumsum(gl.astype(jnp.int32))
-                    nl = cums_gl[W - 1]
-                    # valid lanes are a prefix, so the right-side rank needs
-                    # no second cumsum
-                    prefix_valid = jnp.minimum(lane + 1, live)
-                    lpos = lcur + cums_gl - 1
+                    gr = ~gl & valid
+                with _scope("partition_scatter"):
                     # rights fill backward from the slice end: stable within
                     # a chunk, chunk order reversed on the right side — a
                     # deterministic permutation, only affecting later gather
-                    # order
-                    rpos = rcur - (prefix_valid - cums_gl)
-                    pos = jnp.where(gl, lpos, jnp.where(valid, rpos, N))
-                    nxt = (c + 1, lcur + nl, rcur - (live - nl))
-                with _scope("partition_scatter"):
-                    pbuf = pbuf.at[pos].set(rows, mode="drop")
-                    if layout_sorted:
-                        sbuf = sbuf.at[pos].set(dw, mode="drop")
-                if layout_sorted:
-                    return nxt + (pbuf, sbuf)
-                return nxt + (pbuf,)
+                    # order. rcur - nr >= begin, and the W-row tail pad
+                    # absorbs either window
+                    win = (rows, dw) if layout_sorted else (rows,)
+                    bufs, nl, nr = route_window(bufs, win, gl, gr, lcur,
+                                                rcur)
+                    return (c + 1, lcur + nl, rcur - nr) + bufs
 
             with _scope("partition"):
                 if layout_sorted:
@@ -1849,22 +1839,14 @@ class FusedTreeLearner(SerialTreeLearner):
                 cv, thrv, dlv, self.default_bins_arr[feat],
                 self.missing_types_arr[feat], self.num_bins_arr[feat],
                 catv, bitsv) & valid
-            cums_gl = jnp.cumsum(gl.astype(i32))
-            nl = cums_gl[W - 1]
-            prefix_valid = jnp.minimum(lane + 1, live)
-            lpos = lcur + cums_gl - 1
-            rpos = rcur - (prefix_valid - cums_gl)
-            pos = jnp.where(gl, lpos, jnp.where(valid, rpos, N))
-            pbuf = pbuf.at[pos].set(rows, mode="drop")
             gbuf = lax.dynamic_update_slice(gbuf, gl, (c * W,))
+            win = [rows]
             if sorted_mode:
-                cbufs = [
-                    b.at[pos].set(
-                        lax.dynamic_slice(state[k], (begin + c * W,), (W,)),
-                        mode="drop")
-                    for k, b in zip(chans, cbufs)]
-            return tuple([c + 1, lcur + nl, rcur - (live - nl), pbuf, gbuf]
-                         + cbufs)
+                win += [lax.dynamic_slice(state[k], (begin + c * W,), (W,))
+                        for k in chans]
+            (pbuf, *cbufs), nl, nr = route_window(
+                [pbuf] + cbufs, win, gl, ~gl & valid, lcur, rcur)
+            return tuple([c + 1, lcur + nl, rcur - nr, pbuf, gbuf] + cbufs)
 
         init = [jnp.int32(0), begin, begin + count_eff,
                 state["perm_buf"], jnp.zeros(PV, bool)]

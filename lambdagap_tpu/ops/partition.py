@@ -8,7 +8,9 @@ partition; the CUDA learner uses bit-vector + prefix sums
 (reference: src/treelearner/cuda/cuda_data_partition.hpp:106-139). Here the
 stable partition is a key sort over the padded slice (O(P log P) but fully
 vectorized on the VPU), followed by an in-range scatter back into the
-permutation array.
+permutation array. The fused tree program partitions a leaf window by window
+instead and moves each window's rows with neither: ``route_window``
+(``compact`` + ``write_front``) below.
 """
 from __future__ import annotations
 
@@ -16,6 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .split import MT_NAN, MT_ZERO
 
@@ -40,6 +43,88 @@ def decision_go_left(bin_vals: jax.Array, threshold: jax.Array,
     bit = jnp.right_shift(cat_bitset[word], (b % 32).astype(jnp.uint32)) & 1
     cat_left = bit == 1
     return jnp.where(is_categorical, cat_left, num_left)
+
+
+def _per_lane(mask: jax.Array, like: jax.Array) -> jax.Array:
+    """A ``[W]`` lane mask shaped to select whole rows of ``like``."""
+    return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
+
+
+def compact(keep: jax.Array, *cols: jax.Array, back: bool = False):
+    """Stable compaction of one window: the lanes where ``keep`` holds move
+    to the front (``back``: to the back) in lane order, in every array of
+    ``cols`` (leading axis W, a power of two) alike. What the other lanes
+    hold afterwards is unspecified.
+
+    The compress network (Hacker's Delight 7-4): a kept lane has to move
+    down by the number of dropped lanes before it; stage k moves every
+    element whose displacement has bit k set by the static 2^k. The
+    displacement grows with the lane, so moves in the order 1, 2, 4, ...
+    never land on a live element. log2(W) stages of slice, pad and select:
+    no scatter, no gather, no sort, and exact for any dtype. ``back`` is the
+    mirror image: up by the number of dropped lanes behind.
+    """
+    W = keep.shape[0]
+    assert W & (W - 1) == 0, W
+    lane = jnp.arange(W, dtype=jnp.int32)
+    kept = jnp.cumsum(keep.astype(jnp.int32))        # up to and with a lane
+    # how far a kept lane moves; 0 marks a lane nobody waits for: a dropped
+    # one, or one already left
+    goal = W - 1 - (kept[W - 1] - kept) if back else kept - 1
+    disp = jnp.where(keep, jnp.abs(goal - lane), 0)
+
+    def shifted(x, s):       # front: x[i + s] comes to i; back: x[i - s]
+        pad = [(s, 0, 0)] if back else [(0, s, 0)]
+        return lax.pad(x[:W - s] if back else x[s:], jnp.zeros((), x.dtype),
+                       pad + [(0, 0, 0)] * (x.ndim - 1))
+
+    s = 1
+    while s < W:
+        coming = shifted(disp, s)
+        take = (coming & s) != 0
+        cols = tuple(jnp.where(_per_lane(take, c), shifted(c, s), c)
+                     for c in cols)
+        disp = jnp.where(take, coming, jnp.where((disp & s) != 0, 0, disp))
+        s *= 2
+    return cols
+
+
+def write_front(buf: jax.Array, vals: jax.Array, start: jax.Array,
+                n: jax.Array) -> jax.Array:
+    """``buf[start : start + n] = vals[:n]`` as one contiguous window: read W
+    rows at ``start``, keep what lies behind ``n``, write W rows back. The
+    caller keeps ``start + W`` inside ``buf`` (the training buffers carry a
+    W-row tail pad and no start passes their N-th row), so neither slice
+    clamps."""
+    W = vals.shape[0]
+    assert buf.shape[0] >= W and buf.shape[1:] == vals.shape[1:]
+    idx = (start,) + (0,) * (buf.ndim - 1)
+    old = lax.dynamic_slice(buf, idx, vals.shape)
+    mask = _per_lane(jnp.arange(W, dtype=jnp.int32) < n, vals)
+    return lax.dynamic_update_slice(buf, jnp.where(mask, vals, old), idx)
+
+
+def route_window(bufs, win, go_left: jax.Array, go_right: jax.Array,
+                 lcur: jax.Array, rcur: jax.Array):
+    """One trip of the chunked stable partition, for every array of the
+    window ``win`` into its buffer of ``bufs``: the lefts land at
+    ``[lcur, lcur + nl)`` in lane order, the rights at ``[rcur - nr, rcur)``
+    in REVERSED lane order (the rights of a leaf fill backward from its
+    end). Each side is one contiguous run, so it is compacted in the window
+    (the rights to the back, then flipped: flipping the compacted window
+    costs one reverse where flipping its inputs cost XLA two) and written
+    as one masked window — where a scatter by position cost ~30x more on a
+    TPU v5e (PERF.md section 6, PR 27). Dead lanes are in neither mask.
+    Returns ``(bufs, nl, nr)``: the next trip starts at ``lcur + nl`` and
+    ``rcur - nr``."""
+    nl = jnp.sum(go_left, dtype=jnp.int32)
+    nr = jnp.sum(go_right, dtype=jnp.int32)
+    lefts = compact(go_left, *win)
+    rights = compact(go_right, *win, back=True)
+    bufs = tuple(
+        write_front(write_front(buf, lw, lcur, nl), rw[::-1], rcur - nr, nr)
+        for buf, lw, rw in zip(bufs, lefts, rights))
+    return bufs, nl, nr
 
 
 @functools.partial(jax.jit, static_argnames=("padded_size",))

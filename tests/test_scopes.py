@@ -35,11 +35,9 @@ def _leaf_scope(name: str):
     return found[-1] if found else None
 
 
-def scope_census(lowered):
-    """(ops per innermost scope, [(function, op, location name)] of the ops
-    under none) of a lowered program. An op of a private function (one
-    ``cumsum`` or ``where`` body shared by its callers) counts as scoped
-    when every call of that function is."""
+def _ops_and_calls(lowered):
+    """([(function, op, location name)] of every op, {callee: [(calling
+    function, location name of the call)]}) of a lowered program."""
     ops, calls = [], collections.defaultdict(list)
 
     def walk(op, fn):
@@ -53,11 +51,20 @@ def scope_census(lowered):
                         continue
                     if kind == "func.call":
                         callee = str(child.attributes["callee"]).lstrip("@")
-                        calls[callee].append((fn, _leaf_scope(name)))
+                        calls[callee].append((fn, name))
                     ops.append((fn, kind, name))
                     walk(child, fn)
 
     walk(lowered.compiler_ir(dialect="stablehlo").operation, None)
+    return ops, calls
+
+
+def scope_census(lowered):
+    """(ops per innermost scope, [(function, op, location name)] of the ops
+    under none) of a lowered program. An op of a private function (one
+    ``cumsum`` or ``where`` body shared by its callers) counts as scoped
+    when every call of that function is."""
+    ops, calls = _ops_and_calls(lowered)
     memo = {}
 
     def by_call_site(fn) -> bool:
@@ -65,8 +72,9 @@ def scope_census(lowered):
             return False
         if fn not in memo:
             memo[fn] = False
-            memo[fn] = all(scope is not None or by_call_site(caller)
-                           for caller, scope in calls[fn])
+            memo[fn] = all(_leaf_scope(name) is not None
+                           or by_call_site(caller)
+                           for caller, name in calls[fn])
         return memo[fn]
 
     scoped, outside = collections.Counter(), []
@@ -78,6 +86,25 @@ def scope_census(lowered):
                 and not LOOP_COUNTER.match(name):
             outside.append((fn, kind, name))
     return scoped, outside
+
+
+def op_kinds_under(lowered, scope: str) -> collections.Counter:
+    """Kinds of the ops under ``scope`` as a path component, those of the
+    private functions called from under it included."""
+    ops, calls = _ops_and_calls(lowered)
+    inside = set()
+    grew = True
+    while grew:
+        grew = False
+        for callee, sites in calls.items():
+            if callee not in inside and any(
+                    scope in name.split("/") or caller in inside
+                    for caller, name in sites):
+                inside.add(callee)
+                grew = True
+    return collections.Counter(
+        kind for fn, kind, name in ops
+        if scope in name.split("/") or fn in inside)
 
 
 def _learner(layout: str, hist: str = "onehot", rows: int = 600,
@@ -130,6 +157,12 @@ def test_every_op_of_the_tree_programs_is_under_one_scope(layout, has_mask):
         "tree_init", "leaf_select", "partition_decide", "partition_scatter",
         "split_state", "hist_subtract", "row_leaf"}
     assert set(scoped) <= set(DEVICE_SCOPES)
+    # the pass moves a window's rows by compaction and contiguous window
+    # writes (PR 27): a scatter under ``partition`` is the regression
+    moved = op_kinds_under(lowered["tree"], "partition")
+    assert moved["stablehlo.dynamic_update_slice"] >= 2
+    assert moved["stablehlo.scatter"] == 0, moved
+    assert op_kinds_under(lowered["tree"], "row_leaf")["stablehlo.scatter"]
     if layout == "sorted":
         scoped, outside = scope_census(lowered["layout"])
         assert outside == [] and set(scoped) == {"layout_apply"}
