@@ -579,6 +579,10 @@ class GBDT:
                         log.info("Start training from score %f", init)
             with tel.phase("gradients"):
                 grad, hess = self.boosting()
+            if self.objective.work_counts:
+                # host constants of the ranking objectives' bucketing: no
+                # device array rides along, so nothing is read
+                tel.defer_counts((), lambda _: self.objective.work_counts)
         grad, hess = self.guard.admit_gradients(self, grad, hess)
 
         with tel.phase("sampling"):

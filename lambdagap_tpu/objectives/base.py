@@ -25,6 +25,9 @@ K_EPSILON = 1e-15
 class ObjectiveFunction:
     name = "base"
     num_model_per_iteration = 1
+    # host-side constants an objective adds to every iteration record's
+    # ``counts`` (the ranking objectives' bucketing); None: nothing
+    work_counts: Optional[Dict[str, int]] = None
 
     def __init__(self, config: Config) -> None:
         self.config = config

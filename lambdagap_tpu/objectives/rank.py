@@ -126,6 +126,15 @@ class RankingBase(ObjectiveFunction):
         self.query_boundaries = np.asarray(metadata.query_boundaries)
         self.num_queries = metadata.num_queries
         self.bucketing = _QueryBuckets(self.query_boundaries, num_data)
+        # constants of the bucketing for the iteration record's ``counts``:
+        # docs, docs after padding every query to its bucket's length, and
+        # the pair-lattice cells the gradient program evaluates
+        self.work_counts = {
+            "rank_docs": int(num_data),
+            "rank_pad_docs": sum(int(idx.size) for _, _, idx
+                                 in self.bucketing.buckets),
+            "rank_pair_cells": sum(len(qids) * self._pair_cells(L)
+                                   for L, qids, _ in self.bucketing.buckets)}
         # positions for unbiased LTR
         if metadata.position is not None:
             pos = np.asarray(metadata.position, np.int32)
@@ -148,6 +157,11 @@ class RankingBase(ObjectiveFunction):
     def _bucket_aux(self, qids: np.ndarray) -> tuple:
         return ()
 
+    def _pair_cells(self, L: int) -> int:
+        """Pair-lattice cells the bucket kernel evaluates for one query
+        padded to ``L`` (no lattice: none)."""
+        return 0
+
     def _next_key(self):
         """Per-iteration PRNG key for randomized subclasses."""
         return jnp.zeros(2, jnp.uint32)
@@ -168,28 +182,36 @@ class RankingBase(ObjectiveFunction):
         has_pos = self.positions is not None
 
         def loop(s, label, positions, pos_biases, key, idxs, auxs):
+            # the four inner scopes (obs.telemetry.GRADIENT_SCOPES) tile
+            # the program: the bucket kernels open rank_sort / rank_lattice
             with device_scope("gradients"):
-                if has_pos:
-                    s = s + pos_biases[positions]
-                grad = jnp.zeros(num_data + 1, jnp.float32)
-                hess = jnp.zeros(num_data + 1, jnp.float32)
-                pad_s = jnp.concatenate([s, jnp.asarray([K_MIN_SCORE],
-                                                        s.dtype)])
-                pad_l = jnp.concatenate([label,
-                                         jnp.asarray([0.0], label.dtype)])
+                with device_scope("rank_gather"):
+                    if has_pos:
+                        s = s + pos_biases[positions]
+                    pad_s = jnp.concatenate([s, jnp.asarray([K_MIN_SCORE],
+                                                            s.dtype)])
+                    pad_l = jnp.concatenate([label,
+                                             jnp.asarray([0.0], label.dtype)])
+                with device_scope("rank_scatter"):
+                    grad = jnp.zeros(num_data + 1, jnp.float32)
+                    hess = jnp.zeros(num_data + 1, jnp.float32)
                 eff_sum = jnp.float32(0.0)
                 for idx_d, aux in zip(idxs, auxs):
-                    sb = pad_s[idx_d]
-                    lb = pad_l[idx_d]
-                    vb = idx_d < num_data
+                    with device_scope("rank_gather"):
+                        sb = pad_s[idx_d]
+                        lb = pad_l[idx_d]
+                        vb = idx_d < num_data
                     lam, hes, eff = self._bucket_gradients_k(sb, lb, vb, aux,
                                                              key)
-                    grad = grad.at[idx_d.reshape(-1)].add(lam.reshape(-1),
-                                                          mode="drop")
-                    hess = hess.at[idx_d.reshape(-1)].add(hes.reshape(-1),
-                                                          mode="drop")
-                    eff_sum = eff_sum + jnp.sum(eff)
-                return grad[:-1], hess[:-1], eff_sum
+                    with device_scope("rank_scatter"):
+                        grad = grad.at[idx_d.reshape(-1)].add(
+                            lam.reshape(-1), mode="drop")
+                        hess = hess.at[idx_d.reshape(-1)].add(
+                            hes.reshape(-1), mode="drop")
+                    with device_scope("rank_lattice"):
+                        eff_sum = eff_sum + jnp.sum(eff)
+                with device_scope("rank_scatter"):
+                    return grad[:-1], hess[:-1], eff_sum
 
         idxs = tuple(jnp.asarray(idx) for (_, _, idx)
                      in self.bucketing.buckets)
@@ -300,16 +322,29 @@ class LambdarankNDCG(RankingBase):
         return (jnp.asarray(self.inv_max_dcg[qids], jnp.float32),
                 jnp.asarray(self.inv_max_bdcg[qids], jnp.float32))
 
+    @staticmethod
+    def _tile(L: int) -> Optional[int]:
+        """Row block of the tiled sweep for a bucket of padded length
+        ``L``; None: the dense lattice."""
+        return None if L <= _DENSE_PAIR_L else max(
+            (_DENSE_PAIR_L * _DENSE_PAIR_L) // L, 64)
+
+    def _pair_cells(self, L: int) -> int:
+        tile = self._tile(L)
+        if tile is None:
+            return L * L
+        rows = min(L, self.truncation_level) \
+            if self.target in _TRUNCATED_I_TARGETS else L
+        return -(-rows // tile) * tile * L     # whole row blocks swept
+
     def _bucket_gradients(self, scores_b, labels_b, valid_b, aux_b):
         inv_dcg, inv_bdcg = aux_b
-        L = scores_b.shape[1]
-        tile = None if L <= _DENSE_PAIR_L else max(
-            (_DENSE_PAIR_L * _DENSE_PAIR_L) // L, 64)
         return _lambdarank_bucket(
             scores_b, labels_b, valid_b, inv_dcg, inv_bdcg, self.label_gain,
             target=self.target, sigmoid=self.sigmoid, norm=self.norm,
             truncation_level=self.truncation_level,
-            lambdagap_weight=self.lambdagap_weight, tile=tile)
+            lambdagap_weight=self.lambdagap_weight,
+            tile=self._tile(scores_b.shape[1]))
 
 
 # queries up to this padded length use the dense [L, L] lattice; longer ones
@@ -446,18 +481,12 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
         return (lam_to_row, p_hessian, jnp.sum(p_lambda),
                 jnp.sum(pair_valid, dtype=jnp.float32))
 
-    def one_query(s, l, v, imd, imb):
-        L = s.shape[0]
-        neg = jnp.where(v, s, K_MIN_SCORE)
-        order = jnp.argsort(-neg)              # stable: ranks by score desc
-        ss = neg[order]
-        ls = l[order].astype(jnp.float32)
-        vs = v[order]
+    def lattice(ss, ls, vs, nv, imd, imb, best, worst):
+        """The pair block(s) of one score-sorted query, their row and
+        column reductions and the normalisation: (lambdas, hessians) in
+        sorted order and the effective pair rate."""
+        L = ss.shape[0]
         ranks = jnp.arange(L, dtype=jnp.int32)
-        nv = jnp.sum(vs)
-        best = ss[0]
-        worst = ss[jnp.maximum(nv - 1, 0)]
-
         if tile is None:
             lam_to_row, p_hessian, sum_pl, count_lambdas = pair_block(
                 ranks[:, None], ranks[None, :], ss[:, None], ss[None, :],
@@ -518,17 +547,38 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
                 1.0)
             lam_sorted = lam_sorted * norm_factor
             hes_sorted = hes_sorted * norm_factor
-
-        # unsort back to document order
-        inv = jnp.argsort(order)
-        lam = lam_sorted[inv]
-        hes = hes_sorted[inv]
         nvf = nv.astype(jnp.float32)           # int32 nv*(nv-1) would wrap
         eff = 2.0 * count_lambdas.astype(jnp.float32) / \
             jnp.maximum(nvf * (nvf - 1.0), 1.0)
-        return lam, hes, eff
+        return lam_sorted, hes_sorted, eff
 
-    return jax.vmap(one_query)(scores, labels, valid, inv_dcg, inv_bdcg)
+    def sort_query(s, l, v):
+        neg = jnp.where(v, s, K_MIN_SCORE)
+        order = jnp.argsort(-neg)              # stable: ranks by score desc
+        ss = neg[order]
+        ls = l[order].astype(jnp.float32)
+        vs = v[order]
+        nv = jnp.sum(vs)
+        best = ss[0]
+        worst = ss[jnp.maximum(nv - 1, 0)]
+        return order, ss, ls, vs, nv, best, worst
+
+    def unsort_query(order, lam_sorted, hes_sorted):
+        inv = jnp.argsort(order)               # back to document order
+        return lam_sorted[inv], hes_sorted[inv]
+
+    # one vmap a stage, each opened UNDER its scope: a scope opened inside a
+    # vmapped function is named ``vmap(<scope>)`` and no selector on a path
+    # component finds it
+    with device_scope("rank_sort"):
+        order, ss, ls, vs, nv, best, worst = jax.vmap(sort_query)(
+            scores, labels, valid)
+    with device_scope("rank_lattice"):
+        lam_sorted, hes_sorted, eff = jax.vmap(lattice)(
+            ss, ls, vs, nv, inv_dcg, inv_bdcg, best, worst)
+    with device_scope("rank_sort"):
+        lam, hes = jax.vmap(unsort_query)(order, lam_sorted, hes_sorted)
+    return lam, hes, eff
 
 
 @register_objective

@@ -75,6 +75,14 @@ DEVICE_SCOPES = ("histogram", "partition", "partition_decide",
                  "hist_allreduce", "row_leaf", "layout_apply", "gradients",
                  "score_update")
 
+# inner scopes of the ranking objectives' gradient program, opened INSIDE
+# ``gradients`` (a tuple of their own: DEVICE_SCOPES tiles the programs, these
+# tile one of its scopes): the bucket gather of scores and labels, the
+# per-query sort, the pair lattice with its reductions and normalisation,
+# the write back to document order. Every op of that program carries
+# ``gradients`` and at most one of these (tests/test_scopes.py).
+GRADIENT_SCOPES = ("rank_gather", "rank_sort", "rank_lattice", "rank_scatter")
+
 # names on the profiler's clock: one ITER_ANNOTATION per boosting iteration
 # (begin_iteration .. end_iteration, stat ``iter``) and one
 # PHASE_ANNOTATION + <phase> per span, device_wait included
@@ -83,10 +91,12 @@ PHASE_ANNOTATION = "lg_phase:"
 
 
 def device_scope(name: str):
-    """``jax.named_scope(name)`` for a name of DEVICE_SCOPES (and only
-    those: the vocabulary is closed, a typo fails at trace time)."""
-    if name not in DEVICE_SCOPES:
-        raise ValueError(f"{name!r} is not in obs.telemetry.DEVICE_SCOPES")
+    """``jax.named_scope(name)`` for a name of DEVICE_SCOPES or
+    GRADIENT_SCOPES (and only those: the vocabulary is closed, a typo
+    fails at trace time)."""
+    if name not in DEVICE_SCOPES and name not in GRADIENT_SCOPES:
+        raise ValueError(f"{name!r} is not in obs.telemetry.DEVICE_SCOPES "
+                         "or GRADIENT_SCOPES")
     import jax
     return jax.named_scope(name)
 

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 import lambdagap_tpu as lgb
-from lambdagap_tpu.obs.telemetry import DEVICE_SCOPES, device_scope
+from lambdagap_tpu.obs.telemetry import (DEVICE_SCOPES, GRADIENT_SCOPES,
+                                         device_scope)
 from lambdagap_tpu.ops.hist_pallas import KERNEL_NAME
 
 # ops that move values around and compute nothing: they may sit outside
@@ -183,6 +184,21 @@ def test_scope_nesting_is_only_where_the_vocabulary_says():
                      ("partition", "partition_scatter")}
 
 
+def _lambdarank_loop(sizes):
+    rng = np.random.default_rng(2)
+    n = int(np.sum(sizes))
+    X = rng.normal(size=(n, 3)).astype(np.float32)
+    ds = lgb.Dataset(X, label=rng.integers(0, 4, n).astype(np.float32),
+                     group=sizes)
+    gb = lgb.Booster({"objective": "lambdarank", "verbose": -1}, ds)._booster
+    gb.boosting()                           # builds the jitted program
+    obj = gb.objective
+    return obj._loop_jit.lower(
+        gb.scores[0], obj.label, jnp.zeros(1, jnp.int32),
+        jnp.zeros(1, jnp.float32), obj._next_key(), obj._loop_idxs,
+        obj._loop_auxs)
+
+
 @pytest.mark.parametrize("program", ["fn", "loop", "score_update"])
 def test_gradient_and_score_programs_are_scoped(program):
     if program == "score_update":
@@ -191,38 +207,79 @@ def test_gradient_and_score_programs_are_scoped(program):
             jnp.zeros((1, 64)), jnp.zeros(8), jnp.zeros(64, jnp.int32), k=0)
         want = "score_update"
     else:
-        rng = np.random.default_rng(1)
-        X = rng.normal(size=(240, 4)).astype(np.float32)
         if program == "fn":
-            params = {"objective": "binary", "verbose": -1}
+            rng = np.random.default_rng(1)
+            X = rng.normal(size=(240, 4)).astype(np.float32)
             ds = lgb.Dataset(X, label=(X[:, 0] > 0).astype(np.float32))
-        else:
-            params = {"objective": "lambdarank", "verbose": -1}
-            ds = lgb.Dataset(X, label=rng.integers(0, 4, 240).astype(
-                np.float32), group=[24] * 10)
-        bst = lgb.Booster(params, ds)
-        gb = bst._booster
-        gb.boosting()                       # builds the jitted program
-        obj = gb.objective
-        if program == "fn":
+            gb = lgb.Booster({"objective": "binary", "verbose": -1},
+                             ds)._booster
+            gb.boosting()                   # builds the jitted program
+            obj = gb.objective
             lowered = obj._grad_jit.lower(gb.scores, *[
                 getattr(obj, f) for f in obj._GRAD_ARRAY_FIELDS
                 if getattr(obj, f, None) is not None])
         else:
-            lowered = obj._loop_jit.lower(
-                gb.scores[0], obj.label, jnp.zeros(1, jnp.int32),
-                jnp.zeros(1, jnp.float32), obj._next_key(), obj._loop_idxs,
-                obj._loop_auxs)
+            lowered = _lambdarank_loop([24] * 10)
         assert f"jit_{program}" in lowered.as_text()[:200]
         want = "gradients"
     scoped, outside = scope_census(lowered)
     assert outside == [] and set(scoped) == {want}
 
 
+def test_the_ranking_gradient_program_is_tiled_by_its_inner_scopes():
+    """Every op of the lambdarank gradient program carries ``gradients`` and
+    at most one of GRADIENT_SCOPES, as a path component of its own name or of
+    the calls that reach its function (a scope opened INSIDE a vmapped
+    function would read ``vmap(<scope>)`` and match no selector)."""
+    ops, calls = _ops_and_calls(_lambdarank_loop([5, 12, 24, 40, 100]))
+    memo = {}
+
+    def reached_under(fn):
+        """Scope names on some call path from the program's entry to fn."""
+        if fn not in memo:
+            memo[fn] = set()
+            for caller, name in calls.get(fn, []):
+                memo[fn] |= set(name.split("/")) | reached_under(caller)
+        return memo[fn]
+
+    census = collections.Counter()
+    for fn, kind, name in ops:
+        if kind in PLUMBING:
+            continue
+        comps = set(name.split("/")) | reached_under(fn)
+        assert "gradients" in comps, (fn, kind, name)
+        inner = comps & set(GRADIENT_SCOPES)
+        assert len(inner) <= 1, (fn, kind, name)
+        assert not any(c.startswith("vmap(rank_") for c in comps), name
+        census[next(iter(inner), None)] += 1
+    assert set(census) >= set(GRADIENT_SCOPES)
+    assert census["rank_lattice"] > census["rank_sort"] > 0
+    # the loop's own zero of the pair-rate sum is all that may sit outside
+    assert census[None] <= 2, census
+    kinds = {s: collections.Counter(
+        kind for fn, kind, name in ops
+        if s in set(name.split("/")) | reached_under(fn))
+        for s in GRADIENT_SCOPES}
+    assert kinds["rank_scatter"]["stablehlo.scatter"] == 2 * 5   # 5 buckets
+    assert kinds["rank_gather"]["stablehlo.gather"] == 2 * 5
+    assert kinds["rank_sort"]["stablehlo.sort"] == 2 * 5
+    assert not kinds["rank_lattice"]["stablehlo.sort"] \
+        and not kinds["rank_lattice"]["stablehlo.scatter"]
+
+
 def test_the_vocabulary_is_closed():
     with pytest.raises(ValueError):
         device_scope("histogramm")
+    with pytest.raises(ValueError):
+        device_scope("rank_latice")
     assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 15
+    # the gradient program's inner scopes: a tuple of their own, accepted
+    assert GRADIENT_SCOPES == ("rank_gather", "rank_sort", "rank_lattice",
+                               "rank_scatter")
+    assert not set(GRADIENT_SCOPES) & set(DEVICE_SCOPES)
+    for name in GRADIENT_SCOPES:
+        with device_scope(name):
+            pass
 
 
 def test_the_histogram_kernel_call_carries_its_name():
