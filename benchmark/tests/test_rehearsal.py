@@ -103,6 +103,38 @@ def test_a_split_scan_that_misses_the_best_split_is_not_correct(cell, sound):
     assert reading(res, "leaf_value") <= res["compared"]["leaf_value"][1]
 
 
+LONG = 3
+
+
+def long_drive(cell, **kw):
+    """The usual window with LONG boosting iterations to a step: LONG times
+    the usual run's trees, whatever the host's pace."""
+    def update(bst):
+        for _ in range(LONG):
+            bst.update()
+    res = drive(cell, hooks={"update": update}, **kw)
+    assert reading(res, "trees_followed") \
+        == LONG * (WARMUP + res["attempted"])
+    return res
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_long_rehearsal_is_correct(cell):
+    # split_gap does not grow with the trees a window holds
+    res = long_drive(cell)
+    assert res["correct"] is True
+    assert reading(res, "split_gap") <= 0.5 * res["compared"]["split_gap"][1]
+    assert 0 <= reading(res, "split_gap_tree") < reading(res, "trees_followed")
+    assert reading(res, "split_gap_node") in (0, 1, 2)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_long_window_does_not_hide_a_poor_split_scan(cell):
+    res = long_drive(cell, control="random_split")
+    assert res["correct"] is False and passes_all_but(res, "split_gap")
+    assert reading(res, "split_gap") > 1.5 * res["compared"]["split_gap"][1]
+
+
 def passes_all_but(res, name):
     return all(limit is None or value <= limit
                for other, (value, limit) in res["compared"].items()
@@ -121,7 +153,7 @@ def test_a_window_step_that_leaves_its_state_unchanged_is_not_correct(cell):
         if calls["n"] == WARMUP + 1:     # the window's first step is lost
             gb.scores = before
     # long enough for a second window step: its tree shows the stale scores
-    res = drive(cell, hooks={"update": update}, seconds=5.0)
+    res = drive(cell, hooks={"update": update}, seconds=8.0)
     assert res["attempted"] >= 2 and res["correct"] is False
     assert reading(res, "leaf_value_median") \
         > res["compared"]["leaf_value_median"][1]
