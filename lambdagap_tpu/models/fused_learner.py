@@ -31,7 +31,7 @@ from ..config import Config
 from ..data.dataset import BinnedDataset
 from ..obs.telemetry import device_scope as _scope
 from ..ops.histogram import gh_contract
-from ..ops.partition import decision_go_left, route_window
+from ..ops.partition import decision_go_left, position_leaf, route_window
 from ..ops.split import (K_MIN_SCORE, SplitParams, calculate_leaf_output,
                          gather_threshold_split, leaf_gain, per_feature_best)
 from .learner import SerialTreeLearner, _next_pow2
@@ -1024,7 +1024,7 @@ class FusedTreeLearner(SerialTreeLearner):
                  blc0, blout0, brout0, neg_inf, pos_inf]))
             leaf_i = jnp.zeros((L + 1, 9), i32)
             # inactive leaves carry out-of-range begins so the final
-            # position->leaf searchsorted never matches them
+            # position -> leaf step (position_leaf) never matches them
             leaf_i = leaf_i.at[:, 0].set(N + iota_l1).at[:, 3].set(-1)
             leaf_i = leaf_i.at[0].set(jnp.stack(
                 [i32(0), i32(N), i32(0), i32(-1), i32(0), bf0, bt0,
@@ -1577,19 +1577,11 @@ class FusedTreeLearner(SerialTreeLearner):
 
         # -------------------------------------------------- row -> leaf id
         with _scope("row_leaf"):
-            # leaves with zero (local) rows would duplicate another leaf's
-            # begin offset — push them past the end so searchsorted never
-            # picks them (common under sharding: a leaf can be empty on one
-            # shard)
-            leaf_begin = jnp.where(state["leaf_i"][:L, 1] > 0,
-                                   state["leaf_i"][:L, 0],
-                                   N + jnp.arange(L, dtype=jnp.int32))
-            order = jnp.argsort(leaf_begin)
-            sorted_begin = leaf_begin[order]
-            which = jnp.searchsorted(sorted_begin,
-                                     jnp.arange(N, dtype=jnp.int32),
-                                     side="right") - 1
-            pos_leaf = order[which]
+            # position -> leaf is ops.partition.position_leaf, the one
+            # statement of it for this epilogue and its two mirrors
+            # (_stream_finalize_impl, fused_parallel._s2_final_body)
+            pos_leaf = position_leaf(state["leaf_i"][:L, 0],
+                                     state["leaf_i"][:L, 1], N)
             row_leaf = jnp.zeros(N, jnp.int32).at[
                 state["perm"][:N]].set(pos_leaf)
 
@@ -2045,15 +2037,9 @@ class FusedTreeLearner(SerialTreeLearner):
         N = self.num_data
         L = cfg.num_leaves
         NODES = max(L - 1, 1)
-        leaf_begin = jnp.where(state["leaf_i"][:L, 1] > 0,
-                               state["leaf_i"][:L, 0],
-                               N + jnp.arange(L, dtype=jnp.int32))
-        order = jnp.argsort(leaf_begin)
-        sorted_begin = leaf_begin[order]
-        which = jnp.searchsorted(sorted_begin,
-                                 jnp.arange(N, dtype=jnp.int32),
-                                 side="right") - 1
-        pos_leaf = order[which]
+        # position -> leaf: the fused epilogue's own ops.partition helper
+        pos_leaf = position_leaf(state["leaf_i"][:L, 0],
+                                 state["leaf_i"][:L, 1], N)
         row_leaf = jnp.zeros(N, jnp.int32).at[
             state["perm"][:N]].set(pos_leaf)
         node_f = state["node_f"]

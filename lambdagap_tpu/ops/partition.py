@@ -127,6 +127,51 @@ def route_window(bufs, win, go_left: jax.Array, go_right: jax.Array,
     return bufs, nl, nr
 
 
+_LANES = 128
+
+
+def _cumsum_by_lanes(x: jax.Array) -> jax.Array:
+    """``jnp.cumsum`` of a long 1-D array as sums inside 128-wide rows plus
+    the rows' carries, level by level. XLA splits a long cumulative sum
+    the same way on a TPU but gives the pieces no op name, so a device
+    trace files them under no scope; written out, each piece keeps its
+    caller's (``tests/test_aot_v5e.py``: the split happens after lowering,
+    where ``tests/test_scopes.py`` cannot see it)."""
+    n = x.shape[0]
+    if n <= _LANES:
+        return jnp.cumsum(x, dtype=x.dtype)
+    rows = -(-n // _LANES)
+    inner = jnp.cumsum(
+        jnp.pad(x, (0, rows * _LANES - n)).reshape(rows, _LANES), axis=1,
+        dtype=x.dtype)
+    last = inner[:, -1]
+    carry = _cumsum_by_lanes(last) - last
+    return (inner + carry[:, None]).reshape(-1)[:n]
+
+
+def position_leaf(leaf_begin: jax.Array, leaf_count: jax.Array,
+                  n: int) -> jax.Array:
+    """Leaf id of every position of ``[0, n)`` after a tree's last split
+    (``[n]`` int32): leaf ``l`` holds the positions ``[leaf_begin[l],
+    leaf_begin[l] + leaf_count[l])`` and the leaves with rows tile the
+    range. A leaf with no rows (a slot the tree never reached, or a leaf
+    that is empty on this shard and so shares another's begin) holds no
+    position. The answer is a step function of at most ``L`` steps, so it
+    is streamed: the change of leaf id at each step is written at the
+    step's position, and one cumulative sum over the positions recovers
+    the ids. ``searchsorted`` of the positions in the sorted begins cost
+    eight dependent N-row gathers and a ninth to look the id up: 925 ms at
+    10.5M rows x 255 leaves on a TPU v5e, where this takes 3.4 (PERF.md
+    section 6, PR 32)."""
+    ids = jnp.arange(leaf_begin.shape[0], dtype=jnp.int32)
+    # a leaf with no rows steps past the end, where nothing is written
+    begin = jnp.where(leaf_count > 0, leaf_begin.astype(jnp.int32), n + ids)
+    order = jnp.argsort(begin).astype(jnp.int32)
+    step = order - jnp.concatenate([jnp.zeros(1, jnp.int32), order[:-1]])
+    marks = jnp.zeros(n, jnp.int32).at[begin[order]].add(step, mode="drop")
+    return _cumsum_by_lanes(marks)
+
+
 @functools.partial(jax.jit, static_argnames=("padded_size",))
 def split_partition(x_binned: jax.Array, perm: jax.Array,
                     begin: jax.Array, count: jax.Array,

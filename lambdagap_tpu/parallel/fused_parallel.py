@@ -41,6 +41,7 @@ from ..config import Config
 from ..data.dataset import BinnedDataset
 from ..models.fused_learner import HIST_C, DeviceTree, FusedTreeLearner
 from ..models.learner import _next_pow2
+from ..ops.partition import position_leaf
 from ..ops.split import (K_MIN_SCORE, calculate_leaf_output, leaf_gain,
                          per_feature_best)
 from ..utils import log
@@ -1097,14 +1098,9 @@ class Fused2DTreeLearner(FusedTreeLearner):
         L = cfg.num_leaves
         NODES = max(L - 1, 1)
         leaf_i_l = state["leaf_i"][0]
-        leaf_begin = jnp.where(leaf_i_l[:L, 1] > 0, leaf_i_l[:L, 0],
-                               N + jnp.arange(L, dtype=jnp.int32))
-        order = jnp.argsort(leaf_begin)
-        sorted_begin = leaf_begin[order]
-        which = jnp.searchsorted(sorted_begin,
-                                 jnp.arange(N, dtype=jnp.int32),
-                                 side="right") - 1
-        pos_leaf = order[which]
+        # position -> leaf: the fused epilogue's own ops.partition helper
+        # (a leaf empty on this shard holds no position)
+        pos_leaf = position_leaf(leaf_i_l[:L, 0], leaf_i_l[:L, 1], N)
         row_leaf = jnp.zeros(N, jnp.int32).at[
             state["perm"][:N]].set(pos_leaf)
         node_f = state["node_f"]

@@ -3,6 +3,7 @@ tiles the hot programs (obs.telemetry.DEVICE_SCOPES), the histogram kernel
 carries its name, and the iteration record's work counts are exact.
 Counts and names only: nothing here reads a time."""
 import collections
+import math
 import re
 
 import jax
@@ -37,8 +38,9 @@ def _leaf_scope(name: str):
 
 
 def _ops_and_calls(lowered):
-    """([(function, op, location name)] of every op, {callee: [(calling
-    function, location name of the call)]}) of a lowered program."""
+    """([(function, op kind, location name, op)] of every op, {callee:
+    [(calling function, location name of the call)]}) of a lowered
+    program."""
     ops, calls = [], collections.defaultdict(list)
 
     def walk(op, fn):
@@ -53,7 +55,7 @@ def _ops_and_calls(lowered):
                     if kind == "func.call":
                         callee = str(child.attributes["callee"]).lstrip("@")
                         calls[callee].append((fn, name))
-                    ops.append((fn, kind, name))
+                    ops.append((fn, kind, name, child))
                     walk(child, fn)
 
     walk(lowered.compiler_ir(dialect="stablehlo").operation, None)
@@ -79,7 +81,7 @@ def scope_census(lowered):
         return memo[fn]
 
     scoped, outside = collections.Counter(), []
-    for fn, kind, name in ops:
+    for fn, kind, name, _ in ops:
         scope = _leaf_scope(name)
         if scope is not None:
             scoped[scope] += 1
@@ -89,9 +91,10 @@ def scope_census(lowered):
     return scoped, outside
 
 
-def op_kinds_under(lowered, scope: str) -> collections.Counter:
-    """Kinds of the ops under ``scope`` as a path component, those of the
-    private functions called from under it included."""
+def ops_under(lowered, scope: str):
+    """[(op kind, element counts of its results)] of the ops under ``scope``
+    as a path component, those of the private functions called from under
+    it included."""
     ops, calls = _ops_and_calls(lowered)
     inside = set()
     grew = True
@@ -103,9 +106,15 @@ def op_kinds_under(lowered, scope: str) -> collections.Counter:
                     for caller, name in sites):
                 inside.add(callee)
                 grew = True
-    return collections.Counter(
-        kind for fn, kind, name in ops
-        if scope in name.split("/") or fn in inside)
+    return [(kind, [math.prod(r.type.shape) for r in op.results
+                    if hasattr(r.type, "shape")])
+            for fn, kind, name, op in ops
+            if scope in name.split("/") or fn in inside]
+
+
+def op_kinds_under(lowered, scope: str) -> collections.Counter:
+    """Kinds of the ops ``ops_under`` finds."""
+    return collections.Counter(kind for kind, _ in ops_under(lowered, scope))
 
 
 def _learner(layout: str, hist: str = "onehot", rows: int = 600,
@@ -167,6 +176,23 @@ def test_every_op_of_the_tree_programs_is_under_one_scope(layout, has_mask):
     if layout == "sorted":
         scoped, outside = scope_census(lowered["layout"])
         assert outside == [] and set(scoped) == {"layout_apply"}
+
+
+@pytest.mark.parametrize("layout", ["gather", "sorted"])
+def test_the_epilogue_reaches_no_row_by_a_gather(layout):
+    """The epilogue finds each position's leaf by streaming
+    (``ops.partition.position_leaf``, PR 32): the one N-row random access
+    left under ``row_leaf`` is the scatter that carries positions to rows.
+    An N-row gather (a round of ``searchsorted`` in the sorted leaf begins,
+    a lookup of the leaf id in a table) is the regression."""
+    _, learner = _learner(layout)
+    n = learner.num_data
+    rows = collections.Counter(
+        kind for kind, sizes in ops_under(_lower_tree(learner, False)["tree"],
+                                          "row_leaf")
+        if n in sizes)
+    assert rows["stablehlo.scatter"] >= 1, rows
+    assert rows["stablehlo.gather"] == 0, rows
 
 
 def test_scope_nesting_is_only_where_the_vocabulary_says():
@@ -243,7 +269,7 @@ def test_the_ranking_gradient_program_is_tiled_by_its_inner_scopes():
         return memo[fn]
 
     census = collections.Counter()
-    for fn, kind, name in ops:
+    for fn, kind, name, _ in ops:
         if kind in PLUMBING:
             continue
         comps = set(name.split("/")) | reached_under(fn)
@@ -257,7 +283,7 @@ def test_the_ranking_gradient_program_is_tiled_by_its_inner_scopes():
     # the loop's own zero of the pair-rate sum is all that may sit outside
     assert census[None] <= 2, census
     kinds = {s: collections.Counter(
-        kind for fn, kind, name in ops
+        kind for fn, kind, name, _ in ops
         if s in set(name.split("/")) | reached_under(fn))
         for s in GRADIENT_SCOPES}
     assert kinds["rank_scatter"]["stablehlo.scatter"] == 2 * 5   # 5 buckets
