@@ -17,7 +17,7 @@ Contract clauses (checked by :mod:`.checks` over captured traces):
 - **C2 transfer-freedom** (``hot=True``) — no host callback / infeed /
   outfeed primitives anywhere in the program.
 - **C3 precision discipline** — ``forbid_f64``: re-tracing under
-  ``jax.experimental.enable_x64`` must introduce NO float64 eqns (a
+  ``jax.enable_x64`` must introduce NO float64 eqns (a
   silent-upcast site is invisible at x64=off and a real drift hazard the
   moment anyone enables x64 — graftlint R4's rationale, enforced on the
   IR); ``quant_int_reduction``: in quantized scenarios the histogram

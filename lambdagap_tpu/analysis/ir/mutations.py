@@ -98,8 +98,7 @@ def mutation_f64_literal() -> Dict:
     def prog(x):
         return x * scale
 
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         traced64 = _trace(prog, jnp.ones((4,), jnp.float32))
     contract = _contract("mutation.f64_literal", forbid_f64=True)
     found = checks.check_c3_f64(contract, "selftest", traced64)

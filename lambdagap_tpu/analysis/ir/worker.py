@@ -92,7 +92,6 @@ def main(argv=None) -> int:
     uncontracted = []
 
     if args.discover:
-        from jax.experimental import enable_x64
         for prog, scens in sorted(groups.items()):
             for scen, recs in sorted(scens.items()):
                 traced = recs[0].trace()
@@ -109,7 +108,7 @@ def main(argv=None) -> int:
                                   "collectives": sched}))
         return 0
 
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     for prog, scens in sorted(groups.items()):
         contract = get_contract(prog)
         if contract is None:

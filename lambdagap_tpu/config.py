@@ -432,10 +432,6 @@ class Config:
     profile_serve_n_req: int = 1         # serve-side profiler window length in requests
     profile_stream_start_window: int = -1  # predict_stream profiler window: window index to start at (-1 = off)
     profile_stream_n_windows: int = 1    # predict_stream profiler window length in windows
-    cost_plane: bool = False             # analytic per-executable FLOP/byte/HBM ledger + roofline attribution (obs/costplane.py)
-    cost_plane_out: str = ""             # COSTS.json ledger output path (implies cost_plane=true)
-    cost_plane_memory: str = "compiled"  # peak-HBM source: compiled (XLA memory_analysis) / analytic (aval arithmetic; no extra backend compile)
-    cost_plane_peaks: str = ""           # peak-table override "flops:bandwidth:hbm_bytes" (e.g. "197e12:819e9:17e9"); "" = per-device_kind table
 
     # -- convert ----------------------------------------------------------
     convert_model_language: str = ""
@@ -610,19 +606,6 @@ class Config:
             self.data_sample_strategy = "goss"
         self._check()
 
-    @staticmethod
-    def _peaks_spec_ok(spec: str) -> bool:
-        # cost_plane_peaks syntax: "" or three ':'-separated floats
-        if not spec:
-            return True
-        parts = spec.split(":")
-        if len(parts) != 3:
-            return False
-        try:
-            return all(float(p) > 0 for p in parts)
-        except ValueError:
-            return False
-
     def _check(self) -> None:
         # one source of truth for the int8 quantized-gradient level cap,
         # shared with the fused learner's accumulator guard (it used to be
@@ -785,12 +768,6 @@ class Config:
              "profile_serve_n_req must be >= 1"),
             (self.profile_stream_n_windows >= 1,
              "profile_stream_n_windows must be >= 1"),
-            (self.cost_plane_memory in ("compiled", "analytic"),
-             f"cost_plane_memory must be compiled/analytic, "
-             f"got {self.cost_plane_memory!r}"),
-            (self._peaks_spec_ok(self.cost_plane_peaks),
-             f"cost_plane_peaks must be 'flops:bandwidth:hbm_bytes' "
-             f"(three floats), got {self.cost_plane_peaks!r}"),
         ]
         for ok, msg in checks:
             if not ok:

@@ -77,9 +77,9 @@ def find_groups(nz: np.ndarray, feature_bins: np.ndarray,
             # the bound fails on the true count — skip the O(S) mask AND.
             # Dense matrices (every feature ~always non-default) used to
             # pay F x max_scan full-sample ANDs here just to bundle
-            # nothing, which made max_bin=63 dataset construction ~2x
-            # SLOWER than max_bin=255 (whose wide bins never pass the
-            # bin-budget check above); see BENCH_NOTES.md.
+            # nothing, which made max_bin=63 dataset construction
+            # slower than max_bin=255 (whose wide bins never pass the
+            # bin-budget check above).
             if bundle_conflicts[bi] + max(0, cnt_f + bundle_cnts[bi] - S) \
                     > budget:
                 continue

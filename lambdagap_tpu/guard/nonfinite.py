@@ -22,7 +22,7 @@ issued with the iteration's work; its ONE host read happens at the same
 once-per-iteration device-complete boundary graftscope's
 ``TrainTelemetry.end_iteration`` established — by then the device is idle
 and the read returns a completed buffer, so the guard adds no second sync
-point to the steady loop (ABAB-measured in BENCH_NOTES.md).
+point to the steady loop.
 """
 from __future__ import annotations
 

@@ -289,16 +289,13 @@ class CompiledForest:
             self._leaf = (jnp.asarray(b["leaf_value"]),)
 
     def predict(self, x: jax.Array) -> jax.Array:
-        from ..obs import costplane
         x = jnp.asarray(x, jnp.float32)
-        out, _, _ = costplane.observed_call(
-            "predict.compiled", _predict_compiled,
-            (x, self._blocks, self._group_of_tree, self._tree_class,
-             self._leaf, jnp.float32(self._es_margin)),
-            dict(depths=self._depths, num_class=self.num_class,
-                 early_stop_freq=self.early_stop_freq,
-                 has_linear=self.has_linear),
-            bucket=int(x.shape[0]), phase="predict")
+        out, _, _ = _predict_compiled(
+            x, self._blocks, self._group_of_tree, self._tree_class,
+            self._leaf, jnp.float32(self._es_margin),
+            depths=self._depths, num_class=self.num_class,
+            early_stop_freq=self.early_stop_freq,
+            has_linear=self.has_linear)
         return out
 
     @property

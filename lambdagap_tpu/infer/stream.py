@@ -54,7 +54,6 @@ import numpy as np
 from ..analysis.ir.contracts import register_program
 from ..data.stream import ShardedBinnedDataset, WindowPump
 from ..guard.backoff import Backoff
-from ..obs import costplane
 from ..obs.profile import ProfileWindow
 from ..obs.telemetry import NULL_TELEMETRY, TrainTelemetry
 from ..parallel.sharding import make_mesh, shard_map, sharding, spec
@@ -531,14 +530,8 @@ def predict_stream(gb, data, *, start_iteration: int = 0,
                 dev = jax.device_put(dummy)
             # deliberate warmup sync, not steady state: the bucket traces
             # must land BEFORE the pump opens (a compile under a window
-            # record would be a steady-state compile). The cost plane
-            # captures the window scorer here, at the same warm dispatch.
-            costplane.observed_call(
-                "predict_stream.window", scorer, (dev,), bucket=b,
-                phase="predict_stream",
-                shard_spec=",".join(f"{a}={mesh.shape[a]}"
-                                    for a in mesh.axis_names)
-                if mesh is not None else "").block_until_ready()
+            # record would be a steady-state compile).
+            scorer(dev).block_until_ready()
 
     res = None
     if out is None and src.n_rows is not None:
@@ -587,8 +580,6 @@ def predict_stream(gb, data, *, start_iteration: int = 0,
         # device-complete by construction: every window's scores were
         # drained through ScoreRing.wait_ready above
         wall = time.perf_counter() - t_start
-        costplane.PLANE.note_wall("predict_stream", wall,
-                                  calls=max(n_windows, 1))
         if stats_out is not None:
             n_scored = rows_done
             stats_out.update({

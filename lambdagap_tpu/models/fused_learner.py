@@ -19,7 +19,6 @@ the histogram-subtraction trick's O(min(|L|,|R|)) economics
 """
 from __future__ import annotations
 
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -75,6 +74,9 @@ _HOST_FIELDS = tuple(f for f in DeviceTree._fields
 class FusedTreeLearner(SerialTreeLearner):
     """Whole-tree-per-dispatch learner. Reuses SerialTreeLearner's dataset
     plumbing (bin meta, split params, feature sampling)."""
+
+    #: smallest row window _pick_chunk resolves
+    min_chunk = 1 << 12
 
     def __init__(self, dataset: BinnedDataset, config: Config) -> None:
         super().__init__(dataset, config)
@@ -190,9 +192,9 @@ class FusedTreeLearner(SerialTreeLearner):
         # voting mode: keep histograms local, vote top-k features, psum
         # only voted columns (set by FusedVotingParallelTreeLearner)
         self.voting: bool = False
-        # u32-lane packing of the gathered row matrix (A/B knob; see the
-        # pack32 block in _pack_rows)
-        self.pack32 = os.environ.get("LAMBDAGAP_PACK32", "1") != "0"
+        # u32-lane packing of the packed row matrix (the pack32 block in
+        # _pack_rows); the stream path above keeps bin-dtype columns
+        self.pack32 = True
         # tree_layout=sorted (docs/performance.md): the packed row matrix
         # is (re)built leaf-ordered by a separate jitted pre-pass per tree
         # — dispatched under the layout_apply telemetry span so its cost
@@ -283,16 +285,15 @@ class FusedTreeLearner(SerialTreeLearner):
         """Pack the binned rows plus their per-row channels into ONE
         row-major matrix in the bin dtype, bitcast to u32 lanes (pack32):
         the histogram pass then runs ONE random gather per row window
-        instead of two (the 8 B gh gather pays near-full random latency
-        despite 3.5x fewer bytes than the row fetch; merging them removed
-        it — measured 4.84 -> 4.64 s/iter at full HIGGS size), and one u32
-        element carrying 4 binned uint8 columns (2 uint16) cuts the hot
-        pass's element count ~4x (2x); lanes decode with one bitcast after
-        the fetch (reference analog: cuda_row_data.hpp:32-117 packs rows
-        by bit width for the same reason). Costs: one streaming repack
-        pass per tree (~19 ms at 10.5M rows) and a second resident copy of
-        the binned matrix, ~N*(C+8) bytes — ~380 MB at full HIGGS size
-        against the chip's 16 GB."""
+        instead of two (the 8 B gh gather pays a random access of its
+        own beside the row fetch), and one u32 element carrying 4 binned
+        uint8 columns (2 uint16) cuts the hot pass's element count ~4x
+        (2x); lanes decode with one bitcast after the fetch (reference
+        analog: cuda_row_data.hpp:32-117 packs rows by bit width for the
+        same reason). Costs: one streaming repack pass per tree
+        (`layout_device_ms` 18.767 on higgs-train, 30.41 on
+        istella-s-train; ledger, PR 32) and a second resident copy of the
+        binned matrix, ~N*(C+8) bytes."""
         gh_cols, q_cols, mask_col = self._packed_meta(has_mask)
         parts = [x_rows]
         if gh_cols:
@@ -343,22 +344,6 @@ class FusedTreeLearner(SerialTreeLearner):
             return jnp.concatenate(
                 [packed, jnp.zeros((W, packed.shape[1]), packed.dtype)])
 
-    @staticmethod
-    def _chunk_override() -> Optional[int]:
-        """Debug/bench knob: LAMBDAGAP_CHUNK forces the window size (used
-        for the measured W sweeps in the bench notes). Rounded to a power
-        of two; malformed values are ignored loudly."""
-        import os
-        raw = os.environ.get("LAMBDAGAP_CHUNK")
-        if not raw:
-            return None
-        try:
-            return max(_next_pow2(int(raw)), 1 << 10)
-        except ValueError:
-            from ..utils import log
-            log.warning("LAMBDAGAP_CHUNK=%r is not an integer; ignored", raw)
-            return None
-
     def _pick_chunk(self) -> int:
         """Chunk window for the while-loop'd row passes: small enough that a
         deep (small) leaf doesn't pay a huge padded window of gather/scan
@@ -368,20 +353,25 @@ class FusedTreeLearner(SerialTreeLearner):
         Sized off HALF the average leaf population N/num_leaves, not N:
         padding waste across one tree is ~num_leaves * W/2 rows against
         ~N*log2(L) total row-touches, so a window near the deep-leaf size
-        keeps waste ~10% where an N-scaled window pays ~40% at the HIGGS
-        shape (10.5M rows, 255 leaves; measured 5.21 vs 5.65 s/iter on the
-        bench chip). The round-5 sweep under u32-lane packing moved the
-        optimum one notch smaller still: W=32768 measured 4.44 s/iter vs
-        65536's 4.61 and 131072's 5.05 at full HIGGS shape (replicated;
-        one corrupted-window outlier excluded). Inside one compiled
+        keeps the waste near a tenth of the rows touched where an
+        N-scaled window pads several times that. Inside one compiled
         program extra while-loop trips cost only loop control, not kernel
-        launches."""
-        forced = self._chunk_override()
-        if forced is not None:
-            return forced
+        launches (`partition_us_per_trip` 149.92 and 142.68; ledger, PR
+        32). The two cells resolve W = 32,768 (10.5M rows) and 8,192
+        (3.41M); W has not been swept on the benchmark.
+
+        The learners that shard rows size it off their LOCAL rows
+        (``n_loc``) with a lower floor (``min_chunk``): per-shard leaf
+        populations are n_dev-times smaller, so a wide window is mostly
+        padding. Window size cannot change quantized results (integer
+        accumulation is window-invariant); stream and hbm residencies
+        MUST agree on W per grid — it is the accumulation-order contract
+        the stream mirrors replay."""
+        n = int(getattr(self, "n_loc", self.num_data))   # this shard's rows
         cap = max(int(self.config.tpu_rows_per_block) * 16, 1 << 12)
-        per_leaf = self.num_data // max(self.config.num_leaves, 8)
-        return min(max(_next_pow2(max(per_leaf // 2, 1)), 1 << 12), cap)
+        per_leaf = n // max(self.config.num_leaves, 8)
+        return min(max(_next_pow2(max(per_leaf // 2, 1)), self.min_chunk),
+                   cap)
 
     # ------------------------------------------------------------------
     def train_device(self, grad: jax.Array, hess: jax.Array,
@@ -418,13 +408,9 @@ class FusedTreeLearner(SerialTreeLearner):
                                          has_mask=row_mask is not None)
         else:
             srows = self._srows_dummy
-        from ..obs import costplane
-        rec = costplane.observed_call(
-            "train.fused", self._train_jit,
-            (grad, hess, mask, fmask, self.hx_rows, self.x_cols, srows,
-             gq, hq, gs, hs, ekey),
-            dict(has_mask=row_mask is not None),
-            bucket=int(grad.shape[0]), phase="tree")
+        rec = self._train_jit(
+            grad, hess, mask, fmask, self.hx_rows, self.x_cols, srows,
+            gq, hq, gs, hs, ekey, has_mask=row_mask is not None)
         self.last_row_leaf = rec.row_leaf
         return rec
 

@@ -30,7 +30,6 @@ from ..ops.predict import (_round_depth, build_forest_blocks,
 from ..ops.predict_tensor import (build_tree_tiles, predict_forest_leaf_tensor,
                                   predict_forest_tensor)
 from ..guard.nonfinite import NULL_GUARD, TrainGuard
-from ..obs import costplane
 from ..obs.telemetry import NULL_TELEMETRY, TrainTelemetry, device_scope
 from ..utils import log
 from .learner import SerialTreeLearner
@@ -221,7 +220,6 @@ class GBDT:
                 log.fatal("Cannot use the %s objective with linear_tree",
                           self.objective.name)
             self.objective.init(ds.metadata, ds.num_data)
-        costplane.PLANE.configure(self.config)
         self.telemetry = TrainTelemetry.from_config(self.config)
         self.guard = TrainGuard.from_config(self.config)
         self.learner = self._create_learner(ds)
@@ -1214,11 +1212,8 @@ class GBDT:
             # below, so averaging/conversion here is shared unchanged
             cf = self._compiled_forest(start_iteration, num_iteration,
                                        es_freq)
-            with costplane.PLANE.wall("predict"):
-                # device_get inside the bracket: the noted wall is
-                # device-complete (the cost plane's roofline join contract)
-                res = np.asarray(jax.device_get(
-                    cf.predict(jnp.asarray(data))))
+            res = np.asarray(jax.device_get(
+                cf.predict(jnp.asarray(data))))
             if self.average_output:
                 res = res / max(1, len(idx) // max(K, 1))
             return res[0] if K == 1 else res.T
@@ -1227,13 +1222,12 @@ class GBDT:
         # accumulates each leaf's dot product from the padded coefficient
         # tables stacked into the forest arrays (ops/linear.py), so serve's
         # compiled buckets and this path stay bit-identical
-        with costplane.PLANE.wall("predict"):
-            out = dispatch_forest_predict(
-                self.config, jnp.asarray(data), forest, tree_class, K,
-                depth, binned=False, early_stop_freq=es_freq,
-                early_stop_margin=float(self.config.pred_early_stop_margin),
-                blocks=blocks, has_linear=has_linear)
-            res = np.asarray(jax.device_get(out))
+        out = dispatch_forest_predict(
+            self.config, jnp.asarray(data), forest, tree_class, K,
+            depth, binned=False, early_stop_freq=es_freq,
+            early_stop_margin=float(self.config.pred_early_stop_margin),
+            blocks=blocks, has_linear=has_linear)
+        res = np.asarray(jax.device_get(out))
         if self.average_output:
             n_iters = max(1, len(idx) // max(K, 1))
             res = res / n_iters
@@ -1274,31 +1268,16 @@ class GBDT:
             log.fatal("pred_contrib input has %d features but the model "
                       "splits on feature %d", F_data, max_f)
         phi = np.zeros((K, N, F_data + 1), dtype=np.float64)
-        with costplane.PLANE.wall("predict_shap"):
-            for pos, i in enumerate(idx):
-                t = trees[pos]
-                if getattr(t, "is_linear", False):
-                    # coefficient-attribution split (arXiv:1802.05640): the
-                    # structural TreeSHAP runs over leaf CONSTANTS, the
-                    # linear terms attribute directly to their features —
-                    # rows still sum to the raw prediction (models/shap.py)
-                    tree_shap_linear(t, data, phi[i % K])
-                else:
-                    tree_shap_accumulate(t, data, phi[i % K])
-        if costplane.PLANE.enabled:
-            # host numpy loop, no XLA lowering to inspect: an analytic
-            # traffic model stands in (TreeSHAP visits each leaf's root
-            # path once per row: ~O(N * leaves * depth^2) flops; each tree
-            # pass streams the row matrix and accumulates into phi)
-            leaves = sum(max(int(t.num_leaves), 1) for t in trees)
-            depth_sq = max(int(self.config.max_depth), 6) ** 2
-            costplane.PLANE.record_host(
-                "predict.shap",
-                flops=float(N) * leaves * depth_sq,
-                bytes_accessed=float(len(trees)) * data.nbytes
-                + 2.0 * phi.nbytes,
-                peak_hbm_bytes=int(data.nbytes + phi.nbytes),
-                phase="predict_shap", bucket=N)
+        for pos, i in enumerate(idx):
+            t = trees[pos]
+            if getattr(t, "is_linear", False):
+                # coefficient-attribution split (arXiv:1802.05640): the
+                # structural TreeSHAP runs over leaf CONSTANTS, the
+                # linear terms attribute directly to their features —
+                # rows still sum to the raw prediction (models/shap.py)
+                tree_shap_linear(t, data, phi[i % K])
+            else:
+                tree_shap_accumulate(t, data, phi[i % K])
         if self.average_output:
             phi /= max(1, len(idx) // max(K, 1))
         if K == 1:

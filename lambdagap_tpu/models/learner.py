@@ -23,7 +23,6 @@ import numpy as np
 
 from ..config import Config
 from ..data.dataset import BinnedDataset
-from ..obs import costplane
 from ..obs.telemetry import NULL_TELEMETRY
 from ..ops.histogram import (full_histogram, leaf_histogram,
                              leaf_histogram_sorted)
@@ -327,11 +326,12 @@ class SerialTreeLearner:
 
     def _resolve_layout(self, config: Config) -> str:
         """Resolve ``tree_layout``: 'auto' picks the physically sorted-leaf
-        layout at shapes where gather-issue cost dominates the histogram
-        pass (the BENCH_r05 roofline: random row-gathers issue at
-        ~30 Mrows/s where the same bytes stream at ~20 GB/s); small data
-        keeps the gather layout — the sorted copy's rebuild-per-tree and
-        extra residency are not worth it there (docs/performance.md)."""
+        layout at shapes where a random row gather per window dominates
+        the histogram pass and the same bytes stream from a leaf-ordered
+        copy (both cells resolve ``sorted``); small data keeps the gather
+        layout — the sorted copy's rebuild-per-tree and extra residency
+        are not worth it there. The 2^20-row threshold has no cell on
+        either side of it (ROADMAP D4)."""
         layout = config.tree_layout
         if not self.supports_sorted_layout:
             if layout == "sorted":
@@ -449,16 +449,14 @@ class SerialTreeLearner:
             mono_pen = jnp.where(self.mono_arr != 0, mp, 1.0)
             contri = mono_pen if contri is None else contri * mono_pen
         with self.telemetry.phase("split"):
-            res = costplane.observed_call(
-                "train.serial.split", find_best_split,
-                (hist, pg, ph, pc, parent_output,
-                 self.num_bins_arr, self.default_bins_arr,
-                 self.missing_types_arr, self.is_categorical_arr,
-                 self._node_fmask(fmask, path_feats), self.params),
-                dict(has_categorical=self.has_categorical,
-                     constraints=cons, gain_penalty=pen,
-                     rand_thresholds=rand_t, gain_contri=contri),
-                phase="split")
+            res = find_best_split(
+                hist, pg, ph, pc, parent_output,
+                self.num_bins_arr, self.default_bins_arr,
+                self.missing_types_arr, self.is_categorical_arr,
+                self._node_fmask(fmask, path_feats), self.params,
+                has_categorical=self.has_categorical,
+                constraints=cons, gain_penalty=pen,
+                rand_thresholds=rand_t, gain_contri=contri)
             return _HostSplit(jax.device_get(res))
 
     # advanced monotone method -------------------------------------------
@@ -606,18 +604,14 @@ class SerialTreeLearner:
             # physically reordered matrix — consecutive-index read, no
             # row gather (identical rows in identical order, so the
             # histogram is bit-identical to the gather oracle's)
-            return costplane.observed_call(
-                "train.serial.histogram", leaf_histogram_sorted,
-                (self._x_sorted, self._gh_sorted, jnp.int32(begin),
-                 jnp.int32(count), padded, self.B, self.rows_per_block,
-                 self.hist_precision),
-                bucket=padded, phase="histogram")
-        return costplane.observed_call(
-            "train.serial.histogram", leaf_histogram,
-            (self.x_binned, perm, grad, hess, jnp.int32(begin),
-             jnp.int32(count), padded, self.B, self.rows_per_block,
-             row_mask, self.hist_precision),
-            bucket=padded, phase="histogram")
+            return leaf_histogram_sorted(
+                self._x_sorted, self._gh_sorted, jnp.int32(begin),
+                jnp.int32(count), padded, self.B, self.rows_per_block,
+                self.hist_precision)
+        return leaf_histogram(
+            self.x_binned, perm, grad, hess, jnp.int32(begin),
+            jnp.int32(count), padded, self.B, self.rows_per_block,
+            row_mask, self.hist_precision)
 
     def _leaf_histogram_stream(self, grad, hess, begin, count, padded,
                                row_mask):
@@ -856,31 +850,27 @@ class SerialTreeLearner:
                     # sorted layout: apply the stable partition physically
                     # to the row payload + gradient channels as well
                     (perm, self._x_sorted, self._gh_sorted,
-                     left_cnt_dev) = costplane.observed_call(
-                        "train.serial.partition", split_partition_sorted,
-                        (self._x_sorted, self._gh_sorted, perm,
-                         jnp.int32(begin), jnp.int32(count),
-                         jnp.int32(feat), jnp.int32(s.threshold),
-                         jnp.asarray(bool(s.default_left)),
-                         self.default_bins_arr[feat],
-                         self.missing_types_arr[feat],
-                         self.num_bins_arr[feat],
-                         jnp.asarray(bool(s.is_categorical)),
-                         jnp.asarray(s.cat_bitset), P),
-                        bucket=P, phase="partition")
+                     left_cnt_dev) = split_partition_sorted(
+                        self._x_sorted, self._gh_sorted, perm,
+                        jnp.int32(begin), jnp.int32(count),
+                        jnp.int32(feat), jnp.int32(s.threshold),
+                        jnp.asarray(bool(s.default_left)),
+                        self.default_bins_arr[feat],
+                        self.missing_types_arr[feat],
+                        self.num_bins_arr[feat],
+                        jnp.asarray(bool(s.is_categorical)),
+                        jnp.asarray(s.cat_bitset), P)
                 else:
-                    perm, left_cnt_dev = costplane.observed_call(
-                        "train.serial.partition", split_partition,
-                        (self.x_binned, perm,
-                         jnp.int32(begin), jnp.int32(count),
-                         jnp.int32(feat), jnp.int32(s.threshold),
-                         jnp.asarray(bool(s.default_left)),
-                         self.default_bins_arr[feat],
-                         self.missing_types_arr[feat],
-                         self.num_bins_arr[feat],
-                         jnp.asarray(bool(s.is_categorical)),
-                         jnp.asarray(s.cat_bitset), P),
-                        bucket=P, phase="partition")
+                    perm, left_cnt_dev = split_partition(
+                        self.x_binned, perm,
+                        jnp.int32(begin), jnp.int32(count),
+                        jnp.int32(feat), jnp.int32(s.threshold),
+                        jnp.asarray(bool(s.default_left)),
+                        self.default_bins_arr[feat],
+                        self.missing_types_arr[feat],
+                        self.num_bins_arr[feat],
+                        jnp.asarray(bool(s.is_categorical)),
+                        jnp.asarray(s.cat_bitset), P)
                 left_cnt = int(jax.device_get(left_cnt_dev))
             right_cnt = count - left_cnt
             if _DEBUG_CHECKS and row_mask is None:

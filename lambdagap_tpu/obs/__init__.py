@@ -9,7 +9,7 @@ The telemetry subsystem the perf work reports through (docs/observability.md):
   timing is taken ONCE per iteration boundary (a single
   ``block_until_ready``), so no host sync lands inside hot paths.
 - :mod:`.events` — JSONL structured run log (run header, one record per
-  iteration, compile/swap/error events): the artifact BENCH runs diff.
+  iteration, compile/swap/error events).
 - :mod:`.xla_watch` — recompile & transfer watchdog over ``jax.monitoring``
   events; warns when a steady-state iteration triggers a fresh compile
   (the graftlint-R2 hazard class, caught at runtime).
@@ -31,21 +31,13 @@ The telemetry subsystem the perf work reports through (docs/observability.md):
 - :mod:`.signals` — derived control signals (online goodput-knee,
   residency/eviction pressure, per-replica health timeline): the inputs
   ROADMAP item 2's revival/placement/autoscaling loop consumes.
-- :mod:`.costplane` — the analytic cost plane (graftmeter): a
-  per-executable FLOP/byte/HBM ledger captured at lowering time for every
-  jit entry point (the three learners, the three predict engines, the
-  ``predict_stream`` window scorer, SHAP), joined with measured phase
-  walls into per-phase fraction-of-roofline, persisted as ``COSTS.json``
-  and gated in CI by ``tools/cost_gate.py``.
 
 Everything is inert unless enabled (``telemetry=true`` / ``telemetry_out=``
-/ ``LAMBDAGAP_TIMETAG``; ``serve_trace_sample>0`` for tracing;
-``cost_plane=true`` / ``cost_plane_out=`` for the cost ledger): the off
+/ ``LAMBDAGAP_TIMETAG``; ``serve_trace_sample>0`` for tracing): the off
 path records nothing and registers no ``jax.monitoring`` hooks.
 """
 from __future__ import annotations
 
-from .costplane import CostPlane  # noqa: F401
 from .reservoir import MergedReservoir, Reservoir, merge_states  # noqa: F401
 from .telemetry import NULL_TELEMETRY, TrainTelemetry  # noqa: F401
 from .trace import (RECORDER, FlightRecorder, SpanRecorder,  # noqa: F401
@@ -54,4 +46,4 @@ from .trace import (RECORDER, FlightRecorder, SpanRecorder,  # noqa: F401
 __all__ = ["Reservoir", "MergedReservoir", "merge_states",
            "TrainTelemetry", "NULL_TELEMETRY", "TraceContext",
            "SpanRecorder", "FlightRecorder", "RECORDER", "start_trace",
-           "validate_tree", "CostPlane"]
+           "validate_tree"]

@@ -1,7 +1,7 @@
 """Structured JSONL run log: the machine-readable training artifact.
 
 One ``telemetry_out=`` file per run; every line is one JSON object. This is
-the artifact BENCH_r0N trajectories and regression triage diff against, so
+the artifact regression triage diffs against, so
 the schema is versioned and validated (``validate_record`` /
 ``validate_file`` — used by tests/test_obs.py and the run_full_suite.sh
 telemetry gate).

@@ -266,65 +266,11 @@ def render_train(telemetry) -> str:
     return w.text()
 
 
-def render_costplane(plane=None) -> str:
-    """Cost-plane ledger (obs/costplane.py) -> Prometheus text: one
-    labeled sample per (program, bucket) executable for the analytic
-    flops / bytes-accessed / peak-HBM facts and observed dispatch counts,
-    plus the per-phase roofline join. Empty string when the plane is
-    disarmed (the scrape stays byte-identical with the knob off)."""
-    if plane is None:
-        from .costplane import PLANE as plane
-    if not plane.enabled or not plane.entries:
-        return ""
-    w = _Writer()
-    p = "lambdagap_cost_"
-    doc = plane.to_json()
-    per_exec = (
-        ("program_flops", "flops", "Analytic FLOPs per dispatch of the "
-         "executable", "gauge"),
-        ("program_bytes_accessed", "bytes_accessed", "Analytic bytes "
-         "accessed per dispatch", "gauge"),
-        ("program_peak_hbm_bytes", "peak_hbm_bytes", "Peak HBM of the "
-         "compiled executable (arg+out+temp+code)", "gauge"),
-        ("program_calls_total", "calls", "Observed dispatches of the "
-         "executable", "counter"),
-    )
-    for name, field, help_, type_ in per_exec:
-        full = p + name
-        w.sample_header(full, help_, type_)
-        for e in doc["entries"].values():
-            w.sample(full, e.get(field, 0) or 0,
-                     {"program": e["program"], "bucket": e["bucket"]})
-    attr = doc["attribution"]
-    name = p + "phase_roofline_seconds"
-    w.sample_header(name, "Analytic roofline floor per phase (s)", "gauge")
-    for phase, rec in attr["phases"].items():
-        if "roofline_s" in rec:     # absent on a device with no peak row
-            w.sample(name, rec["roofline_s"], {"phase": phase,
-                                               "bound": rec["bound"]})
-    name = p + "phase_roofline_fraction"
-    w.sample_header(name, "Achieved fraction of the analytic roofline "
-                    "(wall-joined phases only)", "gauge")
-    for phase, rec in attr["phases"].items():
-        if "fraction_of_roofline" in rec:
-            w.sample(name, rec["fraction_of_roofline"], {"phase": phase})
-    name = p + "phase_wall_seconds"
-    w.sample_header(name, "Measured device-complete wall per phase (s)",
-                    "counter")
-    for phase, rec in doc["walls"].items():
-        w.sample(name, rec["seconds"], {"phase": phase})
-    return w.text()
-
-
 def render(telemetry=None, serve_snapshot: Optional[Dict] = None) -> str:
-    """Combined exposition; either side may be absent. The cost-plane
-    section rides along whenever the ledger is armed and non-empty."""
+    """Combined exposition; either side may be absent."""
     parts = []
     if telemetry is not None:
         parts.append(render_train(telemetry))
     if serve_snapshot is not None:
         parts.append(render_serve(serve_snapshot))
-    cost = render_costplane()
-    if cost:
-        parts.append(cost)
     return "".join(parts)

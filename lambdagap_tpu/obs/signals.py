@@ -1,11 +1,10 @@
 """Derived control signals: the signal plane fleet autonomics consume.
 
-BENCH_serve priced two cliffs as one-shot bench artifacts — the open-loop
-goodput knee (12.7k rps raw throughput at 0.12 goodput) and the 174x
-readmission cost — and ROADMAP item 2's control loop (revival, placement,
-autoscaling) is blocked on exactly those numbers being *continuously
-computed online*. This module turns the fleet metric plane's scrape
-stream (obs/fleet.py) into three documented signals:
+Two cliffs of a served fleet — the open-loop goodput knee and the cost of
+readmitting an evicted model — have to be *continuously computed online*
+before a control loop (revival, placement, autoscaling) can act on them;
+neither has been measured on a chip. This module turns the fleet metric
+plane's scrape stream (obs/fleet.py) into three documented signals:
 
 ``goodput`` — an online knee estimator. Each scrape yields an interval
     offered rate (Δ accepted+shed requests / Δt) and a deadline-met

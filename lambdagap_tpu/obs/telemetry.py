@@ -301,35 +301,10 @@ class TrainTelemetry:
         self._finalize()
         if self.profile is not None:
             self.profile.close(self.iterations)
-        self._join_cost_plane()
         self.watchdog.uninstall()
         if self.run_log is not None:
             self.run_log.close()
         self._closed = True
-
-    def _join_cost_plane(self) -> None:
-        """Push the run's measured phase walls into the cost plane (the
-        wall side of its roofline join), append the ledger to the run log
-        as a ``cost_plane`` event, and persist COSTS.json when
-        ``cost_plane_out`` asked for it. No-op when the plane is off."""
-        from .costplane import PLANE
-        if not PLANE.enabled:
-            return
-        for name, secs in self.totals.items():
-            PLANE.note_wall(name, secs, calls=self.counts.get(name, 1))
-        if self.run_log is not None:
-            try:
-                attr = PLANE.attribution()
-                self.run_log.event("cost_plane",
-                                   entries=len(PLANE.entries),
-                                   phases=attr["phases"],
-                                   peaks=attr["peaks"])
-            except Exception as e:  # pragma: no cover
-                log.debug("cost plane run-log export failed: %s", e)
-        try:
-            PLANE.write()
-        except Exception as e:  # pragma: no cover - unwritable path
-            log.warning("cost plane: COSTS.json write failed: %s", e)
 
     # -- internals ------------------------------------------------------
     def _close_iter_annotation(self) -> None:
@@ -428,9 +403,6 @@ class TrainTelemetry:
         out.update({k: v for k, v in self.watchdog.totals().items()
                     if k in ("compiles", "steady_compiles", "transfers",
                              "compile_secs")})
-        from .costplane import PLANE
-        if PLANE.enabled and PLANE.entries:
-            out["cost_plane"] = PLANE.attribution()
         return out
 
     def report(self) -> str:

@@ -106,8 +106,8 @@ class TraceContext:
 class SpanRecorder:
     """Per-process span/event sink: a bounded ring (the flight-recorder
     buffer), optional JSONL output with bounded-interval flushing, and
-    per-name duration reservoirs (the aggregate the signal plane and
-    ``bench_serve trace_breakdown`` read). Thread-safe; records are plain
+    per-name duration reservoirs (the aggregate the signal plane
+    reads). Thread-safe; records are plain
     dicts in the :mod:`.events` schema."""
 
     def __init__(self, ring: int = 4096, out: str = "",
@@ -469,29 +469,9 @@ class FlightRecorder:
         lines = [json.dumps(hdr, separators=(",", ":"), default=str)]
         lines += [json.dumps(r, separators=(",", ":"), default=str)
                   for r in recs]
-        cost = self._cost_plane_record()
-        if cost is not None:
-            lines.append(json.dumps(cost, separators=(",", ":"),
-                                    default=str))
         atomic_write_text(self.path, "\n".join(lines) + "\n")
         self.dumps += 1
         return self.path
-
-    def _cost_plane_record(self) -> Optional[Dict[str, Any]]:
-        """One ``cost_plane`` event record appended to each dump when the
-        analytic ledger is armed: the postmortem of a killed replica then
-        carries the per-executable traffic facts next to its last spans."""
-        try:
-            from .costplane import PLANE
-            if not PLANE.enabled or not PLANE.entries:
-                return None
-            attr = PLANE.attribution()
-            return {"type": "event", "event": "cost_plane",
-                    "proc": self.recorder.proc, "time_unix": time.time(),
-                    "entries": len(PLANE.entries),
-                    "phases": attr["phases"], "peaks": attr["peaks"]}
-        except Exception:  # pragma: no cover - the dump must never fail
-            return None
 
     # -- hooks ----------------------------------------------------------
     def install(self) -> "FlightRecorder":

@@ -19,7 +19,6 @@ iterating inlined trees, reference: src/boosting/gbdt_prediction.cpp).
 from __future__ import annotations
 
 import functools
-import os
 from typing import List, NamedTuple, Optional, Tuple
 
 import jax
@@ -28,6 +27,9 @@ import numpy as np
 from jax import lax
 
 K_ZERO_THRESHOLD = 1e-35
+#: trees per dispatch of the blocked forest traversals (and of serve's
+#: compiled forest): no single kernel grows with the forest
+TREE_BLOCK = 64
 MT_NONE, MT_ZERO, MT_NAN = 0, 1, 2
 
 
@@ -320,7 +322,7 @@ def _predict_forest_block(x: jax.Array, forest: TreeArrays,
 
 
 def build_forest_blocks(forest: TreeArrays, tree_class: jax.Array,
-                        tree_block: Optional[int] = None):
+                        tree_block: int = TREE_BLOCK):
     """Pre-slice a stacked forest into bounded, padded tree blocks ONCE.
 
     The blocked predict paths used to re-slice and zero-pad-concatenate the
@@ -334,8 +336,6 @@ def build_forest_blocks(forest: TreeArrays, tree_class: jax.Array,
     entries, or ``None`` when the forest fits a single dispatch (callers
     pass the unsliced forest through unchanged in that case)."""
     T = int(tree_class.shape[0])
-    if tree_block is None:
-        tree_block = int(os.environ.get("LAMBDAGAP_PREDICT_TREE_BLOCK", 64))
     if tree_block <= 0 or T <= tree_block:
         return None
     out = []
@@ -349,7 +349,7 @@ def predict_forest(x: jax.Array, forest: TreeArrays, tree_class: jax.Array,
                    num_class: int, max_depth: int, binned: bool,
                    early_stop_freq: int = 0,
                    early_stop_margin: float = 0.0,
-                   tree_block: Optional[int] = None,
+                   tree_block: int = TREE_BLOCK,
                    blocks=None, has_linear: bool = False) -> jax.Array:
     """Sum a whole forest's leaf values into per-class scores.
 
@@ -369,7 +369,7 @@ def predict_forest(x: jax.Array, forest: TreeArrays, tree_class: jax.Array,
     (reference: src/boosting/gbdt_prediction.cpp, cuda_tree.cu:459).
 
     The scan is dispatched in bounded blocks of ``tree_block`` trees
-    (default ``LAMBDAGAP_PREDICT_TREE_BLOCK`` or 64) with the accumulator
+    (default :data:`TREE_BLOCK`) with the accumulator
     carried between dispatches: no single kernel grows with the forest,
     at the cost of T/block dispatches. Forests at most one block long
     compile to the identical single kernel as before.
@@ -384,27 +384,20 @@ def predict_forest(x: jax.Array, forest: TreeArrays, tree_class: jax.Array,
         "linear forests traverse raw rows; binned linear replay is host-side"
     N = x.shape[0]
     T = tree_class.shape[0]
-    if tree_block is None:
-        tree_block = int(os.environ.get("LAMBDAGAP_PREDICT_TREE_BLOCK", 64))
     init = (jnp.zeros((num_class, N), jnp.float32),
             jnp.zeros(N, dtype=bool), jnp.int32(0))
-    from ..obs import costplane
     if blocks is None:
         if tree_block <= 0 or T <= tree_block:
-            out, _, _ = costplane.observed_call(
-                "predict.scan", _predict_forest_block,
-                (x, forest, tree_class, init, num_class, max_depth,
-                 binned, early_stop_freq, early_stop_margin, has_linear),
-                bucket=N, phase="predict")
+            out, _, _ = _predict_forest_block(
+                x, forest, tree_class, init, num_class, max_depth,
+                binned, early_stop_freq, early_stop_margin, has_linear)
             return out
         blocks = build_forest_blocks(forest, tree_class, tree_block)
     carry = init
     for blk, tc, _ in blocks:
-        carry = costplane.observed_call(
-            "predict.scan", _predict_forest_block,
-            (x, blk, tc, carry, num_class, max_depth, binned,
-             early_stop_freq, early_stop_margin, has_linear),
-            bucket=N, phase="predict")
+        carry = _predict_forest_block(
+            x, blk, tc, carry, num_class, max_depth, binned,
+            early_stop_freq, early_stop_margin, has_linear)
     return carry[0]
 
 
@@ -440,7 +433,7 @@ def _predict_forest_leaf_block(x: jax.Array, forest: TreeArrays,
 
 def predict_forest_leaf(x: jax.Array, forest: TreeArrays,
                         max_depth: int, binned: bool,
-                        tree_block: Optional[int] = None,
+                        tree_block: int = TREE_BLOCK,
                         blocks=None) -> jax.Array:
     """Leaf index per (tree, row) for a whole forest: [T, N] int32.
 
@@ -449,8 +442,6 @@ def predict_forest_leaf(x: jax.Array, forest: TreeArrays,
     forests). ``blocks`` from
     :func:`build_forest_blocks` skips the per-call forest re-slice."""
     T = forest.leaf_value.shape[0]
-    if tree_block is None:
-        tree_block = int(os.environ.get("LAMBDAGAP_PREDICT_TREE_BLOCK", 64))
     if blocks is None:
         if tree_block <= 0 or T <= tree_block:
             return _predict_forest_leaf_block(x, forest, max_depth, binned)

@@ -33,8 +33,6 @@ decision for decision; that per-tree path stays behind
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,9 +42,9 @@ from .predict import (K_ZERO_THRESHOLD, MT_NAN, MT_ZERO, TreeArrays,
                       build_forest_blocks)
 
 
-def default_tree_tile() -> int:
-    """predict_tree_tile default (env override for benchmarking)."""
-    return int(os.environ.get("LAMBDAGAP_PREDICT_TREE_TILE", 64))
+#: trees per tensorized tile dispatch when the caller passes no
+#: ``predict_tree_tile``
+TREE_TILE = 64
 
 
 def _traverse_tile(x: jax.Array, t: TreeArrays, max_depth: int,
@@ -194,13 +192,11 @@ def _leaf_tensor_tile(x: jax.Array, t: TreeArrays, max_depth: int,
 
 
 def build_tree_tiles(forest: TreeArrays, tree_class: jax.Array,
-                     tree_tile: Optional[int] = None):
+                     tree_tile: int = TREE_TILE):
     """Pre-slice a stacked forest into ``predict_tree_tile``-sized tiles
     ONCE (same padded-tail layout as :func:`predict.build_forest_blocks`,
     so either engine can consume the result). Returns None when the forest
     fits one tile."""
-    if tree_tile is None:
-        tree_tile = default_tree_tile()
     return build_forest_blocks(forest, tree_class, tree_tile)
 
 
@@ -209,7 +205,7 @@ def predict_forest_tensor(x: jax.Array, forest: TreeArrays,
                           max_depth: int, binned: bool,
                           early_stop_freq: int = 0,
                           early_stop_margin: float = 0.0,
-                          tree_tile: Optional[int] = None,
+                          tree_tile: int = TREE_TILE,
                           tiles=None, has_linear: bool = False) -> jax.Array:
     """Tensorized drop-in for :func:`ops.predict.predict_forest`.
 
@@ -223,39 +219,30 @@ def predict_forest_tensor(x: jax.Array, forest: TreeArrays,
         "linear forests traverse raw rows; binned linear replay is host-side"
     N = x.shape[0]
     T = tree_class.shape[0]
-    if tree_tile is None:
-        tree_tile = default_tree_tile()
     init = (jnp.zeros((num_class, N), jnp.float32),
             jnp.zeros(N, dtype=bool), jnp.int32(0))
-    from ..obs import costplane
     if tiles is None:
         if tree_tile <= 0 or T <= tree_tile:
-            out, _, _ = costplane.observed_call(
-                "predict.tensor", _predict_tensor_tile,
-                (x, forest, tree_class, init, num_class, max_depth,
-                 binned, early_stop_freq, early_stop_margin, has_linear),
-                bucket=N, phase="predict")
+            out, _, _ = _predict_tensor_tile(
+                x, forest, tree_class, init, num_class, max_depth,
+                binned, early_stop_freq, early_stop_margin, has_linear)
             return out
         tiles = build_tree_tiles(forest, tree_class, tree_tile)
     carry = init
     for blk, tc, _ in tiles:
-        carry = costplane.observed_call(
-            "predict.tensor", _predict_tensor_tile,
-            (x, blk, tc, carry, num_class, max_depth, binned,
-             early_stop_freq, early_stop_margin, has_linear),
-            bucket=N, phase="predict")
+        carry = _predict_tensor_tile(
+            x, blk, tc, carry, num_class, max_depth, binned,
+            early_stop_freq, early_stop_margin, has_linear)
     return carry[0]
 
 
 def predict_forest_leaf_tensor(x: jax.Array, forest: TreeArrays,
                                max_depth: int, binned: bool,
-                               tree_tile: Optional[int] = None,
+                               tree_tile: int = TREE_TILE,
                                tiles=None) -> jax.Array:
     """Tensorized drop-in for :func:`ops.predict.predict_forest_leaf`:
     leaf index per (tree, row), [T, N] int32."""
     T = forest.leaf_value.shape[0]
-    if tree_tile is None:
-        tree_tile = default_tree_tile()
     if tiles is None:
         if tree_tile <= 0 or T <= tree_tile:
             return _leaf_tensor_tile(x, forest, max_depth, binned)

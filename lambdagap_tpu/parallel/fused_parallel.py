@@ -63,6 +63,7 @@ class FusedDataParallelTreeLearner(FusedTreeLearner):
     # the opt-out only fires for pre-partitioned multi-process data
     # (process-local rows have no host-shard pump) — still a loud demote
     supports_stream = False
+    min_chunk = 1 << 10
 
     def __init__(self, dataset: BinnedDataset, config: Config,
                  mesh: Optional[Mesh] = None) -> None:
@@ -165,25 +166,6 @@ class FusedDataParallelTreeLearner(FusedTreeLearner):
         self.x_cols = jax.device_put(
             jnp.asarray(np.ascontiguousarray(hx.T)),
             NamedSharding(self.mesh, spec("x_cols")))
-
-    def _pick_chunk(self) -> int:
-        # sized off LOCAL rows, not the global count, and with a lower floor
-        # than the serial learner's 4096: per-shard leaf populations are
-        # n_dev-times smaller, so a wide window is mostly padding (measured
-        # 3.2x -> 1.2x vs serial fused on the 8-CPU mesh). The per-leaf
-        # estimate is HALVED like the serial learner's — the leaf-wise tree
-        # splits every population in two, so a full-per-leaf window pays
-        # ~2x padding on every shard from depth 1 on (measured 50 -> 42
-        # s/iter at the 512k-row multichip shape on the 8-virtual-CPU
-        # mesh; window size cannot change quantized results — integer
-        # accumulation is window-invariant — and f32 histograms remain
-        # reduction-order-equal)
-        forced = self._chunk_override()
-        if forced is not None:
-            return forced
-        cap = max(int(self.config.tpu_rows_per_block) * 16, 1 << 12)
-        per_leaf = self.n_loc // max(self.config.num_leaves, 8)
-        return min(max(_next_pow2(max(per_leaf // 2, 1)), 1 << 10), cap)
 
     # ------------------------------------------------------------------
     def _shard_vec(self, v: jax.Array) -> jax.Array:
@@ -515,6 +497,7 @@ class Fused2DTreeLearner(FusedTreeLearner):
     # express a column another shard owns
     supports_sorted_layout = False
     supports_stream = True
+    min_chunk = 1 << 10
 
     def __init__(self, dataset: BinnedDataset, config: Config,
                  mesh: Optional[Mesh] = None) -> None:
@@ -600,18 +583,6 @@ class Fused2DTreeLearner(FusedTreeLearner):
             jnp.asarray(np.ascontiguousarray(hx.T)),
             NamedSharding(self.mesh, spec("x_cols")))
 
-    def _pick_chunk(self) -> int:
-        # sized off LOCAL rows (the fused data-parallel rationale at
-        # fused_parallel.py FusedDataParallelTreeLearner._pick_chunk);
-        # stream and hbm residencies MUST agree on W per grid — it is the
-        # accumulation-order contract the stream mirror replays
-        forced = self._chunk_override()
-        if forced is not None:
-            return forced
-        cap = max(int(self.config.tpu_rows_per_block) * 16, 1 << 12)
-        per_leaf = self.n_loc // max(self.config.num_leaves, 8)
-        return min(max(_next_pow2(max(per_leaf // 2, 1)), 1 << 10), cap)
-
     def _feature_mask(self) -> jax.Array:
         # sample over the REAL features only, pad False (pad columns can
         # never win)
@@ -663,14 +634,9 @@ class Fused2DTreeLearner(FusedTreeLearner):
             ekey = jnp.zeros((2, 2), jnp.uint32)
         g = self._shard_vec(grad)
         h = self._shard_vec(hess)
-        from ..obs import costplane
-        rec = costplane.observed_call(
-            "train.fused2d", self._train_jit_2d,
-            (g, h, m, fmask, self.hx_rows, self.x_cols,
-             self._srows_dummy, gq, hq, gs, hs, ekey),
-            bucket=int(g.shape[0]), phase="tree",
-            shard_spec=",".join(f"{a}={self.mesh.shape[a]}"
-                                for a in self.mesh.axis_names))
+        rec = self._train_jit_2d(
+            g, h, m, fmask, self.hx_rows, self.x_cols,
+            self._srows_dummy, gq, hq, gs, hs, ekey)
         rec = rec._replace(row_leaf=rec.row_leaf[:self.num_data])
         self.last_row_leaf = rec.row_leaf
         return rec

@@ -50,6 +50,26 @@ import numpy as np
 from ..guard.degrade import ServeOverloaded, ServeTimeout
 
 
+class ResolvedAtFuture(Future):
+    """A Future that notes when it was resolved (``t_done``, perf_counter).
+
+    ``Future.set_result`` wakes the waiters BEFORE it runs the done
+    callbacks, so a callback that reads the clock may read it after the
+    caller has already returned: a span closed there ends after its
+    parent (the resolving worker only has to be descheduled in between).
+    A traced request's ``serve_request`` span ends at ``t_done``."""
+
+    t_done: Optional[float] = None
+
+    def set_result(self, result) -> None:
+        self.t_done = time.perf_counter()
+        super().set_result(result)
+
+    def set_exception(self, exception) -> None:
+        self.t_done = time.perf_counter()
+        super().set_exception(exception)
+
+
 class Request:
     """One queued predict: rows + the future its caller waits on, plus the
     registry model it targets, the tenant it bills to, and (when sampled)
@@ -63,7 +83,8 @@ class Request:
                  tenant: Optional[str] = None,
                  trace=None) -> None:
         self.x = x
-        self.future: Future = Future()
+        self.future: Future = (Future() if trace is None
+                               else ResolvedAtFuture())
         self.t_submit = time.perf_counter()
         self.t_wall = time.time()        # epoch twin: span t0s align across processes
         self.deadline = deadline         # absolute perf_counter time, or None

@@ -23,7 +23,6 @@ takes the device path.
 """
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Optional, Sequence, Tuple
@@ -32,7 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.predict import build_forest_blocks, forest_to_arrays, predict_forest
+from ..ops.predict import (TREE_BLOCK, build_forest_blocks, forest_to_arrays,
+                           predict_forest)
 from ..ops.predict_tensor import build_tree_tiles, predict_forest_tensor
 from ..utils import log
 
@@ -80,7 +80,7 @@ class CompiledForestCache:
     def __init__(self, gbdt, buckets: Optional[Sequence[int]] = None,
                  start_iteration: int = 0, num_iteration: int = -1,
                  generation: int = 0, stats=None,
-                 tree_block: Optional[int] = None,
+                 tree_block: int = TREE_BLOCK,
                  artifact_store=None) -> None:
         self.gbdt = gbdt
         self.generation = int(generation)
@@ -113,9 +113,6 @@ class CompiledForestCache:
         self.width = max(1, 1 + max(
             (max(t.split_feature[:t.num_internal], default=0)
              for t in trees), default=0)) if trees else 1
-        if tree_block is None:
-            tree_block = int(os.environ.get("LAMBDAGAP_PREDICT_TREE_BLOCK",
-                                            64))
         self._tree_block = tree_block
         # traversal engine: the tensorized [rows x trees] engine is the
         # serving default (predict_engine=tensor); the sequential scan
@@ -260,10 +257,8 @@ class CompiledForestCache:
         if (self._forest is None and self._compiled is None) or N == 0:
             res = np.zeros((K, N), dtype=np.float32)
             return res[0] if K == 1 else res.T
-        from ..obs import costplane
         parts = []
         lo = 0
-        t_dispatch = time.perf_counter()
         for n, b in self.plan(N):
             chunk = X[lo:lo + n]
             lo += n
@@ -282,11 +277,6 @@ class CompiledForestCache:
             # graftlint: disable=R1 — the terminal D2H of the response is
             # inherent to serving: results must reach the client as numpy
             parts.append(np.asarray(jax.device_get(out))[:, :n])
-        # every chunk ended in a device_get, so this wall is device-
-        # complete — the serve-side join the cost plane's roofline uses
-        costplane.PLANE.note_wall("serve_dispatch",
-                                  time.perf_counter() - t_dispatch,
-                                  calls=len(parts))
         res = parts[0] if len(parts) == 1 else np.concatenate(parts, axis=1)
         return res[0] if K == 1 else res.T
 

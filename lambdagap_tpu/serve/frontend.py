@@ -358,6 +358,13 @@ class ServeFrontend:
             client.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             conn = _Conn(client, self)
             with self._conn_lock:
+                # close() takes its snapshot of _conns under this lock
+                # after setting _closed: a connection accepted while it
+                # ran is either in the snapshot or refused here, never
+                # left open behind a closed door
+                if self._closed:
+                    client.close()
+                    break
                 self._conns.add(conn)
             threading.Thread(target=conn.run, daemon=True,
                              name=f"lambdagap-serve-conn-{addr[1]}").start()
@@ -369,7 +376,8 @@ class ServeFrontend:
     def close(self) -> None:
         """Stop accepting and drop connections. The target server is NOT
         closed — the frontend is a door, not the house."""
-        self._closed = True
+        with self._conn_lock:
+            self._closed = True
         if self._sock is not None:
             try:
                 self._sock.close()

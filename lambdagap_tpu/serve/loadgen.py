@@ -1,8 +1,7 @@
 """Open-loop load generation: offered load the server cannot gate.
 
-bench_serve.py's original clients are CLOSED-loop: each keeps a bounded
-window in flight, so when the server slows down the clients slow down with
-it and "offered load" silently collapses to whatever the server admits —
+A CLOSED-loop client keeps a bounded window in flight, so when the
+server slows down the clients slow down with it and "offered load" silently collapses to whatever the server admits —
 saturation becomes unmeasurable (every closed-loop bench reports a happy
 server at 100% of its own pace). The generator here is OPEN-loop: request
 arrival times are fixed IN ADVANCE from an arrival rate — deterministic

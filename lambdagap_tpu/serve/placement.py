@@ -1,7 +1,8 @@
 """HBM-aware model placement: bin-pack models onto replicas by design.
 
-BENCH_serve priced what an LRU accident costs: a request landing on a
-replica that evicted its model pays a 174-214x p50 readmission cliff.
+A request landing on a replica that evicted its model pays a
+readmission (compile or load, then warm) before it is served; what that
+costs has not been measured on a chip.
 With every replica admitting every model under its own
 ``serve_hbm_budget_mb``, WHICH model is resident WHERE is decided by
 arrival order — the one thing production traffic does not control. This

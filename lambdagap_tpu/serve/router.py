@@ -38,6 +38,7 @@ from ..guard.degrade import (DEGRADED, DRAINING, OK, ReplicaUnavailable,
 from ..guard.faults import InjectedFault
 from ..obs import trace as obs_trace
 from ..utils import log
+from .batcher import ResolvedAtFuture
 
 # exceptions that indict the REPLICA, not the request: these trigger
 # failover to another replica (transport failures additionally mark the
@@ -195,18 +196,19 @@ class Router:
         failover count paid)."""
         if self._closed:
             raise RuntimeError("router closed")
-        outer: Future = Future()
         ctx = trace if trace is not None \
             else obs_trace.RECORDER.maybe_trace()
+        outer: Future = Future() if ctx is None else ResolvedAtFuture()
         hop = None
         if ctx is not None:
             hop = ctx.child()            # the route span's own context
             t0_wall, t0 = time.time(), time.perf_counter()
             route_state = {"replica": None, "failovers": 0}
 
-            def _record(_f) -> None:
+            def _record(f) -> None:
                 obs_trace.RECORDER.record(
-                    "route", ctx, t0_wall, time.perf_counter() - t0,
+                    "route", ctx, t0_wall,
+                    (f.t_done or time.perf_counter()) - t0,
                     span_id=hop.span_id,
                     replica=route_state["replica"],
                     failovers=route_state["failovers"])

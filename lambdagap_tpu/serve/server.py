@@ -248,10 +248,13 @@ class ForestServer:
         if tenant is not None:
             attrs["tenant"] = tenant
 
-        def _record(_f) -> None:
+        def _record(f) -> None:
+            # ends where the future resolved, not where this callback
+            # runs: the waiter is woken first (batcher.ResolvedAtFuture)
             obs_trace.RECORDER.record(
                 "serve_request", ctx, t0_wall,
-                time.perf_counter() - t0, span_id=child.span_id, **attrs)
+                (f.t_done or time.perf_counter()) - t0,
+                span_id=child.span_id, **attrs)
 
         fut.add_done_callback(_record)
         return fut

@@ -24,7 +24,7 @@ class ServeStats:
 
     Times are recorded in seconds and reported in milliseconds. Schema of
     :meth:`snapshot` is documented in docs/serving.md and is the JSON the
-    ``task=serve`` CLI and ``bench_serve.py`` emit.
+    ``task=serve`` CLI emits.
     """
 
     def __init__(self, max_samples: int = 100_000) -> None:

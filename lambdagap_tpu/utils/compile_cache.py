@@ -1,8 +1,8 @@
 """Where JAX's persistent compilation cache lives.
 
 The one place in the repo that sets ``jax_compilation_cache_dir``. Process
-entry points (``python -m lambdagap_tpu`` via ``cli.run``, ``bench.py``,
-``bench_serve.py``, ``chip_smoke.py``) call :func:`configure_compile_cache`
+entry points (``python -m lambdagap_tpu`` via ``cli.run``,
+``benchmark/run.py``, ``chip_smoke.py``) call :func:`configure_compile_cache`
 once before their first compile; the library itself never does, so an
 embedding program keeps its own choice.
 

@@ -1,9 +1,9 @@
-"""R7 fixture: cost-plane wall joins (the obs/costplane.py note_wall feeds).
+"""R7 fixture: a clock delta handed to a recorder as a call argument.
 
-A wall noted into the cost plane is divided into analytic rooflines, so an
-unsynced bracket poisons every fraction-of-roofline built on it: the bad
-bracket times only the enqueue of the dispatch it wraps. Good brackets end
-device-complete (device_get / block_until_ready) before the clock is read.
+A wall noted into a stats sink is later divided into rates, so an unsynced
+bracket poisons every rate built on it: the bad bracket times only the
+enqueue of the dispatch it wraps. Good brackets end device-complete
+(device_get / block_until_ready) before the clock is read.
 """
 import time
 

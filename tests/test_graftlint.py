@@ -17,7 +17,6 @@ import os
 import re
 import subprocess
 import sys
-import time
 
 import pytest
 
@@ -73,7 +72,7 @@ def fixture_findings():
     "parallel/r6_2d_program.py",
     "parallel/stream2d.py",
     "obs/r7_unsynced_timing.py",
-    "obs/costplane.py",
+    "obs/r7_wall_sink.py",
     "serve/r8_futures.py",
     "serve/r8_router.py",
     "serve/r9_cycle_a.py",
@@ -325,9 +324,7 @@ def test_checked_in_baseline_is_writer_normalized():
     """The committed baseline round-trips through the deterministic
     writer unchanged — no hand-edit drift."""
     current = open(BASELINE).read()
-    findings = scan([PKG, os.path.join(REPO, "bench.py"),
-                     os.path.join(REPO, "bench_serve.py"),
-                     os.path.join(REPO, "tools")])
+    findings = scan([PKG, os.path.join(REPO, "tools")])
     import tempfile
     with tempfile.TemporaryDirectory() as td:
         out = os.path.join(td, "bl.json")
@@ -339,21 +336,24 @@ def test_checked_in_baseline_is_writer_normalized():
 
 # -- the G0 time budget -------------------------------------------------
 def test_two_pass_scan_inside_g0_budget():
-    """ISSUE-10 acceptance: the full two-pass run (index build + all 11
-    rules) over the package completes in < 2 s. Best of two runs: the
-    budget bounds the SCAN, and a single measurement deep inside a busy
-    tier-1 container measures the scheduler as much as the analyzer (one
-    observed 2x inflation mid-suite against a 0.75 s idle scan); a real
-    regression slows both runs, a preempted slice only one. The G0 gate
-    (`--max-seconds 2` in run_full_suite.sh) still enforces the budget on
-    a single live run."""
-    elapsed = []
-    for _ in range(2):
-        t0 = time.perf_counter()
-        scan([PKG])
-        elapsed.append(time.perf_counter() - t0)
-    assert min(elapsed) < 2.0, \
-        f"scan took {[f'{e:.2f}' for e in elapsed]}s (budget 2s)"
+    """ISSUE-10 acceptance: the full two-pass run (index build + all
+    rules) over the package stays inside the G0 budget of 2 s. A clock
+    cannot hold that inside tier-1: beside five other workers the same
+    scan read 1.75-9.3 s of ``time.process_time`` against 1.1 s alone
+    (this container's cores are shared below the guest, so even CPU time
+    stretches). The budget is therefore held in the scan's own work:
+    Python and C function calls as ``cProfile`` counts them, which depend
+    on the sources and on nothing else. 12,000,000 calls is what 2 s buy
+    on an idle core here (6.64M calls took 1.1 s, PR 33). The G0 gate
+    (`--max-seconds 2` in run_full_suite.sh) still enforces the wall
+    budget on a single live run."""
+    import cProfile
+    prof = cProfile.Profile()
+    prof.enable()
+    scan([PKG])
+    prof.disable()
+    calls = sum(e.callcount for e in prof.getstats())
+    assert 0 < calls < 12_000_000, f"scan made {calls:,} calls"
 
 
 # -- CLI ----------------------------------------------------------------

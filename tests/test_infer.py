@@ -268,7 +268,7 @@ def test_prune_dominated_branch_exact():
 
 
 def test_merge_tiled_trees_shares_traversal():
-    """An iteration-tiled forest (the bench_serve shape) collapses to the
+    """An iteration-tiled forest (every iteration the same structures) collapses to the
     base structure count: merged trees share one traversal group while
     keeping their own leaf values — outputs stay exact."""
     X, y = _data()

@@ -85,9 +85,14 @@ def _grow(dataset: str, layout: str) -> dict:
     }
 
 
+def _small_window(self) -> int:
+    return 1024
+
+
 @pytest.fixture
 def small_windows(monkeypatch):
-    monkeypatch.setenv("LAMBDAGAP_CHUNK", "1024")
+    from lambdagap_tpu.models.fused_learner import FusedTreeLearner
+    monkeypatch.setattr(FusedTreeLearner, "_pick_chunk", _small_window)
 
 
 @pytest.mark.parametrize("dataset,layout", CASES)
@@ -103,9 +108,10 @@ def test_forest_equals_parent_commits(small_windows, dataset, layout):
 if __name__ == "__main__":
     import sys
     assert sys.argv[1:] == ["--record"], __doc__
-    os.environ["LAMBDAGAP_CHUNK"] = "1024"
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from lambdagap_tpu.models.fused_learner import FusedTreeLearner
+    FusedTreeLearner._pick_chunk = _small_window
     out = {f"{d}-{layout}": _grow(d, layout) for d, layout in CASES}
     os.makedirs(os.path.dirname(GOLDEN), exist_ok=True)
     with open(GOLDEN, "w") as f:

@@ -348,7 +348,9 @@ def _brute_force_counts(tree, binned, window: int) -> dict:
 
 @pytest.mark.parametrize("bagging", [False, True], ids=["all_rows", "bagged"])
 def test_work_counts_equal_a_brute_force_count(bagging, monkeypatch):
-    monkeypatch.setenv("LAMBDAGAP_CHUNK", "1024")   # several trips a pass
+    from lambdagap_tpu.models.fused_learner import FusedTreeLearner
+    monkeypatch.setattr(FusedTreeLearner, "_pick_chunk",
+                        lambda self: 1024)          # several trips a pass
     rng = np.random.default_rng(3)
     X = rng.normal(size=(5000, 6)).astype(np.float32)
     y = (X[:, 0] * X[:, 1] + X[:, 2] > 0).astype(np.float32)

@@ -390,6 +390,11 @@ def test_frontend_client_dead_socket_resolves_pending():
             fut.result(timeout=10)                # value (already served)…
         except (ReplicaUnavailable, ConnectionError):
             pass                                  # …or the transport error
+    # all four may have been served before the close: the client knows the
+    # socket is dead once its reader has seen the end of the stream, which
+    # is its own thread's business, not a matter of time
+    c._reader.join(timeout=30)
+    assert not c._reader.is_alive() and not c.alive
     with pytest.raises(ReplicaUnavailable):
         c.submit(X[:1])
     c.close()

@@ -44,13 +44,24 @@ def test_csr_construct_memory_envelope():
     """Constructing from CSR must peak WELL below the dense float
     footprint. 400k x 500 f64 dense = 1.6 GB; the binned matrix is 200 MB.
     The check runs in a subprocess so other tests' allocations don't
-    pollute maxrss."""
+    pollute the peak, and reads ``VmHWM`` of ``/proc/self/status`` (the
+    high-water mark of the child's OWN address space): ``ru_maxrss``
+    starts at the forking parent's resident size, so under a tier-1
+    worker that has run other files it read over 1,000 MB before the
+    child had imported anything. What is bounded is the construct's own
+    growth over what was resident when it started."""
     code = r"""
-import resource, sys
 import numpy as np
 import jax; jax.config.update("jax_platforms", "cpu")
 import scipy.sparse as sp
 import lambdagap_tpu as lgb
+
+def status_mb(key):
+    for ln in open("/proc/self/status"):
+        if ln.startswith(key + ":"):
+            return int(ln.split()[1]) / 1024
+    raise KeyError(key)
+
 rng = np.random.RandomState(0)
 n, d = 400_000, 500
 nnz_per_row = 5                      # density 0.01
@@ -59,23 +70,24 @@ indices = rng.randint(0, d, n * nnz_per_row).astype(np.int32)
 data = rng.randn(n * nnz_per_row).astype(np.float64)
 X = sp.csr_matrix((data, indices, indptr), shape=(n, d))
 y = rng.randint(0, 2, n).astype(float)
+before_mb = status_mb("VmRSS")
 ds = lgb.Dataset(X, label=y, params={"max_bin": 63,
                                      "bin_construct_sample_cnt": 20000})
 b = ds.construct()
 assert b.num_data == n and b.binned.shape[0] == n
-peak_mb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024
-print("PEAK_MB", peak_mb)
-# dense f64 would be 1600 MB on top of everything else; peak memory is
-# bounded by baseline + binned matrix (200 MB) + the bin-finding sample
-# (20k x 500 f64 = 80 MB) + one 64k-row chunk (256 MB)
-assert peak_mb < 1000, peak_mb
+grew_mb = status_mb("VmHWM") - before_mb
+print("GREW_MB", grew_mb)
+# dense f64 would be 1600 MB; the construct's growth is bounded by the
+# binned matrix (200 MB) + the bin-finding sample (20k x 500 f64 = 80 MB)
+# + one 64k-row chunk (256 MB) = 536 MB (372 measured alone, PR 33)
+assert 100 < grew_mb < 600, grew_mb
 """
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, cwd=os.getcwd(), env=env, timeout=540)
     assert r.returncode == 0, (r.stdout[-500:], r.stderr[-2000:])
-    assert "PEAK_MB" in r.stdout
+    assert "GREW_MB" in r.stdout
 
 
 @pytest.mark.parametrize("fmt", ["tsv", "libsvm"])

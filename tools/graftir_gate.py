@@ -71,7 +71,7 @@ def main(argv=None) -> int:
 
     if args.sarif_out:
         rc_l, lint = _capture(["--format", "sarif", "lambdagap_tpu",
-                               "bench.py", "bench_serve.py", "tools"])
+                               "tools"])
         rc_i, ir = _capture(["--ir", "--format", "sarif"])
         merged = cli.merge_sarif([lint, ir])
         out_dir = os.path.dirname(args.sarif_out)
