@@ -52,6 +52,13 @@ def _discount(rank):
     return 1.0 / jnp.log2(2.0 + rank)
 
 
+def _gain_gap(hi_is_i, gi, gj):
+    """``label_gain[high label] - label_gain[low label]`` of each pair, from
+    the two documents' own gains: a label's gain belongs to its document, so
+    it is looked up once a document (``lattice``) and only oriented here."""
+    return jnp.where(hi_is_i, gi, gj) - jnp.where(hi_is_i, gj, gi)
+
+
 def _next_pow2(n: int) -> int:
     p = 1
     while p < n:
@@ -381,10 +388,11 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
             f"tile={tile} must divide the padded bucket length "
             f"{scores.shape[1]}")
 
-    def pair_block(i, j, si, sj, li, lj, vij, imd, imb, best, worst):
+    def pair_block(i, j, si, sj, li, lj, gi, gj, vij, imd, imb, best, worst):
         """All pair quantities for one [bi, bj] block of the sorted
-        lattice. i/j are rank indices ([bi,1] / [1,bj]); s/l are the
-        score/label slices at those ranks; vij the validity product.
+        lattice. i/j are rank indices ([bi,1] / [1,bj]); s/l/g are the
+        score/label/label-gain slices at those ranks; vij the validity
+        product.
         Returns (lam_to_row [bi,bj] signed lambda for the ROW doc,
         p_hessian [bi,bj], sum_p_lambda scalar, pair_count scalar); the
         COLUMN doc's lambda is minus the row's (accumulated by the
@@ -410,8 +418,6 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
         hi_is_i = li > lj
         hs = jnp.where(hi_is_i, si, sj)
         lo_s = jnp.where(hi_is_i, sj, si)
-        hl = jnp.where(hi_is_i, li, lj).astype(jnp.int32)
-        ll = jnp.where(hi_is_i, lj, li).astype(jnp.int32)
         hr = jnp.where(hi_is_i, i, j)          # rank of the high-label doc
         lr = jnp.where(hi_is_i, j, i)
         delta_score = hs - lo_s
@@ -421,7 +427,7 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
         disc_lr = _discount(lr.astype(jnp.float32))
         paired_lambdarank = jnp.abs(disc_hr - disc_lr)
         paired_lambdaloss = _discount(rank_diff) - _discount(rank_diff + 1.0)
-        gain_gap = label_gain[hl] - label_gain[ll]
+        gain_gap = _gain_gap(hi_is_i, gi, gj)
 
         # delta_pair per target (reference: rank_objective.hpp:398-489)
         if target == "ndcg":
@@ -487,11 +493,12 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
         sorted order and the effective pair rate."""
         L = ss.shape[0]
         ranks = jnp.arange(L, dtype=jnp.int32)
+        gs = label_gain[ls.astype(jnp.int32)]  # [L]: one lookup a document
         if tile is None:
             lam_to_row, p_hessian, sum_pl, count_lambdas = pair_block(
                 ranks[:, None], ranks[None, :], ss[:, None], ss[None, :],
-                ls[:, None], ls[None, :], vs[:, None] & vs[None, :],
-                imd, imb, best, worst)
+                ls[:, None], ls[None, :], gs[:, None], gs[None, :],
+                vs[:, None] & vs[None, :], imd, imb, best, worst)
             lam_sorted = (jnp.sum(lam_to_row, axis=1)
                           - jnp.sum(lam_to_row, axis=0))
             hes_sorted = (jnp.sum(p_hessian, axis=1)
@@ -506,6 +513,7 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
             jr = ranks[None, :]
             sj = ss[None, :]
             lj = ls[None, :]
+            gj = gs[None, :]
             vj = vs[None, :]
 
             def body(b, carry):
@@ -514,8 +522,9 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
                 ir = (off + jnp.arange(T, dtype=jnp.int32))[:, None]
                 si = lax.dynamic_slice(ss, (off,), (T,))[:, None]
                 li = lax.dynamic_slice(ls, (off,), (T,))[:, None]
+                gi = lax.dynamic_slice(gs, (off,), (T,))[:, None]
                 vi = lax.dynamic_slice(vs, (off,), (T,))[:, None]
-                ltr, ph, s1, c1 = pair_block(ir, jr, si, sj, li, lj,
+                ltr, ph, s1, c1 = pair_block(ir, jr, si, sj, li, lj, gi, gj,
                                              vi & vj, imd, imb, best, worst)
                 lam_row = lax.dynamic_update_slice(
                     lam_row,

@@ -210,13 +210,14 @@ def test_scope_nesting_is_only_where_the_vocabulary_says():
                      ("partition", "partition_scatter")}
 
 
-def _lambdarank_loop(sizes):
+def _lambdarank_loop(sizes, target="ndcg"):
     rng = np.random.default_rng(2)
     n = int(np.sum(sizes))
     X = rng.normal(size=(n, 3)).astype(np.float32)
     ds = lgb.Dataset(X, label=rng.integers(0, 4, n).astype(np.float32),
                      group=sizes)
-    gb = lgb.Booster({"objective": "lambdarank", "verbose": -1}, ds)._booster
+    gb = lgb.Booster({"objective": "lambdarank", "verbose": -1,
+                      "lambdarank_target": target}, ds)._booster
     gb.boosting()                           # builds the jitted program
     obj = gb.objective
     return obj._loop_jit.lower(
@@ -291,6 +292,60 @@ def test_the_ranking_gradient_program_is_tiled_by_its_inner_scopes():
     assert kinds["rank_sort"]["stablehlo.sort"] == 2 * 5
     assert not kinds["rank_lattice"]["stablehlo.sort"] \
         and not kinds["rank_lattice"]["stablehlo.scatter"]
+
+
+# the three targets whose pair weight reads the labels' gains
+GAIN_TARGETS = ["ndcg", "lambdaloss-ndcg", "lambdaloss-ndcg-plus-plus"]
+
+
+def _lower_bucket(target, nq, L, tile):
+    """One bucket kernel alone, lowered at ``[nq, L]``."""
+    from lambdagap_tpu.objectives.rank import _lambdarank_bucket
+    f = jnp.zeros((nq, L), jnp.float32)
+    return _lambdarank_bucket.lower(
+        f, f, jnp.ones((nq, L), bool), jnp.ones(nq), jnp.ones(nq),
+        jnp.arange(4, dtype=jnp.float32), target=target, sigmoid=1.0,
+        norm=True, truncation_level=20, lambdagap_weight=0.5, tile=tile)
+
+
+@pytest.mark.parametrize("form", ["dense", "tiled"])
+@pytest.mark.parametrize("target", GAIN_TARGETS)
+def test_the_lattice_looks_a_gain_up_once_a_document(target, form):
+    """A label's gain is a property of a DOCUMENT: under ``rank_lattice``
+    the one gather of a bucket is ``label_gain[ls]`` over its ``[nq, L]``
+    sorted documents (PR 34). A lookup per pair cell (``[nq, L, L]``, two a
+    bucket: the orientation done on the labels, not on the gains) is the
+    regression: on the chip it compiled to stand-alone gathers that took
+    19 % of ``istella-s-train``'s iteration."""
+    if form == "dense":
+        # three queries a bucket: nq x L (24 ... 384) is no bucket's L x L
+        sizes = [5] * 3 + [12] * 3 + [24] * 3 + [40] * 3 + [100] * 3
+        lowered = _lambdarank_loop(sizes, target)
+        docs, slices = [3 * L for L in (8, 16, 32, 64, 128)], []
+    else:
+        # the row-block sweep, as test_rank's tiled-against-dense test
+        # reaches it; under ``vmap`` each ``dynamic_slice`` of a row block
+        # (scores, labels, gains, validity, the two row sums) is a gather of
+        # nq x T elements
+        nq, L, T = 3, 256, 64
+        lowered = _lower_bucket(target, nq, L, T)
+        docs, slices = [nq * L], [nq * T] * 6
+    gathers = sorted(max(sizes) for kind, sizes
+                     in ops_under(lowered, "rank_lattice")
+                     if kind == "stablehlo.gather")
+    # one lookup a bucket, of its nq x L documents: nothing of a pair
+    # block's size (L x L dense, T x L tiled; 64 cells the least here)
+    assert gathers == sorted(docs + slices), gathers
+
+
+@pytest.mark.parametrize("target", sorted(
+    set(lgb.config.LAMBDARANK_TARGETS) - set(GAIN_TARGETS)))
+def test_a_target_that_reads_no_gain_carries_no_lookup(target):
+    """The other fifteen targets never read ``gain_gap``: the per-document
+    vector is dead code there and is dropped when the program is lowered,
+    so their lattices hold no gather at all, as before PR 34."""
+    lowered = _lower_bucket(target, 3, 64, None)
+    assert not op_kinds_under(lowered, "rank_lattice")["stablehlo.gather"]
 
 
 def test_the_vocabulary_is_closed():
