@@ -343,4 +343,65 @@ def render_matrix(cells: Sequence[MatrixCell]) -> str:
     lines.append(f"{len(cells)} cell(s); axes audited: "
                  + ", ".join(f"`{k}`" for k in AXIS_KNOBS) + ".")
     lines.append("")
+    lines += render_metric_forms()
     return "\n".join(lines)
+
+
+def metric_forms() -> List[Tuple[str, str, bool]]:
+    """``(metric name, module, has a device form)`` of every registered
+    metric class, read from ``lambdagap_tpu/metrics/*.py`` without
+    importing them: a class has a device form when it, or a base class of
+    its module, defines ``eval_device`` (``metrics/base.py``)."""
+    import glob
+    import os
+    root = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), "metrics")
+    out = []
+    for path in sorted(glob.glob(os.path.join(root, "*.py"))):
+        with open(path, "r", encoding="utf-8") as f:
+            tree = ast.parse(f.read())
+        classes = {n.name: n for n in tree.body
+                   if isinstance(n, ast.ClassDef)}
+
+        def on_device(cls) -> bool:
+            if any(isinstance(n, ast.FunctionDef) and n.name == "eval_device"
+                   for n in cls.body):
+                return True
+            return any(isinstance(b, ast.Name) and b.id in classes
+                       and on_device(classes[b.id]) for b in cls.bases)
+        for cls in classes.values():
+            registered = any(isinstance(d, ast.Name)
+                             and d.id == "register_metric"
+                             for d in cls.decorator_list)
+            names = [n.value.value for n in cls.body
+                     if isinstance(n, ast.Assign)
+                     and any(isinstance(t, ast.Name) and t.id == "name"
+                             for t in n.targets)
+                     and isinstance(n.value, ast.Constant)]
+            if registered and names:
+                out.append((names[0], "metrics/" + os.path.basename(path),
+                            on_device(cls)))
+    return sorted(out)
+
+
+def render_metric_forms() -> List[str]:
+    """The doc's second table: where each metric is evaluated."""
+    lines = [
+        "## Where a metric is evaluated",
+        "",
+        "With a validation set attached (or a training metric asked for) "
+        "the scores stay on the device. A metric with a **device** form "
+        "(`Metric.eval_device`) is computed there and only its values are "
+        "read back, 4 bytes each; a **host** metric has the set's scores "
+        "read back whole, once an evaluation, and runs its float64 numpy "
+        "statement (`Metric.eval`), which every metric keeps and the tests "
+        "hold the device forms to. Extracted from `lambdagap_tpu/metrics/`.",
+        "",
+        "| metric | where | evaluated on |",
+        "|---|---|---|",
+    ]
+    for name, path, device in metric_forms():
+        lines.append(f"| `{name}` | `{path}` | "
+                     f"{'device' if device else 'host'} |")
+    lines.append("")
+    return lines

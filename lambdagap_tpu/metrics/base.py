@@ -1,9 +1,12 @@
 """Metric interface + factory.
 
 (reference: include/LightGBM/metric.h:24 Metric, src/metric/metric.cpp:24-133
-factory.) Metrics consume converted scores (numpy, host) — evaluation is
-O(N log N) at worst and happens once per ``metric_freq`` iterations, so the
-host is the right place; heavy per-iteration math stays on device.
+factory.) ``eval`` consumes converted scores on the host (numpy, float64):
+the statement every metric has, and what the tests hold a device form to.
+A metric that overrides ``eval_device`` is computed where the scores live
+and only its values are read back (``GBDT._eval_sets``): with a validation
+set watched every iteration, reading N scores back and walking the queries
+in Python was the iteration's largest host cost.
 """
 from __future__ import annotations
 
@@ -37,6 +40,19 @@ class Metric:
         """scores: converted predictions [N] or [K, N]. Returns
         [(metric_name, value)]."""
         raise NotImplementedError
+
+    def eval_device(self, scores):
+        """The metric on DEVICE-resident converted scores (``[N]`` or
+        ``[K, N]`` float32), as ``(names, float32[len(names)] device
+        array)``: only the values cross to the host. ``None`` where the
+        metric has no device form; the caller then reads the scores back
+        and calls :meth:`eval` (docs/observability.md lists which have
+        one)."""
+        return None
+
+    #: host constants of the device form for the iteration record's
+    #: ``counts`` (the ranking metrics' padded buckets)
+    work_counts: Dict[str, int] = {}
 
     def _avg(self, pointwise: np.ndarray) -> float:
         if self.weight is not None:

@@ -6,11 +6,16 @@ PrecisionMetric with its cumulative-hit bucket formula.)
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..config import Config
+from ..obs.telemetry import device_scope
 from .base import Metric, register_metric
 
 
@@ -77,6 +82,99 @@ class NDCGMetric(_RankMetricBase):
             out.append(dcg / max_dcg if max_dcg > 0 else 1.0)
         return out
 
+    # -- the device form ------------------------------------------------
+    @functools.cached_property
+    def _dev(self):
+        """What :func:`_ndcg_at` needs beside the scores, built on the host
+        at the first use (a metric that only ever runs :meth:`eval` never
+        pays for it): the queries in the padded buckets the ranking objectives use
+        (``objectives.rank._QueryBuckets``), per bucket the documents'
+        indices, their gains, and per query ``weight / maxDCG@k`` (the
+        inverse max DCG precomputed at init as rank_metric.hpp does; 0
+        where a query has no relevant document: it counts 1, as a constant
+        of the set). Float64 here, float32 on the device."""
+        from ..objectives.rank import _QueryBuckets
+        ks = tuple(int(k) for k in self.eval_at)
+        kmax = max(ks)
+        disc = 1.0 / np.log2(2.0 + np.arange(kmax))
+        label_pad = np.concatenate([self.label.astype(np.int64), [-1]])
+        gain_of = np.concatenate([self.label_gain, [0.0]])    # [-1]: a pad
+        weights = (np.ones(self.num_queries) if self.query_weights is None
+                   else np.asarray(self.query_weights, np.float64))
+        buckets, ones = [], np.zeros(len(ks))
+        for L, qids, idx in _QueryBuckets(self.qb, self.num_data).buckets:
+            lab = label_pad[idx]                              # [nq, L]
+            gain = gain_of[lab]
+            top = min(kmax, L)
+            ideal = gain_of[-np.sort(-lab, axis=1)[:, :top]] * disc[:top]
+            max_dcg = np.stack([ideal[:, :min(k, L)].sum(axis=1)
+                                for k in ks], axis=1)         # [nq, nk]
+            w = weights[qids][:, None]
+            some = max_dcg > 0
+            scale = np.where(some, w / np.where(some, max_dcg, 1.0), 0.0)
+            ones += np.sum(np.where(some, 0.0, w), axis=0)
+            buckets.append((jnp.asarray(idx), jnp.asarray(gain, jnp.float32),
+                            jnp.asarray(scale, jnp.float32)))
+        return (ks, tuple(buckets), jnp.asarray(disc, jnp.float32),
+                jnp.asarray(ones / self.sum_qw, jnp.float32),
+                jnp.float32(1.0 / self.sum_qw))
+
+    @property
+    def work_counts(self):
+        return {"valid_queries": int(self.num_queries),
+                "valid_pad_docs": sum(int(b[0].size) for b in self._dev[1])}
+
+    def eval_device(self, scores):
+        if scores.ndim == 2:
+            if scores.shape[0] != 1:
+                return None
+            scores = scores[0]
+        ks, buckets, disc, ones, inv_sum_qw = self._dev
+        return ([f"{self.name}@{k}" for k in ks],
+                _ndcg_at(scores, buckets, disc, ones, inv_sum_qw, ks=ks))
+
+
+def _pairwise_sum(x: jax.Array) -> jax.Array:
+    """Sum over axis 0 by halving: every value passes through log2(n)
+    float32 additions, not n (7,000 per-query values summed in a row lose
+    the sixth digit of their mean)."""
+    n = 1 << max(x.shape[0] - 1, 0).bit_length()
+    x = jnp.concatenate([x, jnp.zeros((n - x.shape[0],) + x.shape[1:],
+                                      x.dtype)])
+    while n > 1:
+        n //= 2
+        x = x[:n] + x[n:]
+    return x[0]
+
+
+@functools.partial(jax.jit, static_argnames=("ks",))
+def _ndcg_at(scores, buckets, disc, ones, inv_sum_qw, ks):
+    """NDCG@k of every ``k`` in ``ks`` on device-resident scores ``[N]``:
+    per bucket the scores gathered into ``[queries, L]``, ONE stable sort a
+    bucket by descending score with the gains riding along (a tie keeps the
+    earlier document first: ``std::stable_sort`` in dcg_calculator.cpp,
+    ``kind="stable"`` in :meth:`NDCGMetric._per_query`; pad slots sort
+    last and gain 0), the top ``k`` gains against ``1 / log2(2 + i)``, each
+    query's DCG times its ``weight / maxDCG``. Returns float32 ``[len(ks)]``:
+    what leaves the device."""
+    with device_scope("valid_metric"):
+        # descending by score = ascending by its negative; a NaN sorts with
+        # the lowest scores (the host's argsort puts it last)
+        key = jnp.where(jnp.isnan(scores), jnp.inf, -scores)
+        pad_key = jnp.concatenate([key, jnp.full(1, jnp.inf, key.dtype)])
+        per_query = []
+        for idx, gain, scale in buckets:
+            L = idx.shape[1]
+            _, g = lax.sort((pad_key[idx], gain), dimension=1,
+                            is_stable=True, num_keys=1)
+            top = min(max(ks), L)
+            terms = g[:, :top] * disc[:top]
+            dcg = jnp.stack([jnp.sum(terms[:, :min(k, L)], axis=1)
+                             for k in ks], axis=1)
+            per_query.append(dcg * scale)
+        total = _pairwise_sum(jnp.concatenate(per_query, axis=0))
+        return total * inv_sum_qw + ones
+
 
 @register_metric
 class MapMetric(_RankMetricBase):
@@ -119,3 +217,12 @@ class PrecisionMetric(_RankMetricBase):
             out.append(num_hit / denom if denom > 0 else 0.0)
             cur_left = k
         return out
+
+
+# graftir IR contract
+from ..analysis.ir.contracts import register_program  # noqa: E402
+
+register_program(
+    "rank._ndcg_at", collective_free=True,
+    notes="NDCG@k on device-resident scores: one stable sort a bucket, "
+          "float32[len(eval_at)] out")

@@ -23,9 +23,10 @@ from ..config import Config
 from ..data.dataset import BinnedDataset
 from ..metrics.base import Metric, create_metrics
 from ..objectives.base import ObjectiveFunction, create_objective
-from ..ops.predict import (_round_depth, build_forest_blocks,
+from ..ops.predict import (RoutingTree, _round_depth, build_forest_blocks,
                            forest_to_arrays, predict_forest,
                            predict_forest_leaf, predict_tree_binned,
+                           route_tree_binned, routing_tree_from_host,
                            tree_to_arrays)
 from ..ops.predict_tensor import (build_tree_tiles, predict_forest_leaf_tensor,
                                   predict_forest_tensor)
@@ -73,6 +74,21 @@ def _score_update(scores, leaf_values, row_leaf, k: int):
     stack, and a profiler trace then shows an anonymous jit_gather)."""
     with device_scope("score_update"):
         return scores.at[k].add(leaf_values[row_leaf])
+
+
+@functools.partial(jax.jit, static_argnames=("k", "has_categorical"))
+def _valid_tree_score(scores, x_binned, tree: RoutingTree, leaf_values,
+                      default_bins, missing_types, num_bins, k: int,
+                      has_categorical: bool):
+    """scores[k] += the tree's value of every row of a watched set: ONE
+    program a set's shape, whose compile key holds no property of the tree
+    (``ops.predict.route_tree_binned``), fed the device-resident record the
+    iteration already holds. A program of its own name: ``grad_device_ms``
+    and ``tree_device_ms`` select theirs by name."""
+    with device_scope("valid_score"):
+        return scores.at[k].add(route_tree_binned(
+            x_binned, tree, leaf_values, default_bins, missing_types,
+            num_bins, has_categorical))
 
 
 @functools.partial(jax.jit, static_argnames=("num_leaves",))
@@ -581,6 +597,8 @@ class GBDT:
                 # host constants of the ranking objectives' bucketing: no
                 # device array rides along, so nothing is read
                 tel.defer_counts((), lambda _: self.objective.work_counts)
+        if self.valid_sets:
+            tel.defer_counts((), lambda _: self._valid_counts())
         grad, hess = self.guard.admit_gradients(self, grad, hess)
 
         with tel.phase("sampling"):
@@ -621,10 +639,13 @@ class GBDT:
                                  init_scores[k])
                 self.models.append(lazy)
                 if self.valid_sets:
-                    tree = self._tree(len(self.models) - 1)
+                    # the watched sets take the same ``lv`` from the same
+                    # device record: nothing is materialised, nothing read
                     with tel.phase("eval"):
+                        routed = RoutingTree(*(getattr(rec, f) for f
+                                               in RoutingTree._fields))
                         for vi in range(len(self.valid_sets)):
-                            self._add_valid_tree_score(vi, tree, k)
+                            self._route_valid(vi, routed, lv, k)
             self.iter_ += 1
             tel.end_iteration(sync=self.scores)
             self.last_iteration_skipped = self.guard.end_iteration(self)
@@ -824,10 +845,45 @@ class GBDT:
             jnp.asarray(self.learner.last_leaf_count, dtype=jnp.int32),
             lv, tree.num_leaves))
 
+    def _valid_counts(self) -> Dict[str, int]:
+        """Host constants of the watched sets for the iteration record's
+        ``counts``: sets, rows, and the queries and padded documents of
+        the metrics' device buckets."""
+        counts = {"valid_sets": len(self.valid_sets),
+                  "valid_rows": sum(ds.num_data for _, ds in self.valid_sets)}
+        for metrics in self.valid_metrics:
+            for m in metrics:
+                for name, v in m.work_counts.items():
+                    counts[name] = counts.get(name, 0) + v
+        return counts
+
+    def _route_valid(self, vi: int, routed: RoutingTree, leaf_values,
+                     k: int) -> None:
+        """valid_scores[vi][k] += the tree's value of the set's rows."""
+        if getattr(self, "_route_meta", None) is None:
+            self._route_meta = (
+                tuple(jnp.asarray(self._meta[name]) for name in
+                      ("default_bins", "missing_types", "num_bins")),
+                bool(self._meta["is_categorical"].any()))
+        meta, has_categorical = self._route_meta
+        self.valid_scores[vi] = _valid_tree_score(
+            self.valid_scores[vi], self.valid_binned[vi], routed,
+            leaf_values, *meta, k=k, has_categorical=has_categorical)
+
+    def _route_host_tree(self, tree: Tree, sign: float = 1.0):
+        """A host Tree as ``_valid_tree_score`` takes it: the routing fields
+        and the float32 leaf values, padded to the configuration's
+        ``num_leaves`` so that no tree's depth or size is a compile key."""
+        routed = routing_tree_from_host(tree, self.config.num_leaves)
+        lv = np.zeros(routed.node_left.shape[0] + 1, np.float32)
+        n = max(tree.num_leaves, 1)
+        lv[:n] = sign * np.asarray(tree.leaf_value[:n], np.float32)
+        return routed, lv
+
     def _add_valid_tree_score(self, vi: int, tree: Tree, k: int) -> None:
-        x = self.valid_binned[vi]
-        arrs = tree_to_arrays(tree, feature_meta=self._meta, use_inner_feature=True)
-        depth = _round_depth(tree.max_depth + 1)
+        """A host Tree's values added to a watched set's scores (the
+        branches that grow host trees: DART, RF, ``linear_tree``, renewing
+        objectives, the host-loop learners)."""
         if getattr(tree, "is_linear", False):
             from ..ops.predict import predict_leaf_index_binned
             from .tree import linear_leaf_outputs
@@ -837,18 +893,21 @@ class GBDT:
                             "linear-tree eval falls back to constant leaf "
                             "values (metrics will not match predict())",
                             self.valid_sets[vi][0])
-            if vraw is not None:
+            else:
+                arrs = tree_to_arrays(tree, feature_meta=self._meta,
+                                      use_inner_feature=True)
                 # graftlint: disable=R1 — linear-tree valid-set eval must
                 # gather raw feature rows per leaf on the host; one
                 # transfer per tree per valid set, opt-in linear_tree path
                 leaf_idx = np.asarray(jax.device_get(
-                    predict_leaf_index_binned(x, arrs, depth)))
+                    predict_leaf_index_binned(
+                        self.valid_binned[vi], arrs,
+                        _round_depth(tree.max_depth + 1))))
                 add = linear_leaf_outputs(tree, vraw, leaf_idx)
                 self.valid_scores[vi] = self.valid_scores[vi].at[k].add(
                     jnp.asarray(add.astype(np.float32)))
                 return
-        add = predict_tree_binned(x, arrs, depth)
-        self.valid_scores[vi] = self.valid_scores[vi].at[k].add(add)
+        self._route_valid(vi, *self._route_host_tree(tree), k)
 
     def _renew_tree_output(self, tree: Tree, k: int, mask) -> None:
         """L1-family leaf refit by weighted percentile of residuals
@@ -1038,22 +1097,50 @@ class GBDT:
         return out[0] if self.num_tree_per_iteration == 1 else out
 
     def eval_train(self) -> List[Tuple[str, str, float, bool]]:
-        return self._eval("training", self.train_metrics,
-                          self._converted_scores(self.scores))
+        return self._eval_sets([("training", self.train_metrics,
+                                 self.scores)])
 
     def eval_valid(self) -> List[Tuple[str, str, float, bool]]:
-        out = []
-        for vi, (name, _) in enumerate(self.valid_sets):
-            out.extend(self._eval(name, self.valid_metrics[vi],
-                                  self._converted_scores(self.valid_scores[vi])))
-        return out
+        return self._eval_sets([
+            (name, self.valid_metrics[vi], self.valid_scores[vi])
+            for vi, (name, _) in enumerate(self.valid_sets)])
 
-    @staticmethod
-    def _eval(data_name, metrics, converted) -> List[Tuple[str, str, float, bool]]:
+    def _eval_sets(self, sets) -> List[Tuple[str, str, float, bool]]:
+        """Every metric of every ``(name, metrics, raw device scores)``. A
+        metric with a device form (``Metric.eval_device``) is dispatched
+        where the scores live, and all such values of the call come back in
+        ONE read of 4 bytes each; a set is read back whole only for a
+        metric without one. The bytes read go on the iteration record as
+        ``counts.eval_d2h_bytes``."""
+        slots, pending, d2h = [], [], 0
+        for name, metrics, raw in sets:
+            if not metrics:
+                continue
+            host = None
+            on_device = (self.objective.convert_output(raw)
+                         if self.objective else raw)
+            for m in metrics:
+                dev = m.eval_device(on_device)
+                if dev is not None:
+                    names, values = dev
+                    pending.append(values)
+                    slots.append((name, m, names, len(pending) - 1))
+                    continue
+                if host is None:
+                    host = self._converted_scores(raw)
+                    d2h += raw.size * raw.dtype.itemsize
+                slots.append((name, m, m.eval(host), None))
+        if not slots:
+            return []
+        # the evaluation's one read: the metric values, 4 bytes each
+        values = jax.device_get(pending)
+        d2h += sum(v.nbytes for v in values)
+        self.telemetry.add_counts({"eval_d2h_bytes": d2h})
         res = []
-        for m in metrics:
-            for mname, val in m.eval(converted):
-                res.append((data_name, mname, val, m.greater_is_better))
+        for name, m, got, at in slots:
+            pairs = got if at is None else zip(got, values[at].tolist())
+            for mname, val in pairs:
+                res.append((name, mname, float(val), m.greater_is_better))
         return res
 
     # ------------------------------------------------------------------
@@ -1441,8 +1528,7 @@ class GBDT:
             self.scores = self.scores.at[k].add(
                 predict_tree_binned(self.learner.x_binned, arrs, depth))
             for vi in range(len(self.valid_sets)):
-                self.valid_scores[vi] = self.valid_scores[vi].at[k].add(
-                    predict_tree_binned(self.valid_binned[vi], arrs, depth))
+                self._route_valid(vi, *self._route_host_tree(tree, -1.0), k)
         del self.models[-self.num_tree_per_iteration:]
         self.iter_ -= 1
 
@@ -1453,6 +1539,11 @@ from ..analysis.ir.contracts import register_program
 register_program(
     "gbdt._add_tree_score", collective_free=True,
     notes="score accumulation after each tree; device-resident add")
+register_program(
+    "gbdt._valid_tree_score", collective_free=True,
+    notes="a watched set's rows routed through one tree from its device "
+          "record: two matrix products a row block, no property of the "
+          "tree in the compile key")
 register_program(
     "gbdt._score_update", collective_free=True,
     notes="zero-sync path's score update: leaf values gathered by the "

@@ -83,6 +83,15 @@ DEVICE_SCOPES = ("histogram", "partition", "partition_decide",
 # ``gradients`` and at most one of these (tests/test_scopes.py).
 GRADIENT_SCOPES = ("rank_gather", "rank_sort", "rank_lattice", "rank_scatter")
 
+# scopes of the evaluation programs, which run only with a validation set
+# (or a training metric) attached: ``valid_score`` is the routing of a set's
+# binned rows through the iteration's tree and the add into its scores
+# (``models.gbdt._valid_tree_score``), ``valid_metric`` a metric computed on
+# device-resident scores (``metrics.rank._ndcg_at``). A tuple of their own:
+# DEVICE_SCOPES tiles the training programs and a benchmark file holds a
+# copy of it. Every op of the two programs carries exactly one of these.
+EVAL_SCOPES = ("valid_score", "valid_metric")
+
 # names on the profiler's clock: one ITER_ANNOTATION per boosting iteration
 # (begin_iteration .. end_iteration, stat ``iter``) and one
 # PHASE_ANNOTATION + <phase> per span, device_wait included
@@ -91,12 +100,12 @@ PHASE_ANNOTATION = "lg_phase:"
 
 
 def device_scope(name: str):
-    """``jax.named_scope(name)`` for a name of DEVICE_SCOPES or
-    GRADIENT_SCOPES (and only those: the vocabulary is closed, a typo
-    fails at trace time)."""
-    if name not in DEVICE_SCOPES and name not in GRADIENT_SCOPES:
-        raise ValueError(f"{name!r} is not in obs.telemetry.DEVICE_SCOPES "
-                         "or GRADIENT_SCOPES")
+    """``jax.named_scope(name)`` for a name of DEVICE_SCOPES,
+    GRADIENT_SCOPES or EVAL_SCOPES (and only those: the vocabulary is
+    closed, a typo fails at trace time)."""
+    if name not in DEVICE_SCOPES + GRADIENT_SCOPES + EVAL_SCOPES:
+        raise ValueError(f"{name!r} is not in obs.telemetry.DEVICE_SCOPES, "
+                         "GRADIENT_SCOPES or EVAL_SCOPES")
     import jax
     return jax.named_scope(name)
 
@@ -245,6 +254,17 @@ class TrainTelemetry:
             return
         self._deferred.append((arrays, reducer))
 
+    def add_counts(self, counts: Dict[str, int]) -> None:
+        """Host-known counts summed into the open record's ``counts`` at
+        once: for what happens after :meth:`end_iteration` has read the
+        deferred ones (the engine's ``eval`` phase)."""
+        if not self.enabled or self._cur is None:
+            return
+        rec = self._cur.setdefault("counts", {})
+        for k, v in counts.items():
+            rec[k] = rec.get(k, 0) + int(v)
+            self.work_totals[k] = self.work_totals.get(k, 0) + int(v)
+
     def begin_iteration(self, iteration: int) -> None:
         """Open the record for ``iteration`` (finalizing the previous
         one). Called at the top of ``GBDT.train_one_iter``."""
@@ -326,9 +346,7 @@ class TrainTelemetry:
                 jax.device_get([arrays for arrays, _ in pending]), pending):
             for k, v in reducer(host).items():
                 counts[k] = counts.get(k, 0) + int(v)
-        self._cur["counts"] = counts
-        for k, v in counts.items():
-            self.work_totals[k] = self.work_totals.get(k, 0) + v
+        self.add_counts(counts)
 
     def _add_phase(self, name: str, exclusive: float, inclusive: float,
                    legacy: Optional[str]) -> None:

@@ -23,6 +23,19 @@ from jax import lax
 from .split import MT_NAN, MT_ZERO
 
 
+def numerical_go_left(b: jax.Array, threshold: jax.Array,
+                      default_left: jax.Array, default_bin: jax.Array,
+                      missing_type: jax.Array, num_bin: jax.Array
+                      ) -> jax.Array:
+    """The numerical half of :func:`decision_go_left` on int32 bins: left
+    iff ``bin <= threshold``, rows in the missing bin follow
+    ``default_left``. Every argument broadcasts."""
+    is_missing = jnp.where(
+        missing_type == MT_ZERO, b == default_bin,
+        jnp.where(missing_type == MT_NAN, b == num_bin - 1, False))
+    return jnp.where(is_missing, default_left, b <= threshold)
+
+
 def decision_go_left(bin_vals: jax.Array, threshold: jax.Array,
                      default_left: jax.Array, default_bin: jax.Array,
                      missing_type: jax.Array, num_bin: jax.Array,
@@ -35,14 +48,36 @@ def decision_go_left(bin_vals: jax.Array, threshold: jax.Array,
     ``default_left``; categorical goes left iff its bin is in the bitset.
     """
     b = bin_vals.astype(jnp.int32)
-    is_missing = jnp.where(
-        missing_type == MT_ZERO, b == default_bin,
-        jnp.where(missing_type == MT_NAN, b == num_bin - 1, False))
-    num_left = jnp.where(is_missing, default_left, b <= threshold)
+    num_left = numerical_go_left(b, threshold, default_left, default_bin,
+                                 missing_type, num_bin)
     word = jnp.clip(b // 32, 0, cat_bitset.shape[0] - 1)
     bit = jnp.right_shift(cat_bitset[word], (b % 32).astype(jnp.uint32)) & 1
     cat_left = bit == 1
     return jnp.where(is_categorical, cat_left, num_left)
+
+
+def decisions_by_node(bin_vals: jax.Array, threshold: jax.Array,
+                      default_left: jax.Array, default_bin: jax.Array,
+                      missing_type: jax.Array, num_bin: jax.Array,
+                      is_categorical: jax.Array, cat_bits: jax.Array,
+                      has_categorical: bool) -> jax.Array:
+    """:func:`decision_go_left` for every (row, node) pair at once:
+    ``bin_vals`` is ``[R, M]`` (row r's bin of node m's feature), the other
+    arguments are per node (``[M]``; ``cat_bits`` ``[M, 8]``). The bitset
+    word is picked by eight selects, not by a gather: a gather by a
+    ``[R, M]`` index is the one thing here a TPU is slow at."""
+    b = bin_vals.astype(jnp.int32)
+    num_left = numerical_go_left(b, threshold, default_left, default_bin,
+                                 missing_type, num_bin)
+    if not has_categorical:
+        return num_left
+    words = cat_bits.shape[1]
+    word = jnp.clip(b // 32, 0, words - 1)
+    picked = jnp.broadcast_to(cat_bits[:, 0], b.shape)
+    for j in range(1, words):
+        picked = jnp.where(word == j, cat_bits[:, j], picked)
+    bit = jnp.right_shift(picked, (b % 32).astype(jnp.uint32)) & 1
+    return jnp.where(is_categorical, bit == 1, num_left)
 
 
 def _per_lane(mask: jax.Array, like: jax.Array) -> jax.Array:

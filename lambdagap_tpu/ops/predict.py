@@ -242,6 +242,134 @@ def _traverse_leaf_id(x: jax.Array, t: TreeArrays, max_depth: int,
     return jax.vmap(traverse)(x)
 
 
+class RoutingTree(NamedTuple):
+    """What routes a binned row through ONE tree, under the names and in the
+    shapes the fused learner's ``DeviceTree`` keeps them (M = num_leaves - 1
+    node slots of the configuration, of which the first ``num_leaves - 1``
+    are live): nothing here depends on the tree that was grown."""
+    node_feature: jax.Array       # i32 [M] inner feature index
+    node_threshold: jax.Array     # i32 [M] bin threshold
+    node_default_left: jax.Array  # bool [M]
+    node_is_cat: jax.Array        # bool [M]
+    node_cat_bits: jax.Array      # u32 [M, 8] bin-space bitset
+    node_left: jax.Array          # i32 [M] (>=0 node, <0 ~leaf)
+    node_right: jax.Array         # i32 [M]
+    num_leaves: jax.Array         # i32 scalar
+
+
+def routing_tree_from_host(tree, num_leaves: int) -> RoutingTree:
+    """A host Tree as a :class:`RoutingTree` padded to ``num_leaves`` (the
+    configuration's, or the tree's own when it is larger)."""
+    M = max(max(num_leaves, tree.num_leaves) - 1, 1)
+    n = tree.num_internal
+
+    def col(vals, dtype):
+        a = np.zeros(M, dtype)
+        a[:n] = np.asarray(vals[:n], dtype)
+        return a
+    bits = np.zeros((M, 8), np.uint32)
+    for i in range(n):
+        bb = np.asarray(tree.cat_bitset[i], np.uint32)[:8]
+        bits[i, :len(bb)] = bb
+    return RoutingTree(
+        node_feature=col(tree.split_feature_inner, np.int32),
+        node_threshold=col(tree.threshold_bin, np.int32),
+        node_default_left=col(tree.default_left, bool),
+        node_is_cat=col(tree.is_categorical, bool),
+        node_cat_bits=bits,
+        node_left=col(tree.left_child, np.int32),
+        node_right=col(tree.right_child, np.int32),
+        num_leaves=np.int32(max(tree.num_leaves, 1)))
+
+
+#: rows routed at a time by :func:`route_tree_binned`: bounds its
+#: ``[rows, nodes]`` temporaries whatever the set's size
+ROUTE_BLOCK = 1 << 15
+
+
+def _leaf_paths(t: RoutingTree, L: int) -> Tuple[jax.Array, jax.Array]:
+    """The tree's paths as a matrix: ``turn[l, m]`` is +1 where the way to
+    leaf ``l`` goes LEFT at node ``m``, -1 where it goes right, 0 where it
+    does not pass ``m``; ``lefts[l]`` counts the +1s. A row sits in leaf
+    ``l`` iff its go-left decisions ``d[m]`` in {0, 1} give
+    ``sum_m d[m] * turn[l, m] == lefts[l]`` (every left turn taken, no right
+    turn refused). Built from the child pointers alone by ~log2(M) products
+    of M x M matrices of 0 and 1: a fixed shape for any tree, any depth."""
+    M = t.node_left.shape[0]
+    f32 = jnp.float32
+    live = jnp.arange(M, dtype=jnp.int32) < t.num_leaves - 1
+    node = jnp.arange(M, dtype=jnp.int32)[:, None]
+    leaf = ~jnp.arange(L, dtype=jnp.int32)[:, None]
+    # [child, parent]: the child is the parent's left (right) node / leaf
+    nl = ((t.node_left[None, :] == node) & live[None, :]).astype(f32)
+    nr = ((t.node_right[None, :] == node) & live[None, :]).astype(f32)
+    ll = ((t.node_left[None, :] == leaf) & live[None, :]).astype(f32)
+    lr = ((t.node_right[None, :] == leaf) & live[None, :]).astype(f32)
+    # anc[m, a]: a is m or above it -- the closure of "parent of", doubled
+    anc = jnp.minimum(jnp.eye(M, dtype=f32) + nl + nr, 1.0)
+    for _ in range(max(M - 1, 1).bit_length()):     # 2^k >= the longest path
+        anc = jnp.minimum(anc @ anc, 1.0)
+    par = ll + lr                                  # [L, M]: the leaf's parent
+    left = ll + par @ (anc @ nl)
+    right = lr + par @ (anc @ nr)
+    return left - right, jnp.sum(left, axis=1)
+
+
+def route_tree_binned(x_binned: jax.Array, t: RoutingTree,
+                      leaf_value: jax.Array, default_bins: jax.Array,
+                      missing_types: jax.Array, num_bins: jax.Array,
+                      has_categorical: bool) -> jax.Array:
+    """One tree's value of every binned row ``[N, F] -> [N]``, with no
+    traversal: every row's decision at EVERY node (the statement training
+    rows are routed by, ``ops.partition``), then the leaf whose path the
+    decisions satisfy (:func:`_leaf_paths`). Two matrix products a block of
+    rows -- the nodes' feature columns picked out of the row, the decisions
+    against the paths -- and no gather by row; the shape holds no property
+    of the tree, so one compile serves every tree of a run. Bit-equal to
+    :func:`predict_tree_binned` on the materialised tree: the sums are of
+    small whole numbers, the value is selected, not multiplied."""
+    from .partition import decisions_by_node
+    N, F = x_binned.shape
+    L = leaf_value.shape[0]
+    turn, lefts = _leaf_paths(t, L)
+    # bins up to 256 are whole numbers a bfloat16 holds; wider bins take
+    # the float32 product at full precision
+    narrow = x_binned.dtype.itemsize == 1
+    sel_t = jnp.bfloat16 if narrow else jnp.float32
+    prec = None if narrow else lax.Precision.HIGHEST
+    feat = jnp.clip(t.node_feature, 0, F - 1)
+    pick = (feat[None, :] == jnp.arange(F, dtype=jnp.int32)[:, None]
+            ).astype(sel_t)                                       # [F, M]
+    args = (t.node_threshold, t.node_default_left, default_bins[feat],
+            missing_types[feat], num_bins[feat], t.node_is_cat,
+            t.node_cat_bits)
+    live_leaf = jnp.arange(L, dtype=jnp.int32) < jnp.maximum(t.num_leaves, 1)
+    turn_t = turn.T.astype(jnp.bfloat16)                          # [M, L]
+    blk = min(ROUTE_BLOCK, N)
+    assert 0 < blk <= N
+
+    def block(xb):
+        bins = jnp.dot(xb.astype(sel_t), pick, precision=prec,
+                       preferred_element_type=jnp.float32)
+        go = decisions_by_node(bins, *args, has_categorical)
+        met = jnp.dot(go.astype(jnp.bfloat16), turn_t,
+                      preferred_element_type=jnp.float32)         # [blk, L]
+        here = (met == lefts[None, :]) & live_leaf[None, :]
+        return jnp.sum(jnp.where(here, leaf_value[None, :], 0.0), axis=1)
+
+    if blk == N:
+        return block(x_binned)
+
+    def body(b, out):
+        # the last block is moved back to end at N: its overlap with the
+        # block before is computed twice and written twice, alike
+        lo = jnp.minimum(b * blk, N - blk)
+        xb = lax.dynamic_slice(x_binned, (lo, 0), (blk, F))
+        return lax.dynamic_update_slice(out, block(xb), (lo,))
+    return lax.fori_loop(0, -(-N // blk), body,
+                         jnp.zeros(N, jnp.float32))
+
+
 @functools.partial(jax.jit, static_argnames=("max_depth",))
 def predict_tree_raw(x: jax.Array, t: TreeArrays, max_depth: int) -> jax.Array:
     """Predict one tree on raw float features [N, D] -> [N] leaf values."""

@@ -88,3 +88,81 @@ def test_no_gain_lookup_per_pair_cell_survives_the_tpu_compiler(one_chip, L):
     # what is left: the sort's own gathers and the per-document lookup (the
     # one-query bucket's XLA turns into selects by itself), nq x L each
     assert gathers and max(gathers) <= nq * L < L * L, gathers
+
+
+def _unnamed(text, scope, least):
+    """Instructions of the compiled program's own computations (not of a
+    fusion's body: a trace shows the fusion) that produce ``least`` elements
+    or more and do not carry ``scope`` in their name."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    bad, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.search(r"= \(?\w+\[([\d,]+)\]", line)
+        if comp in fused or not m or FREE.search(line) \
+                or re.search(r" (copy-start|copy-done|slice-start|"
+                             r"slice-done|while|iota)\(|ConcatBitcast",
+                             line):
+            # (the compiler's own prefetches between memory spaces, the
+            # loop itself: nothing a selector would attribute)
+            continue
+        if math.prod(int(d) for d in m.group(1).split(",")) >= least \
+                and f"/{scope}" not in line:
+            bad.append(line.strip()[:140])
+    return bad
+
+
+def test_the_evaluation_programs_compile_at_the_watched_folds_shapes(one_chip):
+    """``istella-s-valid``'s validation fold (7,211 queries of the
+    configuration's fixed multiset of lengths, ~744k documents x 220 uint8
+    bins, 255 leaves): the routing program and the NDCG@10 program compile
+    for a v5e, every instruction of either that touches a fold-sized array
+    carries the scope its metric selects by, the routing holds no gather
+    or sort and streams (temporaries of a few row blocks), the metric
+    sorts once a bucket."""
+    from benchmark import manifest
+    from benchmark.datagen.mslr_like import query_lengths
+    from lambdagap_tpu.metrics.rank import _ndcg_at
+    from lambdagap_tpu.models.gbdt import _valid_tree_score
+    from lambdagap_tpu.ops.predict import ROUTE_BLOCK, RoutingTree
+    cfg = manifest.load_json("configs", "istella-s-valid.json")
+    sizes = query_lengths(cfg["num_queries"], cfg["num_docs"],
+                          cfg["assumed"]["query_length_median"],
+                          cfg["assumed"]["query_length_longest"])
+    fold = sizes[np.random.default_rng(0).permutation(len(sizes))[
+        :cfg["folds"]["valid"]]]
+    n, f = int(fold.sum()), int(cfg["num_features"])
+    leaves = int(cfg["params"]["num_leaves"])
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    m = leaves - 1
+    tree = RoutingTree(
+        arg((m,), jnp.int32), arg((m,), jnp.int32), arg((m,), jnp.bool_),
+        arg((m,), jnp.bool_), arg((m, 8), jnp.uint32), arg((m,), jnp.int32),
+        arg((m,), jnp.int32), arg((), jnp.int32))
+    routed = _valid_tree_score.lower(
+        arg((1, n)), arg((n, f), jnp.uint8), tree, arg((leaves,)),
+        arg((f,), jnp.int32), arg((f,), jnp.int32), arg((f,), jnp.int32),
+        k=0, has_categorical=False).compile()
+    text = routed.as_text()
+    assert "jit__valid_tree_score" in text[:200]
+    assert _unnamed(text, "valid_score", ROUTE_BLOCK) == []
+    assert not re.search(r" (gather|sort|scatter)\(.*\[\d{5,}", text)
+    assert routed.memory_analysis().temp_size_in_bytes \
+        <= 8 * 4 * ROUTE_BLOCK * leaves
+
+    padded = np.maximum(8, 2 ** np.ceil(np.log2(fold)).astype(np.int64))
+    buckets = tuple(
+        (arg((nq, L), jnp.int32), arg((nq, L)), arg((nq, 1)))
+        for L, nq in zip(*np.unique(padded, return_counts=True)))
+    metric = _ndcg_at.lower(arg((n,)), buckets, arg((10,)), arg((1,)),
+                            arg(()), ks=(10,)).compile()
+    text = metric.as_text()
+    assert "jit__ndcg_at" in text[:200]
+    assert _unnamed(text, "valid_metric", 1 << 17) == []
+    assert len(re.findall(r" sort\(", text)) == len(buckets)

@@ -12,8 +12,8 @@ import numpy as np
 import pytest
 
 import lambdagap_tpu as lgb
-from lambdagap_tpu.obs.telemetry import (DEVICE_SCOPES, GRADIENT_SCOPES,
-                                         device_scope)
+from lambdagap_tpu.obs.telemetry import (DEVICE_SCOPES, EVAL_SCOPES,
+                                         GRADIENT_SCOPES, device_scope)
 from lambdagap_tpu.ops.hist_pallas import KERNEL_NAME
 
 # ops that move values around and compute nothing: they may sit outside
@@ -32,8 +32,8 @@ def _loc_name(op) -> str:
     return m.group(1) if m else ""
 
 
-def _leaf_scope(name: str):
-    found = [c for c in name.split("/") if c in DEVICE_SCOPES]
+def _leaf_scope(name: str, vocabulary=DEVICE_SCOPES):
+    found = [c for c in name.split("/") if c in vocabulary]
     return found[-1] if found else None
 
 
@@ -62,11 +62,11 @@ def _ops_and_calls(lowered):
     return ops, calls
 
 
-def scope_census(lowered):
-    """(ops per innermost scope, [(function, op, location name)] of the ops
-    under none) of a lowered program. An op of a private function (one
-    ``cumsum`` or ``where`` body shared by its callers) counts as scoped
-    when every call of that function is."""
+def scope_census(lowered, vocabulary=DEVICE_SCOPES):
+    """(ops per innermost scope of ``vocabulary``, [(function, op, location
+    name)] of the ops under none) of a lowered program. An op of a private
+    function (one ``cumsum`` or ``where`` body shared by its callers) counts
+    as scoped when every call of that function is."""
     ops, calls = _ops_and_calls(lowered)
     memo = {}
 
@@ -75,14 +75,14 @@ def scope_census(lowered):
             return False
         if fn not in memo:
             memo[fn] = False
-            memo[fn] = all(_leaf_scope(name) is not None
+            memo[fn] = all(_leaf_scope(name, vocabulary) is not None
                            or by_call_site(caller)
                            for caller, name in calls[fn])
         return memo[fn]
 
     scoped, outside = collections.Counter(), []
     for fn, kind, name, _ in ops:
-        scope = _leaf_scope(name)
+        scope = _leaf_scope(name, vocabulary)
         if scope is not None:
             scoped[scope] += 1
         elif kind not in PLUMBING and not by_call_site(fn) \
@@ -251,6 +251,80 @@ def test_gradient_and_score_programs_are_scoped(program):
         want = "gradients"
     scoped, outside = scope_census(lowered)
     assert outside == [] and set(scoped) == {want}
+
+
+def _lower_eval_programs(categorical: bool):
+    """``_valid_tree_score`` and ``_ndcg_at`` lowered at a watched fold's
+    shapes: a ranking booster with one validation set, one iteration in."""
+    from lambdagap_tpu.metrics.rank import _ndcg_at
+    from lambdagap_tpu.models.gbdt import _valid_tree_score
+    from lambdagap_tpu.ops.predict import RoutingTree
+    rng = np.random.default_rng(3)
+
+    def fold(sizes):
+        n = int(np.sum(sizes))
+        X = rng.normal(size=(n, 4)).astype(np.float32)
+        X[:, 2] = rng.integers(0, 5, n)
+        return X, rng.integers(0, 4, n).astype(np.float32), sizes
+    params = {"objective": "lambdarank", "verbose": -1, "num_leaves": 8,
+              "min_data_in_leaf": 2, "tpu_fused_learner": 1,
+              "eval_at": [1, 10],
+              "categorical_feature": [2] if categorical else []}
+    X, y, g = fold([5, 12, 24, 40, 100] * 3)
+    ds = lgb.Dataset(X, label=y, group=g, params=params)
+    bst = lgb.Booster(params, ds)
+    Xv, yv, gv = fold([3, 9, 30, 70])
+    bst.add_valid(lgb.Dataset(Xv, label=yv, group=gv, reference=ds), "v")
+    bst.update()
+    gb = bst._booster
+    rec = gb.models[-1].rec
+    meta = [jnp.asarray(gb._meta[k]) for k in
+            ("default_bins", "missing_types", "num_bins")]
+    score = _valid_tree_score.lower(
+        gb.valid_scores[0], gb.valid_binned[0],
+        RoutingTree(*(getattr(rec, f) for f in RoutingTree._fields)),
+        rec.leaf_value, *meta, k=0, has_categorical=categorical)
+    ks, buckets, disc, ones, inv = gb.valid_metrics[0][0]._dev
+    metric = _ndcg_at.lower(gb.valid_scores[0][0], buckets, disc, ones, inv,
+                            ks=ks)
+    return {"valid_score": score, "valid_metric": metric}
+
+
+@pytest.mark.parametrize("categorical", [False, True],
+                         ids=["numerical", "categorical"])
+def test_every_op_of_the_evaluation_programs_is_under_one_eval_scope(
+        categorical):
+    """``EVAL_SCOPES`` tiles the two programs that run only with a watched
+    set attached, one name a program, under programs of their own names
+    (``grad_device_ms`` and ``tree_device_ms`` select theirs by name); the
+    routing reaches no row by a gather, the metric sorts once a bucket."""
+    lowered = _lower_eval_programs(categorical)
+    for want, program in lowered.items():
+        scoped, outside = scope_census(program, EVAL_SCOPES)
+        assert outside == [] and set(scoped) == {want}
+        assert not set(scope_census(program, DEVICE_SCOPES
+                                    + GRADIENT_SCOPES)[0])
+    assert "jit__valid_tree_score" in lowered["valid_score"].as_text()[:200]
+    assert "jit__ndcg_at" in lowered["valid_metric"].as_text()[:200]
+    kinds = op_kinds_under(lowered["valid_score"], "valid_score")
+    assert kinds["stablehlo.dot_general"] >= 2
+    assert kinds["stablehlo.sort"] == 0 and kinds["stablehlo.scatter"] <= 1
+    n = 3 + 9 + 30 + 70
+    rows = [k for k, sizes in ops_under(lowered["valid_score"],
+                                        "valid_score")
+            if k == "stablehlo.gather" and any(s >= n for s in sizes)]
+    assert rows == []
+    kinds = op_kinds_under(lowered["valid_metric"], "valid_metric")
+    assert kinds["stablehlo.sort"] == 4 == kinds["stablehlo.gather"]
+
+
+def test_the_evaluation_vocabulary_is_closed_and_apart():
+    assert EVAL_SCOPES == ("valid_score", "valid_metric")
+    assert not set(EVAL_SCOPES) & set(DEVICE_SCOPES + GRADIENT_SCOPES)
+    with device_scope("valid_score"), device_scope("valid_metric"):
+        pass
+    with pytest.raises(ValueError):
+        device_scope("valid_scores")
 
 
 def test_the_ranking_gradient_program_is_tiled_by_its_inner_scopes():
