@@ -336,13 +336,26 @@ class FusedTreeLearner(SerialTreeLearner):
         gather); gradients change every iteration, which is why the buffer
         cannot persist across trees. The W trailing pad rows let every
         window read in the fused program be a clamp-free dynamic slice
-        (the same invariant as the permutation buffer's)."""
+        (the same invariant as the permutation buffer's).
+
+        The payload is WORD-MAJOR, ``[SW, N + W]``: word w of the row at
+        position p is ``[w, p]``, so a window of W rows is a slice along
+        the minor axis. A TPU keeps a narrow 2-D array with its long axis
+        minor whatever its shape says, and the partition and histogram
+        loops of the tree program read it so; held as ``[N + W, SW]``, the
+        split loop's carry and the copy-back loop chose row-major and XLA
+        converted the WHOLE buffer between the two twice a split (1,940 of
+        ``istella-s-train``'s 3,990 device ms at 57 words; none at
+        ``higgs-train``'s 9; PERF.md section 6, PR 36). With the long axis
+        minor in the shape itself there is one layout to choose, and
+        ``tests/test_aot_v5e.py`` compiles the tree program for a v5e and
+        fails on any instruction of the split loop that produces the whole
+        payload."""
         with _scope("layout_apply"):
             packed = self._pack_rows(grad, hess, row_mask, x_rows, gq, hq,
                                      has_mask)
             W = self._window(x_rows.shape[0])
-            return jnp.concatenate(
-                [packed, jnp.zeros((W, packed.shape[1]), packed.dtype)])
+            return jnp.pad(packed.T, ((0, 0), (0, W)))
 
     def _pick_chunk(self) -> int:
         """Chunk window for the while-loop'd row passes: small enough that a
@@ -569,14 +582,15 @@ class FusedTreeLearner(SerialTreeLearner):
         pack32 = self.pack32
         if layout_sorted:
             packed_rows = None          # rows live in the carried srows
-            SW = srows.shape[1]
+            SW = srows.shape[0]         # word-major: [SW, N + W]
         else:
             with _scope("tree_init"):
                 packed_rows = self._pack_rows(grad, hess, row_mask, x_rows,
                                               gq, hq, has_mask)
 
         def unpack(prow):
-            """u32 lanes -> bin-dtype columns (no-op when pack32 is off)."""
+            """``[W, SW]`` u32 lanes -> bin-dtype columns (no-op when
+            pack32 is off)."""
             if pack32:
                 return lax.bitcast_convert_type(
                     prow, x_rows.dtype).reshape(prow.shape[0], -1)
@@ -584,10 +598,14 @@ class FusedTreeLearner(SerialTreeLearner):
 
         def srow_slice(buf, start):
             """Contiguous W-row window of the (N+W padded) sorted payload
-            — a dynamic-slice DMA, the sorted layout's whole point."""
+            — a dynamic-slice DMA, the sorted layout's whole point. The
+            payload is word-major (_build_sorted_impl), and so is the
+            window: ``[SW, W]``, rows on the minor axis. The partition
+            network moves it as it is; the two decoders transpose the
+            window (1.9 MB at 57 words, never the buffer)."""
             # same pad invariant as perm_slice: starts stay <= N
-            assert buf.shape[0] == N + W
-            return lax.dynamic_slice(buf, (start, 0), (W, SW))
+            assert buf.shape == (SW, N + W)
+            return lax.dynamic_slice(buf, (0, start), (SW, W))
 
         def perm_slice(perm, start):
             """Contiguous W-row window of the (N+W padded) permutation —
@@ -603,7 +621,7 @@ class FusedTreeLearner(SerialTreeLearner):
             gather layout, a contiguous window DMA under sorted."""
             if layout_sorted:
                 rows = None
-                prow = unpack(srow_slice(srows_c, begin + c * W))
+                prow = unpack(srow_slice(srows_c, begin + c * W).T)
             else:
                 rows = perm_slice(perm, begin + c * W)
                 prow = unpack(packed_rows[rows])    # [W, C(+gh+mask)]
@@ -1220,7 +1238,7 @@ class FusedTreeLearner(SerialTreeLearner):
                     rows = perm_slice(perm_in, begin + c * W)
                     if layout_sorted:
                         dw = srow_slice(srows_cur, begin + c * W)
-                        cv = jnp.take(unpack(dw), colidx,
+                        cv = jnp.take(unpack(dw.T), colidx,
                                       axis=1).astype(jnp.int32)
                     else:
                         cv = col[rows].astype(jnp.int32)
@@ -1267,7 +1285,10 @@ class FusedTreeLearner(SerialTreeLearner):
             # copy the partitioned slice back into perm (chunked); both reads
             # and the write are contiguous-window DMAs, with the stale tail
             # of the last window re-written from perm itself. The sorted
-            # payload copies back the same way — stream reads, stream write.
+            # payload copies back the same way — stream reads, stream write,
+            # ``[SW, W]`` windows along the word-major payload's minor axis,
+            # the layout every loop of the program holds it in
+            # (_build_sorted_impl).
             def cbody(s):
                 if layout_sorted:
                     c, pm, sr = s
@@ -1282,9 +1303,9 @@ class FusedTreeLearner(SerialTreeLearner):
                                  perm_slice(pm, start))
                 pm = lax.dynamic_update_slice(pm, vals, (start,))
                 if layout_sorted:
-                    sw = jnp.where(valid[:, None], srow_slice(sbuf, start),
+                    sw = jnp.where(valid, srow_slice(sbuf, start),
                                    srow_slice(sr, start))
-                    sr = lax.dynamic_update_slice(sr, sw, (start, 0))
+                    sr = lax.dynamic_update_slice(sr, sw, (0, start))
                     return c + 1, pm, sr
                 return c + 1, pm
 

@@ -80,15 +80,12 @@ def decisions_by_node(bin_vals: jax.Array, threshold: jax.Array,
     return jnp.where(is_categorical, bit == 1, num_left)
 
 
-def _per_lane(mask: jax.Array, like: jax.Array) -> jax.Array:
-    """A ``[W]`` lane mask shaped to select whole rows of ``like``."""
-    return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
-
-
 def compact(keep: jax.Array, *cols: jax.Array, back: bool = False):
     """Stable compaction of one window: the lanes where ``keep`` holds move
     to the front (``back``: to the back) in lane order, in every array of
-    ``cols`` (leading axis W, a power of two) alike. What the other lanes
+    ``cols`` alike. The lanes are an array's LAST axis (W, a power of two):
+    a window of row ids is ``[W]``, a window of the sorted payload
+    ``[SW, W]``, word-major like the payload itself. What the other lanes
     hold afterwards is unspecified.
 
     The compress network (Hacker's Delight 7-4): a kept lane has to move
@@ -108,17 +105,17 @@ def compact(keep: jax.Array, *cols: jax.Array, back: bool = False):
     goal = W - 1 - (kept[W - 1] - kept) if back else kept - 1
     disp = jnp.where(keep, jnp.abs(goal - lane), 0)
 
-    def shifted(x, s):       # front: x[i + s] comes to i; back: x[i - s]
-        pad = [(s, 0, 0)] if back else [(0, s, 0)]
-        return lax.pad(x[:W - s] if back else x[s:], jnp.zeros((), x.dtype),
-                       pad + [(0, 0, 0)] * (x.ndim - 1))
+    def shifted(x, s):       # front: lane i + s comes to i; back: lane i - s
+        pad = (s, 0, 0) if back else (0, s, 0)
+        return lax.pad(x[..., :W - s] if back else x[..., s:],
+                       jnp.zeros((), x.dtype),
+                       [(0, 0, 0)] * (x.ndim - 1) + [pad])
 
     s = 1
     while s < W:
         coming = shifted(disp, s)
         take = (coming & s) != 0
-        cols = tuple(jnp.where(_per_lane(take, c), shifted(c, s), c)
-                     for c in cols)
+        cols = tuple(jnp.where(take, shifted(c, s), c) for c in cols)
         disp = jnp.where(take, coming, jnp.where((disp & s) != 0, 0, disp))
         s *= 2
     return cols
@@ -126,23 +123,30 @@ def compact(keep: jax.Array, *cols: jax.Array, back: bool = False):
 
 def write_front(buf: jax.Array, vals: jax.Array, start: jax.Array,
                 n: jax.Array) -> jax.Array:
-    """``buf[start : start + n] = vals[:n]`` as one contiguous window: read W
-    rows at ``start``, keep what lies behind ``n``, write W rows back. The
-    caller keeps ``start + W`` inside ``buf`` (the training buffers carry a
-    W-row tail pad and no start passes their N-th row), so neither slice
+    """``buf[..., start : start + n] = vals[..., :n]`` as one contiguous
+    window along the last axis (positions: the permutation is ``[N + W]``,
+    the sorted payload ``[SW, N + W]``): read W positions at ``start``, keep
+    what lies behind ``n``, write W positions back. Nothing but that window
+    of ``buf`` is read or written: the payload keeps the one layout it has
+    through the tree program (``tests/test_aot_v5e.py`` compiles it for a
+    v5e and fails on a whole-payload copy in the split loop). The caller
+    keeps ``start + W`` inside ``buf`` (the training buffers carry a W-wide
+    tail pad and no start passes their N-th position), so neither slice
     clamps."""
-    W = vals.shape[0]
-    assert buf.shape[0] >= W and buf.shape[1:] == vals.shape[1:]
-    idx = (start,) + (0,) * (buf.ndim - 1)
+    W = vals.shape[-1]
+    assert buf.shape[-1] >= W and buf.shape[:-1] == vals.shape[:-1]
+    idx = (0,) * (buf.ndim - 1) + (start,)
     old = lax.dynamic_slice(buf, idx, vals.shape)
-    mask = _per_lane(jnp.arange(W, dtype=jnp.int32) < n, vals)
+    mask = jnp.arange(W, dtype=jnp.int32) < n
     return lax.dynamic_update_slice(buf, jnp.where(mask, vals, old), idx)
 
 
 def route_window(bufs, win, go_left: jax.Array, go_right: jax.Array,
                  lcur: jax.Array, rcur: jax.Array):
     """One trip of the chunked stable partition, for every array of the
-    window ``win`` into its buffer of ``bufs``: the lefts land at
+    window ``win`` into its buffer of ``bufs`` (lanes and positions on the
+    last axis of both: ``[W]`` row ids into ``[N + W]``, a ``[SW, W]``
+    payload window into the ``[SW, N + W]`` payload): the lefts land at
     ``[lcur, lcur + nl)`` in lane order, the rights at ``[rcur - nr, rcur)``
     in REVERSED lane order (the rights of a leaf fill backward from its
     end). Each side is one contiguous run, so it is compacted in the window
@@ -157,7 +161,8 @@ def route_window(bufs, win, go_left: jax.Array, go_right: jax.Array,
     lefts = compact(go_left, *win)
     rights = compact(go_right, *win, back=True)
     bufs = tuple(
-        write_front(write_front(buf, lw, lcur, nl), rw[::-1], rcur - nr, nr)
+        write_front(write_front(buf, lw, lcur, nl), rw[..., ::-1],
+                    rcur - nr, nr)
         for buf, lw, rw in zip(bufs, lefts, rights))
     return bufs, nl, nr
 

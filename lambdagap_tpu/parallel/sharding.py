@@ -62,8 +62,9 @@ RULES: Tuple[Tuple[str, Tuple[Optional[str], ...]], ...] = (
     # fully replicated matrix (the host-loop feature learner keeps all
     # rows everywhere and block-slices columns by axis_index itself)
     (r"^x_replicated$", ()),
-    # sorted-leaf payload [rows + W, lanes]: lanes ride with their row
-    (r"^srows$|^sorted_(rows|payload)$", (DATA_AXIS, None)),
+    # sorted-leaf payload, word-major [lanes, rows + W]: a shard holds its
+    # rows' every lane
+    (r"^srows$|^sorted_(rows|payload)$", (None, DATA_AXIS)),
     # per-row training state: gh buffers, quantized gh levels, sample /
     # pad masks, permutations, scores, row->leaf maps
     (r"^(grad|hess|gq|hq)$|^(row_|real_)?mask$|^perm$|^score$|^row_leaf$",

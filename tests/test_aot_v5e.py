@@ -90,27 +90,37 @@ def test_no_gain_lookup_per_pair_cell_survives_the_tpu_compiler(one_chip, L):
     assert gathers and max(gathers) <= nq * L < L * L, gathers
 
 
-def _unnamed(text, scope, least):
-    """Instructions of the compiled program's own computations (not of a
-    fusion's body: a trace shows the fusion) that produce ``least`` elements
-    or more and do not carry ``scope`` in their name."""
+def _instructions(text):
+    """(is ENTRY, line) of every instruction of the compiled program's own
+    computations (not of a fusion's body: a trace shows the fusion)."""
     fused = set(re.findall(r"calls=%([\w.\-]+)", text))
-    bad, comp = [], None
+    comp = entry = None
     for line in text.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(", line)
         if head:
-            comp = head.group(1)
-            continue
+            entry, comp = bool(head.group(1)), head.group(2)
+        elif comp not in fused:
+            yield entry, line
+
+
+def _elements(dims: str) -> int:
+    return math.prod(int(d) for d in dims.split(","))
+
+
+def _unnamed(text, scope, least):
+    """Instructions that produce ``least`` elements or more and do not
+    carry ``scope`` in their name."""
+    bad = []
+    for _, line in _instructions(text):
         m = re.search(r"= \(?\w+\[([\d,]+)\]", line)
-        if comp in fused or not m or FREE.search(line) \
+        if not m or FREE.search(line) \
                 or re.search(r" (copy-start|copy-done|slice-start|"
                              r"slice-done|while|iota)\(|ConcatBitcast",
                              line):
             # (the compiler's own prefetches between memory spaces, the
             # loop itself: nothing a selector would attribute)
             continue
-        if math.prod(int(d) for d in m.group(1).split(",")) >= least \
-                and f"/{scope}" not in line:
+        if _elements(m.group(1)) >= least and f"/{scope}" not in line:
             bad.append(line.strip()[:140])
     return bad
 
@@ -166,3 +176,78 @@ def test_the_evaluation_programs_compile_at_the_watched_folds_shapes(one_chip):
     assert "jit__ndcg_at" in text[:200]
     assert _unnamed(text, "valid_metric", 1 << 17) == []
     assert len(re.findall(r" sort\(", text)) == len(buckets)
+
+
+# instructions that name a buffer and move none of it, or a window of it
+WINDOWED = {"dynamic-update-slice", "while", "parameter",
+            "get-tuple-element", "tuple", "bitcast"}
+
+
+def _payload_producers(text: str, elements: int):
+    """Instructions of the compiled program's loops (every computation but
+    ``ENTRY``) whose result, or any element of a tuple result, is an array
+    of ``elements`` elements or more, whatever the instruction: a copy, a
+    transpose, a fusion of one output or several, an asynchronous copy's
+    tuple. An in-place ``dynamic-update-slice`` names the whole buffer too
+    and writes a window of it, and a loop, its parameter and the tuples
+    around it carry it: ``WINDOWED`` are the only names let through."""
+    bad = []
+    for entry, line in _instructions(text):
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(",
+                     line)
+        if entry or not m or m.group(2) in WINDOWED:
+            continue
+        if any(_elements(dims) >= elements
+               for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            bad.append(line.strip()[:160])
+    return bad
+
+
+@pytest.mark.parametrize("features,n,window", [
+    (220, 3_408_630, 8_192), (28, 10_500_000, 32_768)],
+    ids=["istella-s-57-words", "higgs-9-words"])
+def test_the_split_loop_moves_the_sorted_payload_by_windows_alone(
+        one_chip, monkeypatch, features, n, window):
+    """The tree program at the two cells' shapes (255 leaves, 255 bins, the
+    Pallas kernel, ``tree_layout=sorted``; a learner built on 4,096 rows and
+    lowered at the cell's N and W): outside ``ENTRY`` no instruction
+    produces an array as large as the sorted payload. Held as ``[N + W, 57]`` the split
+    loop's carry and the copy-back loop were row-major, the partition and
+    histogram loops N-minor, and the compiler converted all 779 MB between
+    them twice a split (``%copy.135``, ``%copy.136``: 1,940 of
+    ``istella-s-train``'s 3,990 device ms, PR 36); at 9 words it never
+    did. The payload is word-major since (``_build_sorted_impl``)."""
+    import lambdagap_tpu as lgb
+    from lambdagap_tpu.ops import hist_pallas
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(hist_pallas, "_interpret", lambda: False)
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(4096, features)).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 255, "max_bin": 255,
+              "tree_layout": "sorted", "tpu_fused_learner": 1,
+              "tpu_hist_impl": "pallas", "enable_bundle": False,
+              "verbose": -1}
+    learner = lgb.Booster(params, lgb.Dataset(
+        X, label=(X[:, 0] > 0).astype(np.float32),
+        params=params))._booster.learner
+    assert (type(learner).__name__, learner.layout, learner.hist_impl) \
+        == ("FusedTreeLearner", "sorted", "pallas")
+    learner.chunk = window          # the cell's own W (_pick_chunk at its N)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x_rows, q = arg((n, features), jnp.uint8), arg((1,), jnp.int8)
+    srows = jax.eval_shape(
+        lambda *a: learner._build_sorted_impl(*a, has_mask=False),
+        arg((n,)), arg((n,)), arg((1,), jnp.bool_), x_rows, q, q)
+    words = -(-(features + 8) // 4)
+    assert sorted(srows.shape) == [words, n + window]
+    compiled = learner._train_jit.lower(
+        arg((n,)), arg((n,)), arg((1,), jnp.bool_),
+        arg((features,), jnp.bool_), x_rows, arg((1, 1), jnp.uint8),
+        arg(srows.shape, srows.dtype), q, q, arg(()), arg(()),
+        arg((2, 2), jnp.uint32), has_mask=False).compile()
+    text = compiled.as_text()
+    assert "jit__train_tree_impl" in text[:200] and "lg_hist" in text
+    assert _payload_producers(text, words * (n + window)) == []

@@ -166,7 +166,7 @@ def test_train_device_hands_train_jit_its_twelve_arguments():
         "grad": ((N,), "float32"), "hess": ((N,), "float32"),
         "row_mask": ((1,), "bool"), "fmask": ((F,), "bool"),
         "x_rows": ((N, F), "uint8"), "x_cols": ((1, 1), "uint8"),
-        "srows": ((N + W, lanes), "uint32"),
+        "srows": ((lanes, N + W), "uint32"),      # word-major (PR 36)
         "gq": ((1,), "int8"), "hq": ((1,), "int8"),
         "gs": ((), "float32"), "hs": ((), "float32"),
         "ekey": ((2, 2), "uint32"),

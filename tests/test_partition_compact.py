@@ -4,7 +4,9 @@
 ``compact`` networks and two masked window writes) must leave the destination buffers exactly as the old
 ``.at[pos].set(..., mode="drop")`` pair did: the same ids and the same
 payload at the same rows. The scatter lives on here as the oracle, not in
-the program.
+the program. The oracle holds the payload ``[N + W, SW]`` as that commit
+did; the pass holds it word-major (``[SW, N + W]``, lanes and positions on
+the last axis: PR 36), so its side is the oracle's transpose.
 """
 import jax
 import jax.numpy as jnp
@@ -35,9 +37,9 @@ def _trip_compact(pbuf, sbuf, rows, dw, gl, live, lcur, rcur, N):
     valid = jnp.arange(W, dtype=jnp.int32) < live
     gl = gl & valid
     gr = ~gl & valid
-    (pbuf, sbuf), nl, nr = route_window((pbuf, sbuf), (rows, dw), gl, gr,
-                                        lcur, rcur)
-    return pbuf, sbuf, nl
+    (pbuf, sbuf), nl, nr = route_window((pbuf, sbuf.T), (rows, dw.T), gl,
+                                        gr, lcur, rcur)
+    return pbuf, sbuf.T, nl
 
 
 ORACLE = jax.jit(_trip_oracle, static_argnames=("N",))
@@ -125,10 +127,10 @@ def test_compact_is_stable_and_exact(back):
     for W in (8, 64, 1024):
         keep = rng.rand(W) < 0.4
         ids = rng.randint(-2**31, 2**31 - 1, W).astype(np.int32)
-        vals = rng.randn(W, 3).astype(np.float32)
+        vals = rng.randn(3, W).astype(np.float32)
         a, b = compact(jnp.asarray(keep), jnp.asarray(ids),
                        jnp.asarray(vals), back=back)
         n = int(keep.sum())
         where = slice(W - n, W) if back else slice(0, n)
         assert np.array_equal(np.asarray(a)[where], ids[keep])
-        assert np.array_equal(np.asarray(b)[where], vals[keep])
+        assert np.array_equal(np.asarray(b)[:, where], vals[:, keep])
