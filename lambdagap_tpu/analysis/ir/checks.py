@@ -289,8 +289,11 @@ def check_c3_quant(contract: ProgramContract, scenario: str, traced,
     def walk(jaxpr):
         nonlocal checked
         for eqn in jaxpr.eqns:
+            # a histogram is [columns, bins, channels]; a 1-D psum (the
+            # tree's integer row counts, fused_learner count_rows) is not
             if eqn.primitive.name == "psum" and \
-                    data_axis in _axes_of(eqn):
+                    data_axis in _axes_of(eqn) and eqn.invars and \
+                    len(getattr(eqn.invars[0].aval, "shape", ())) > 1:
                 checked += 1
                 dt = str(getattr(getattr(eqn.invars[0], "aval", None),
                                  "dtype", "")) if eqn.invars else ""

@@ -680,6 +680,18 @@ class FusedTreeLearner(SerialTreeLearner):
                                self.hist_precision)
             return acc + part.reshape(HIST_C, C, Bb).transpose(1, 2, 0)
 
+        # rows a leaf holds, exactly, on the 1-D data mesh: the psum-ed
+        # float32 count channel rounds past 2^24 rows (a bin, a node) and
+        # the larger child's count is its parent's minus the smaller's, so a
+        # leaf under a node of that size could miss its true count by a few
+        # rows (996,173 read for 996,177 at 20M rows: test_mesh_cell.py).
+        # Each shard keeps its own in-bag counts as integers instead (a
+        # shard's float32 sums stay exact: at most its rows), and one psum
+        # of them at the end of the tree gives the model's leaf and node
+        # counts
+        count_rows = self.axis is not None and not self.voting \
+            and getattr(self, "feat_axis", None) is None
+
         def leaf_hist(perm, srows_c, begin, count):
             # jax.named_scope labels below tag the traced ops so profiler
             # windows (obs/profile.py) show the same histogram/partition/
@@ -696,6 +708,9 @@ class FusedTreeLearner(SerialTreeLearner):
                 _, hist = lax.while_loop(
                     lambda st: st[0] < nch, body,
                     (jnp.int32(0), jnp.zeros((C, Bb, HIST_C), acc_dtype)))
+                # every column's bins hold each in-bag row once
+                rows = jnp.sum(hist[0, :, 2]).astype(jnp.int32) \
+                    if count_rows else None
             if self.axis is not None and not self.voting:
                 # the one collective per split: local chunk loops may run
                 # different trip counts per shard (local leaf sizes differ),
@@ -715,7 +730,7 @@ class FusedTreeLearner(SerialTreeLearner):
                         [gs, hs, jnp.float32(1.0)])
             # voting + quant_exact: keep RAW level sums — the exact integer
             # reduction happens per voted column inside best_of, scales after
-            return hist
+            return hist, rows
 
         extra_on = self.extra_on
         contri = self.contri_arr
@@ -972,7 +987,8 @@ class FusedTreeLearner(SerialTreeLearner):
         with _scope("tree_init"):
             perm0 = jnp.concatenate([jnp.arange(N, dtype=jnp.int32),
                                      jnp.zeros(W, jnp.int32)])
-        hist_root = leaf_hist(perm0, srows, jnp.int32(0), jnp.int32(N))
+        hist_root, rows_root = leaf_hist(perm0, srows, jnp.int32(0),
+                                         jnp.int32(N))
         with _scope("tree_init"):
             totals = jnp.sum(hist_root[0], axis=0)
             if fax is not None and self.axis is not None:
@@ -1051,6 +1067,10 @@ class FusedTreeLearner(SerialTreeLearner):
                 # the carry so each split's permutation delta applies in place
                 state["srows"] = srows
                 state["srows_buf"] = jnp.zeros_like(srows)
+            if count_rows:
+                state["leaf_rows"] = jnp.zeros(L + 1, i32).at[0].set(
+                    rows_root)
+                state["node_rows"] = jnp.zeros(NODES + 1, i32)
             if ic_on:
                 state["path"] = jnp.zeros((L + 1, PW), jnp.uint32)
             if inter:
@@ -1383,7 +1403,7 @@ class FusedTreeLearner(SerialTreeLearner):
                 node_bits = st["node_bits"].at[wk].set(bitsv)
 
             # -- children histograms (smaller built, larger by subtraction)
-            hist_small = leaf_hist(perm, srows_new, sb, sc)
+            hist_small, rows_small = leaf_hist(perm, srows_new, sb, sc)
             with _scope("hist_subtract"):
                 hist_large = st["hist"][leaf] - hist_small
                 hist_left = jnp.where(small_is_left, hist_small, hist_large)
@@ -1548,6 +1568,14 @@ class FusedTreeLearner(SerialTreeLearner):
                 if layout_sorted:
                     out["srows"] = srows_new
                     out["srows_buf"] = sbuf
+                if count_rows:
+                    parent_rows = st["leaf_rows"][leaf]
+                    large = parent_rows - rows_small
+                    out["leaf_rows"] = st["leaf_rows"].at[wl].set(
+                        jnp.where(small_is_left, rows_small, large)).at[
+                        wn].set(jnp.where(small_is_left, large, rows_small))
+                    out["node_rows"] = st["node_rows"].at[wk].set(
+                        parent_rows)
                 if forced is not None:
                     out["forcing"] = forcing_next
                 if ic_on:
@@ -1619,6 +1647,13 @@ class FusedTreeLearner(SerialTreeLearner):
                            < state["num_leaves"])
                           & (state["num_leaves"] > 1))
                 leaf_value_out = jnp.where(active, renewed, leaf_value_out)
+            # once a tree, so under this scope (tree_fixed_device_ms), not
+            # under the per-split hist_allreduce
+            counts = None
+            if count_rows:
+                counts = [c.astype(f32) for c in lax.psum(
+                    (state["node_rows"][:NODES], state["leaf_rows"][:L]),
+                    self.axis)]
             return DeviceTree(
                 node_feature=node_i[:NODES, 0],
                 node_threshold=node_i[:NODES, 1],
@@ -1630,10 +1665,10 @@ class FusedTreeLearner(SerialTreeLearner):
                 node_gain=node_f[:NODES, 0],
                 node_value=node_f[:NODES, 1],
                 node_weight=node_f[:NODES, 2],
-                node_count=node_f[:NODES, 3],
+                node_count=node_f[:NODES, 3] if counts is None else counts[0],
                 leaf_value=leaf_value_out,
                 leaf_weight=leaf_f[:L, 1],
-                leaf_count=leaf_f[:L, 2],
+                leaf_count=leaf_f[:L, 2] if counts is None else counts[1],
                 leaf_depth=leaf_i[:L, 2],
                 leaf_parent_node=leaf_i[:L, 3],
                 num_leaves=state["num_leaves"],

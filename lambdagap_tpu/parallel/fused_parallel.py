@@ -149,23 +149,32 @@ class FusedDataParallelTreeLearner(FusedTreeLearner):
 
     # -- device-layout hooks -------------------------------------------
     def _place_binned(self, hx: np.ndarray) -> None:
+        """Each shard's rows go from the host straight to its device (no
+        whole-matrix staging on the first one). Under ``tree_layout=sorted``
+        the program never reads the column-major copy (the serial learner's
+        rule): a placeholder of one column a shard keeps the ``x_cols``
+        spec sharding over the data axis, where the copy would be N*C dead
+        bytes of every device."""
         if self.proc_sharded:
             pad = self.proc_pad - hx.shape[0]
             if pad:
                 hx = np.pad(hx, ((0, pad), (0, 0)))
             self.hx_rows = global_array_from_local(hx, self.mesh,
                                                    spec("x_rows"))
-            self.x_cols = global_array_from_local(
-                np.ascontiguousarray(hx.T), self.mesh, spec("x_cols"))
+            cols = np.zeros((1, self.proc_pad // self.n_loc), hx.dtype) \
+                if self.layout == "sorted" else np.ascontiguousarray(hx.T)
+            self.x_cols = global_array_from_local(cols, self.mesh,
+                                                  spec("x_cols"))
             return
         pad = self.n_pad - hx.shape[0]
         if pad:
             hx = np.pad(hx, ((0, pad), (0, 0)))
         self.hx_rows = jax.device_put(
-            jnp.asarray(hx), NamedSharding(self.mesh, spec("x_rows")))
+            hx, NamedSharding(self.mesh, spec("x_rows")))
+        cols = np.zeros((1, self.n_dev), hx.dtype) \
+            if self.layout == "sorted" else np.ascontiguousarray(hx.T)
         self.x_cols = jax.device_put(
-            jnp.asarray(np.ascontiguousarray(hx.T)),
-            NamedSharding(self.mesh, spec("x_cols")))
+            cols, NamedSharding(self.mesh, spec("x_cols")))
 
     # ------------------------------------------------------------------
     def _shard_vec(self, v: jax.Array) -> jax.Array:
@@ -1281,6 +1290,11 @@ def _hist_bytes(d):
     return -(-d["features"] // d["ff"]) * d["bins"] * d["hist_item"]
 
 
+def _rows_bytes(d):
+    # the tree's exact row counts: one int32 a leaf and one a node
+    return 4 * (d["leaves"] + max(d["leaves"] - 1, 1))
+
+
 def _rowflag_bytes(d):
     # go-left partition flags: one byte per shard-resident row
     return -(-d["rows"] // d["dd"])
@@ -1290,9 +1304,11 @@ register_program(
     "FusedDataParallelTreeLearner._train_tree_impl",
     quant_int_reduction=True,
     step_collectives=(psum("data", 1, "leaf histogram", _hist_bytes),),
-    setup_collectives=(psum("data", 1, "root histogram", _hist_bytes),),
+    setup_collectives=(psum("data", 3, "root histogram; leaf rows; node "
+                            "rows", lambda d: _hist_bytes(d) + _rows_bytes(d)),),
     notes="one histogram psum per split step; splits are chosen locally "
-          "on the replicated reduced histograms — no other wire traffic")
+          "on the replicated reduced histograms; once a tree, the shards' "
+          "exact leaf and node row counts — no other wire traffic")
 
 register_program(
     "FusedVotingParallelTreeLearner._train_tree_impl",

@@ -192,6 +192,21 @@ def test_data_parallel_forest_equals_the_row_major_payloads(small_windows):
     _held_to_golden("data_parallel", _data_parallel)
 
 
+def test_sorted_mesh_learner_holds_no_column_major_matrix(small_windows):
+    """Under the sorted layout the program decodes the split feature from
+    the payload window: a ``[C, N]`` copy on the mesh is dead bytes, and
+    the placeholder still shards over the data axis (one column a shard)."""
+    learner = _data_parallel("sorted")["learner"]
+    assert learner.x_cols.shape == (1, 4)
+    assert [s.data.shape for s in learner.x_cols.addressable_shards] \
+        == [(1, 1)] * 4
+    import jax
+    big = [a.shape for a in learner.__dict__.values()
+           if isinstance(a, jax.Array) and a.ndim == 2
+           and a.shape[0] < a.shape[1] and a.shape[1] >= N]
+    assert big == [], big
+
+
 def _write_golden(out: dict) -> None:
     """One case a line."""
     lines = [f" {json.dumps(name)}: "
