@@ -29,7 +29,7 @@ from jax import lax
 from ..config import Config
 from ..data.dataset import BinnedDataset
 from ..obs.telemetry import device_scope as _scope
-from ..ops.histogram import gh_contract
+from ..ops.histogram import gh_contract, write_children
 from ..ops.partition import decision_go_left, position_leaf, route_window
 from ..ops.split import (K_MIN_SCORE, SplitParams, calculate_leaf_output,
                          gather_threshold_split, leaf_gain, per_feature_best)
@@ -1405,10 +1405,8 @@ class FusedTreeLearner(SerialTreeLearner):
             # -- children histograms (smaller built, larger by subtraction)
             hist_small, rows_small = leaf_hist(perm, srows_new, sb, sc)
             with _scope("hist_subtract"):
-                hist_large = st["hist"][leaf] - hist_small
-                hist_left = jnp.where(small_is_left, hist_small, hist_large)
-                hist_right = jnp.where(small_is_left, hist_large, hist_small)
-                hist = st["hist"].at[wl].set(hist_left).at[wn].set(hist_right)
+                hist, hist_left, hist_right = write_children(
+                    st["hist"], leaf, hist_small, small_is_left, wl, wn)
 
             # -- both children's best splits in one vmapped scan -------
             with _scope("split_scan"):
@@ -2034,10 +2032,8 @@ class FusedTreeLearner(SerialTreeLearner):
         node_bits = state["node_bits"].at[wk].set(bitsv)
 
         small_is_left = left_count <= right_count
-        hist_large = state["hist"][leaf] - hist_small
-        hist_left = jnp.where(small_is_left, hist_small, hist_large)
-        hist_right = jnp.where(small_is_left, hist_large, hist_small)
-        hist = state["hist"].at[wl].set(hist_left).at[wn].set(hist_right)
+        hist, hist_left, hist_right = write_children(
+            state["hist"], leaf, hist_small, small_is_left, wl, wn)
 
         fms = jnp.broadcast_to(fmask, (2, F))
         best_children = jax.vmap(self._stream_best_of,

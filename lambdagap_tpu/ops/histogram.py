@@ -234,6 +234,33 @@ def subtract_histogram(parent_hist: jax.Array, child_hist: jax.Array) -> jax.Arr
     return parent_hist - child_hist
 
 
+def write_children(hist: jax.Array, parent: jax.Array, hist_small: jax.Array,
+                   small_is_left: jax.Array, wl: jax.Array, wn: jax.Array):
+    """Replace leaf ``parent``'s histogram in the carried state
+    ``hist [L + 1, C, Bb, 3]`` by its two children's: the left one at slot
+    ``wl``, the right one at slot ``wn`` (the right wins where they
+    coincide), the larger child by subtraction of ``hist_small``.
+
+    The parent's slice is read once and both children are materialised
+    before either write, so neither write reads the state again and both
+    land in place. Fused into the writes, the second one re-read the
+    parent out of the loop's incoming carry after the first had changed
+    it, and the compiler copied the whole state twice a split to keep that
+    carry intact. Returns ``(hist, hist_left, hist_right)``."""
+    # each child fills exactly one slot; ``parent``, ``wl`` and ``wn`` are
+    # leaf indices or the dump slot L, all rows of ``hist``
+    assert hist.shape[1:] == hist_small.shape, (hist.shape, hist_small.shape)
+    hist_large = subtract_histogram(
+        lax.dynamic_index_in_dim(hist, parent, keepdims=False), hist_small)
+    hist_left = jnp.where(small_is_left, hist_small, hist_large)
+    hist_right = jnp.where(small_is_left, hist_large, hist_small)
+    hist_left, hist_right = lax.optimization_barrier((hist_left, hist_right))
+    at = (0,) * hist_small.ndim
+    hist = lax.dynamic_update_slice(hist, hist_left[None], (wl,) + at)
+    hist = lax.dynamic_update_slice(hist, hist_right[None], (wn,) + at)
+    return hist, hist_left, hist_right
+
+
 # ---------------------------------------------------------------------------
 # data_residency=stream kernels (docs/performance.md "Out-of-core"): the
 # binned matrix lives in host shards; windows arrive as UPLOADED buffers

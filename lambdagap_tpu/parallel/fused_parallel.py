@@ -41,6 +41,7 @@ from ..config import Config
 from ..data.dataset import BinnedDataset
 from ..models.fused_learner import HIST_C, DeviceTree, FusedTreeLearner
 from ..models.learner import _next_pow2
+from ..ops.histogram import write_children
 from ..ops.partition import position_leaf
 from ..ops.split import (K_MIN_SCORE, calculate_leaf_output, leaf_gain,
                          per_feature_best)
@@ -1026,10 +1027,8 @@ class Fused2DTreeLearner(FusedTreeLearner):
         # local partial fed the one psum); local partition counts differ
         # per shard, the scan's global (in-bag) counts do not
         small_is_left = lc <= pc - lc
-        hist_large = state["hist"][leaf] - hist_small
-        hist_left = jnp.where(small_is_left, hist_small, hist_large)
-        hist_right = jnp.where(small_is_left, hist_large, hist_small)
-        hist = state["hist"].at[wl].set(hist_left).at[wn].set(hist_right)
+        hist, hist_left, hist_right = write_children(
+            state["hist"], leaf, hist_small, small_is_left, wl, wn)
 
         fms = jnp.broadcast_to(fmask, (2, F))
         best_children = jax.vmap(self._s2_best_of,

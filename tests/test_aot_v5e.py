@@ -183,71 +183,114 @@ WINDOWED = {"dynamic-update-slice", "while", "parameter",
             "get-tuple-element", "tuple", "bitcast"}
 
 
-def _payload_producers(text: str, elements: int):
-    """Instructions of the compiled program's loops (every computation but
-    ``ENTRY``) whose result, or any element of a tuple result, is an array
-    of ``elements`` elements or more, whatever the instruction: a copy, a
-    transpose, a fusion of one output or several, an asynchronous copy's
-    tuple. An in-place ``dynamic-update-slice`` names the whole buffer too
-    and writes a window of it, and a loop, its parameter and the tuples
-    around it carry it: ``WINDOWED`` are the only names let through."""
-    bad = []
+def _loop_results(text: str):
+    """(opcode, element count of each array of the result, line) of every
+    instruction of the compiled program's loops (every computation but
+    ``ENTRY``); a tuple result counts each of its arrays."""
     for entry, line in _instructions(text):
         m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(",
                      line)
-        if entry or not m or m.group(2) in WINDOWED:
-            continue
-        if any(_elements(dims) >= elements
-               for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
-            bad.append(line.strip()[:160])
-    return bad
+        if not entry and m:
+            yield (m.group(2), [_elements(dims) for dims in
+                                re.findall(r"\w+\[([\d,]+)\]", m.group(1))],
+                   line.strip()[:160])
 
 
-@pytest.mark.parametrize("features,n,window", [
+def _payload_producers(text: str, elements: int):
+    """Instructions of the compiled program's loops whose result, or any
+    element of a tuple result, is an array of ``elements`` elements or
+    more, whatever the instruction: a copy, a transpose, a fusion of one
+    output or several, an asynchronous copy's tuple. An in-place
+    ``dynamic-update-slice`` names the whole buffer too and writes a window
+    of it, and a loop, its parameter and the tuples around it carry it:
+    ``WINDOWED`` are the only names let through."""
+    return [line for op, sizes, line in _loop_results(text)
+            if op not in WINDOWED and max(sizes, default=0) >= elements]
+
+
+@pytest.fixture(scope="module", params=[
     (220, 3_408_630, 8_192), (28, 10_500_000, 32_768)],
     ids=["istella-s-57-words", "higgs-9-words"])
-def test_the_split_loop_moves_the_sorted_payload_by_windows_alone(
-        one_chip, monkeypatch, features, n, window):
-    """The tree program at the two cells' shapes (255 leaves, 255 bins, the
-    Pallas kernel, ``tree_layout=sorted``; a learner built on 4,096 rows and
-    lowered at the cell's N and W): outside ``ENTRY`` no instruction
-    produces an array as large as the sorted payload. Held as ``[N + W, 57]`` the split
-    loop's carry and the copy-back loop were row-major, the partition and
-    histogram loops N-minor, and the compiler converted all 779 MB between
-    them twice a split (``%copy.135``, ``%copy.136``: 1,940 of
-    ``istella-s-train``'s 3,990 device ms, PR 36); at 9 words it never
-    did. The payload is word-major since (``_build_sorted_impl``)."""
+def tree_program(request, one_chip):
+    """The tree program compiled for a v5e at one of two cells' shapes (255
+    leaves, 255 bins, the Pallas kernel, ``tree_layout=sorted``; a learner
+    built on 4,096 rows and lowered at the cell's N and W), once for every
+    test of this file that reads it: a compile takes ~20 s at 28 features
+    and ~40 s at 220."""
     import lambdagap_tpu as lgb
     from lambdagap_tpu.ops import hist_pallas
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(hist_pallas, "_interpret", lambda: False)
-    rng = np.random.default_rng(0)
-    X = rng.normal(size=(4096, features)).astype(np.float32)
-    params = {"objective": "binary", "num_leaves": 255, "max_bin": 255,
-              "tree_layout": "sorted", "tpu_fused_learner": 1,
-              "tpu_hist_impl": "pallas", "enable_bundle": False,
-              "verbose": -1}
-    learner = lgb.Booster(params, lgb.Dataset(
-        X, label=(X[:, 0] > 0).astype(np.float32),
-        params=params))._booster.learner
-    assert (type(learner).__name__, learner.layout, learner.hist_impl) \
-        == ("FusedTreeLearner", "sorted", "pallas")
-    learner.chunk = window          # the cell's own W (_pick_chunk at its N)
+    features, n, window = request.param
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        mp.setattr(hist_pallas, "_interpret", lambda: False)
+        rng = np.random.default_rng(0)
+        X = rng.normal(size=(4096, features)).astype(np.float32)
+        params = {"objective": "binary", "num_leaves": 255, "max_bin": 255,
+                  "tree_layout": "sorted", "tpu_fused_learner": 1,
+                  "tpu_hist_impl": "pallas", "enable_bundle": False,
+                  "verbose": -1}
+        learner = lgb.Booster(params, lgb.Dataset(
+            X, label=(X[:, 0] > 0).astype(np.float32),
+            params=params))._booster.learner
+        assert (type(learner).__name__, learner.layout, learner.hist_impl) \
+            == ("FusedTreeLearner", "sorted", "pallas")
+        learner.chunk = window      # the cell's own W (_pick_chunk at its N)
 
-    def arg(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        def arg(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    x_rows, q = arg((n, features), jnp.uint8), arg((1,), jnp.int8)
-    srows = jax.eval_shape(
-        lambda *a: learner._build_sorted_impl(*a, has_mask=False),
-        arg((n,)), arg((n,)), arg((1,), jnp.bool_), x_rows, q, q)
-    words = -(-(features + 8) // 4)
-    assert sorted(srows.shape) == [words, n + window]
-    compiled = learner._train_jit.lower(
-        arg((n,)), arg((n,)), arg((1,), jnp.bool_),
-        arg((features,), jnp.bool_), x_rows, arg((1, 1), jnp.uint8),
-        arg(srows.shape, srows.dtype), q, q, arg(()), arg(()),
-        arg((2, 2), jnp.uint32), has_mask=False).compile()
-    text = compiled.as_text()
+        x_rows, q = arg((n, features), jnp.uint8), arg((1,), jnp.int8)
+        srows = jax.eval_shape(
+            lambda *a: learner._build_sorted_impl(*a, has_mask=False),
+            arg((n,)), arg((n,)), arg((1,), jnp.bool_), x_rows, q, q)
+        text = learner._train_jit.lower(
+            arg((n,)), arg((n,)), arg((1,), jnp.bool_),
+            arg((features,), jnp.bool_), x_rows, arg((1, 1), jnp.uint8),
+            arg(srows.shape, srows.dtype), q, q, arg(()), arg(()),
+            arg((2, 2), jnp.uint32), has_mask=False).compile().as_text()
     assert "jit__train_tree_impl" in text[:200] and "lg_hist" in text
-    assert _payload_producers(text, words * (n + window)) == []
+    return {"features": features, "n": n, "window": window,
+            "srows": srows.shape, "text": text,
+            # the carried histogram state, [L + 1, C, Bb, 3]
+            "hist": (255 + 1) * features * learner.Bb * 3}
+
+
+def test_the_split_loop_moves_the_sorted_payload_by_windows_alone(
+        tree_program):
+    """The tree program at the two cells' shapes: outside ``ENTRY`` no
+    instruction produces an array as large as the sorted payload. Held as
+    ``[N + W, 57]`` the split loop's carry and the copy-back loop were
+    row-major, the partition and histogram loops N-minor, and the compiler
+    converted all 779 MB between them twice a split (``%copy.135``,
+    ``%copy.136``: 1,940 of ``istella-s-train``'s 3,990 device ms on a v5e);
+    at 9 words it never did. The payload is word-major since
+    (``_build_sorted_impl``)."""
+    features, n, window = (tree_program[k] for k in ("features", "n",
+                                                     "window"))
+    words = -(-(features + 8) // 4)
+    assert sorted(tree_program["srows"]) == [words, n + window]
+    assert _payload_producers(tree_program["text"],
+                              words * (n + window)) == []
+
+
+def _whole_copies(text: str, elements: int):
+    """``copy``, ``copy-start`` and ``transpose`` instructions of the
+    compiled program's loops whose result, or an element of a tuple
+    result, is an array of exactly ``elements`` elements."""
+    return [line for op, sizes, line in _loop_results(text)
+            if op in ("copy", "copy-start", "transpose") and elements in sizes]
+
+
+def test_the_split_loop_writes_the_childrens_histograms_in_place(
+        tree_program):
+    """The tree program at the two cells' shapes: outside ``ENTRY`` no copy
+    or transpose produces an array of the carried histogram state's size,
+    ``[L + 1, C, Bb, 3]``. Written as two ``.at[].set`` of children that
+    each read the parent's slice, the second write read the loop's
+    incoming carry after the first had changed it, and the compiler copied
+    the whole state twice a split to keep that carry intact (``%copy.161``
+    and ``%copy.213``, 173 MB each at 220 features: 273.5 of
+    ``istella-s-train``'s 2,041 device ms on a v5e; ``%copy.255`` and
+    ``%copy.307`` at 28). ``ops.histogram.write_children`` reads the parent
+    once, before either write."""
+    assert _whole_copies(tree_program["text"], tree_program["hist"]) == []

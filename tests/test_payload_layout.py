@@ -11,7 +11,12 @@ threshold bins and leaf row counts, and the last tree's row -> leaf map
 stay what they were, for both bin widths, at ``higgs``'s 9 words a row and
 at ``istella-s``'s 57, with float gradients, quantized ones and a bagging
 mask column, and for the data-parallel learner, whose shards hold the
-payload's rows on its second axis now. The golden holds a sha256 of each
+payload's rows on its second axis now. Three more cases hold the readers of
+the carried histogram state besides the split itself to the forests of the
+commit before ``ops.histogram.write_children``: a forced split gathers its
+statistics from a leaf's histogram, the intermediate monotone method
+re-scans the leaves whose bounds a split tightened, and the voting learner
+votes over its shards' histograms. The golden holds a sha256 of each
 (as ``row_leaf`` and the model text always were), and the integers of one
 case, ``IN_THE_CLEAR``, beside their digests, to debug a miss from.
 
@@ -49,6 +54,17 @@ EXTRA = {
 }
 CASES = [(bins, words, channel) for bins in ("uint8", "uint16")
          for words in (9, 57) for channel in RIDERS]
+# readers of the carried histogram state, with float gradients riding
+FORCED = os.path.join(os.path.dirname(__file__), "data",
+                      "payload_forced_splits.json")
+READERS = {
+    "forced": {"forcedsplits_filename": FORCED},
+    "intermediate": {"monotone_constraints_method": "intermediate"},
+    "voting": {"tree_learner": "voting", "tpu_num_devices": 4},
+}
+RIDERS.update(dict.fromkeys(READERS, RIDERS["plain"]))
+EXTRA.update(READERS)
+CASES += [("uint8", 57, reader) for reader in READERS]
 INTEGERS = ("split_feature", "threshold_bin", "leaf_count")
 IN_THE_CLEAR = "uint8-57-plain"
 
@@ -64,6 +80,12 @@ def _data(features: int):
     X = rng.randn(N, features)
     y = X[:, 1] + np.sin(X[:, 2] * 2) + X[:, 3] * 0.5 + 0.1 * rng.randn(N)
     return X, y
+
+
+def _monotone(features: int) -> list:
+    """Increasing in the two linear features of ``_data``, decreasing in
+    the sine's."""
+    return [0, 1, -1, 1] + [0] * (features - 4)
 
 
 def _params(bins: str, channel: str, layout: str, **more) -> dict:
@@ -103,10 +125,18 @@ def _grow(features: int, params: dict, learner_type: str) -> dict:
 
 
 def _serial(bins: str, words: int, channel: str, layout: str) -> dict:
-    got = _grow(_features(bins, words, channel),
-                _params(bins, channel, layout), "FusedTreeLearner")
+    features = _features(bins, words, channel)
+    more = ({"monotone_constraints": _monotone(features)}
+            if channel == "intermediate" else {})
+    voting = channel == "voting"
+    got = _grow(features, _params(bins, channel, layout, **more),
+                "FusedVotingParallelTreeLearner" if voting
+                else "FusedTreeLearner")
     learner = got["learner"]
-    assert str(learner.hx_rows.dtype) == bins and learner._window(N) == 1024
+    assert str(learner.hx_rows.dtype) == bins
+    assert learner.n_loc == N // 4 if voting else learner._window(N) == 1024
+    if channel == "forced":
+        assert [t[0] for t in got["split_feature"]] == [7] * ROUNDS
     return got
 
 
@@ -181,7 +211,7 @@ def test_forest_equals_the_row_major_payloads(small_windows, bins, words,
                                               channel):
     def grow(layout):
         got = _serial(bins, words, channel, layout)
-        if layout == "sorted":
+        if layout == "sorted" and channel != "voting":
             assert _payload_shape(got["learner"], channel == "bagged") \
                 == (words, N + 1024)
         return got
