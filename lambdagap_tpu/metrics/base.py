@@ -50,10 +50,6 @@ class Metric:
         one)."""
         return None
 
-    #: host constants of the device form for the iteration record's
-    #: ``counts`` (the ranking metrics' padded buckets)
-    work_counts: Dict[str, int] = {}
-
     def _avg(self, pointwise: np.ndarray) -> float:
         if self.weight is not None:
             return float(np.sum(pointwise * self.weight) / self.sum_weight)

@@ -119,11 +119,6 @@ class NDCGMetric(_RankMetricBase):
                 jnp.asarray(ones / self.sum_qw, jnp.float32),
                 jnp.float32(1.0 / self.sum_qw))
 
-    @property
-    def work_counts(self):
-        return {"valid_queries": int(self.num_queries),
-                "valid_pad_docs": sum(int(b[0].size) for b in self._dev[1])}
-
     def eval_device(self, scores):
         if scores.ndim == 2:
             if scores.shape[0] != 1:

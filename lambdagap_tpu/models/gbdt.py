@@ -617,7 +617,7 @@ class GBDT:
             # leaves" stop check is skipped to avoid a per-iteration D2H —
             # converged training just appends constant trees.
             for k in range(self.num_tree_per_iteration):
-                with tel.phase("tree", legacy="tree: fused train"):
+                with tel.phase("tree"):
                     rec = self.learner.train_device(grad[k], hess[k],
                                                     row_mask=mask)
                 with tel.phase("score_update"):
@@ -653,7 +653,7 @@ class GBDT:
 
         should_continue = False
         for k in range(self.num_tree_per_iteration):
-            with tel.phase("tree", legacy="tree: train"):
+            with tel.phase("tree"):
                 tree = self.learner.train(grad[k], hess[k], row_mask=mask)
             if tree.num_leaves > 1:
                 should_continue = True
@@ -847,15 +847,9 @@ class GBDT:
 
     def _valid_counts(self) -> Dict[str, int]:
         """Host constants of the watched sets for the iteration record's
-        ``counts``: sets, rows, and the queries and padded documents of
-        the metrics' device buckets."""
-        counts = {"valid_sets": len(self.valid_sets),
-                  "valid_rows": sum(ds.num_data for _, ds in self.valid_sets)}
-        for metrics in self.valid_metrics:
-            for m in metrics:
-                for name, v in m.work_counts.items():
-                    counts[name] = counts.get(name, 0) + v
-        return counts
+        ``counts``: sets and rows."""
+        return {"valid_sets": len(self.valid_sets),
+                "valid_rows": sum(ds.num_data for _, ds in self.valid_sets)}
 
     def _route_valid(self, vi: int, routed: RoutingTree, leaf_values,
                      k: int) -> None:

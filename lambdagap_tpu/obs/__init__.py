@@ -11,8 +11,10 @@ The telemetry subsystem the perf work reports through (docs/observability.md):
 - :mod:`.events` — JSONL structured run log (run header, one record per
   iteration, compile/swap/error events).
 - :mod:`.xla_watch` — recompile & transfer watchdog over ``jax.monitoring``
-  events; warns when a steady-state iteration triggers a fresh compile
-  (the graftlint-R2 hazard class, caught at runtime).
+  events; sorts every compile into fresh (XLA ran) or loaded (the
+  persistent cache served it), per program; warns when a steady-state
+  iteration triggers a fresh compile (the graftlint-R2 hazard class,
+  caught at runtime).
 - :mod:`.profile` — ``jax.profiler`` capture windows driven by the
   ``profile_start_iter`` / ``profile_n_iters`` / ``profile_dir`` knobs.
 - :mod:`.prom` — Prometheus text exposition for ``TrainTelemetry``, the
@@ -32,9 +34,9 @@ The telemetry subsystem the perf work reports through (docs/observability.md):
   residency/eviction pressure, per-replica health timeline): the inputs
   ROADMAP item 2's revival/placement/autoscaling loop consumes.
 
-Everything is inert unless enabled (``telemetry=true`` / ``telemetry_out=``
-/ ``LAMBDAGAP_TIMETAG``; ``serve_trace_sample>0`` for tracing): the off
-path records nothing and registers no ``jax.monitoring`` hooks.
+Everything is inert unless enabled (``telemetry=true`` / ``telemetry_out=``;
+``serve_trace_sample>0`` for tracing): the off path records nothing and
+registers no ``jax.monitoring`` hooks.
 """
 from __future__ import annotations
 

@@ -1,10 +1,10 @@
 """TrainTelemetry: per-iteration phase spans for the boosting loop.
 
-The training analog of serve's ``ServeStats`` and the replacement for the
-coarse ``utils.timer`` scopes: every boosting iteration produces one record
-with named phase spans (gradients, sampling, tree, histogram, split,
-partition, score_update, eval, device_wait), kept in a bounded ring buffer
-and aggregated into totals + an iteration-wall reservoir. The GPU GBDT
+The training analog of serve's ``ServeStats``: every boosting iteration
+produces one record with named phase spans (gradients, sampling, tree,
+histogram, split, partition, score_update, eval, device_wait), kept in a
+bounded ring buffer and aggregated into totals + an iteration-wall
+reservoir. The GPU GBDT
 literature (arXiv:1806.11248, arXiv:2005.09148) attributes its wins with
 exactly this phase-level breakdown; here it is a first-class subsystem so
 every perf PR ships its own evidence.
@@ -33,7 +33,6 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 from ..utils import log
-from ..utils import timer as _timer
 from .events import RunLog
 from .profile import ProfileWindow
 from .reservoir import Reservoir
@@ -110,15 +109,6 @@ def device_scope(name: str):
     return jax.named_scope(name)
 
 
-# phase -> the utils.timer scope name it replaces (the deprecation shim:
-# the legacy global_timer report keeps its historical row names)
-_LEGACY = {
-    "gradients": "boosting: gradients",
-    "sampling": "boosting: sampling",
-    "score_update": "score: update",
-}
-
-
 class _NullSpan:
     """Reusable no-op context manager for the disabled path."""
     __slots__ = ()
@@ -133,15 +123,18 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _rounded(by_program: Dict[str, Dict]) -> Dict[str, Dict]:
+    return {k: {"n": v["n"], "secs": round(v["secs"], 6)}
+            for k, v in sorted(by_program.items())}
+
+
 class _Span:
     """One live phase span; see TrainTelemetry.phase."""
-    __slots__ = ("tel", "name", "legacy", "ann")
+    __slots__ = ("tel", "name", "ann")
 
-    def __init__(self, tel: "TrainTelemetry", name: str,
-                 legacy: Optional[str]) -> None:
+    def __init__(self, tel: "TrainTelemetry", name: str) -> None:
         self.tel = tel
         self.name = name
-        self.legacy = legacy
         self.ann = tel._annotate_phase(name)
 
     def __enter__(self):
@@ -156,7 +149,7 @@ class _Span:
         name, t0, child = tel._stack.pop()
         dt = time.perf_counter() - t0
         self.ann.__exit__(*exc)
-        tel._add_phase(name, dt - child, dt, self.legacy)
+        tel._add_phase(name, dt - child)
         if tel._stack:
             tel._stack[-1][2] += dt
         return False
@@ -211,8 +204,7 @@ class TrainTelemetry:
     def from_config(cls, config, params: Optional[Dict[str, Any]] = None
                     ) -> "TrainTelemetry":
         """Build from the ``telemetry*`` / ``profile_*`` config knobs.
-        ``telemetry_out``, a configured profiler window, or the legacy
-        ``LAMBDAGAP_TIMETAG`` env (evaluated NOW, not at import) each imply
+        ``telemetry_out`` or a configured profiler window implies
         ``telemetry=true``."""
         out = getattr(config, "telemetry_out", "") or ""
         profile = ProfileWindow(
@@ -220,7 +212,7 @@ class TrainTelemetry:
             n_iters=getattr(config, "profile_n_iters", 1),
             out_dir=getattr(config, "profile_dir", "") or "")
         enabled = (bool(getattr(config, "telemetry", False)) or bool(out)
-                   or profile.enabled or _timer.timer_enabled())
+                   or profile.enabled)
         return cls(enabled=enabled, out=out,
                    ring=getattr(config, "telemetry_ring", 256),
                    warmup=getattr(config, "telemetry_warmup", 2),
@@ -232,13 +224,13 @@ class TrainTelemetry:
     def current_phase(self) -> Optional[str]:
         return self._stack[-1][0] if self._stack else None
 
-    def phase(self, name: str, legacy: Optional[str] = None):
+    def phase(self, name: str):
         """Context manager timing one named phase (nested spans use
         exclusive accounting). Cheap no-op when disabled or when no
         iteration record is open."""
         if not self.enabled or self._cur is None:
             return _NULL_SPAN
-        return _Span(self, name, legacy)
+        return _Span(self, name)
 
     def _annotate_phase(self, name: str):
         return self._annotation(PHASE_ANNOTATION + name,
@@ -290,9 +282,9 @@ class TrainTelemetry:
     def end_iteration(self, sync: Any = None) -> None:
         """Close the iteration's device-complete train window: ONE
         ``block_until_ready`` on ``sync`` (the score state), recorded as
-        the ``device_wait`` phase; stamps ``wall_s`` and the iteration's
-        compile/transfer deltas. The record stays open for late phases
-        (eval) until the next :meth:`begin_iteration`."""
+        the ``device_wait`` phase; stamps ``wall_s``. The record stays open
+        for late phases (eval) until the next :meth:`begin_iteration` or
+        :meth:`close`, which stamps its compile/transfer deltas."""
         if not self.enabled or self._cur is None or self._train_done:
             return
         import jax
@@ -304,10 +296,9 @@ class TrainTelemetry:
                 except Exception:  # pragma: no cover - deleted buffers etc.
                     pass
         now = time.perf_counter()
-        self._add_phase("device_wait", now - t, now - t, None)
+        self._add_phase("device_wait", now - t)
         self._cur["wall_s"] = now - self._t0
         self._close_iter_annotation()
-        self._stamp_watch()
         self._read_deferred()
         self._train_done = True
         self.watchdog.set_iteration(None)
@@ -348,19 +339,12 @@ class TrainTelemetry:
                 counts[k] = counts.get(k, 0) + int(v)
         self.add_counts(counts)
 
-    def _add_phase(self, name: str, exclusive: float, inclusive: float,
-                   legacy: Optional[str]) -> None:
+    def _add_phase(self, name: str, exclusive: float) -> None:
         if self._cur is not None:
             ph = self._cur["phases"]
             ph[name] = ph.get(name, 0.0) + exclusive
         self.totals[name] = self.totals.get(name, 0.0) + exclusive
         self.counts[name] = self.counts.get(name, 0) + 1
-        if _timer.timer_enabled():
-            # deprecation shim: the legacy global_timer table is now a view
-            # over telemetry spans, under its historical scope names
-            scope = legacy or _LEGACY.get(name, name)
-            _timer.global_timer.totals[scope] += inclusive
-            _timer.global_timer.counts[scope] += 1
 
     def _stamp_watch(self) -> None:
         tot = self.watchdog.totals()
@@ -368,11 +352,27 @@ class TrainTelemetry:
         by_phase = {k: v - base["compiles_by_phase"].get(k, 0)
                     for k, v in tot["compiles_by_phase"].items()
                     if v - base["compiles_by_phase"].get(k, 0)}
+        # the iteration's compiles, fresh and loaded, and the run's to date
+        # (the benchmark reads set-up's from the window's first record)
         self._cur["compiles"] = {
             "total": tot["compiles"] - base["compiles"],
             "steady": tot["steady_compiles"] - base["steady_compiles"],
             "secs": round(tot["compile_secs"] - base["compile_secs"], 6),
             "by_phase": by_phase,
+            "fresh": tot["fresh"] - base["fresh"],
+            "fresh_secs": round(tot["fresh_secs"] - base["fresh_secs"], 6),
+            "loaded": tot["loaded"] - base["loaded"],
+            "load_secs": round(tot["load_secs"] - base["load_secs"], 6),
+            "run": {
+                "total": tot["compiles"],
+                "secs": round(tot["compile_secs"], 6),
+                "fresh": tot["fresh"],
+                "fresh_secs": round(tot["fresh_secs"], 6),
+                "loaded": tot["loaded"],
+                "load_secs": round(tot["load_secs"], 6),
+                "fresh_by_program": _rounded(tot["fresh_by_program"]),
+                "loaded_by_program": _rounded(tot["loaded_by_program"]),
+            },
         }
         self._cur["transfers"] = {
             "total": tot["transfers"] - base["transfers"],
@@ -389,8 +389,10 @@ class TrainTelemetry:
         if "wall_s" not in rec:         # end_iteration never ran
             rec["wall_s"] = time.perf_counter() - self._t0
             self._close_iter_annotation()
-            self._stamp_watch()
             self._deferred = []
+        # what the iteration compiled, late phases included: the records'
+        # deltas tile the run from the first record's start
+        self._stamp_watch()
         # round phase seconds for a compact JSONL (µs resolution)
         rec["phases"] = {k: round(v, 6) for k, v in rec["phases"].items()}
         rec["wall_s"] = round(rec["wall_s"], 6)
@@ -424,7 +426,7 @@ class TrainTelemetry:
         return out
 
     def report(self) -> str:
-        """Human-readable phase table (the global_timer.report analog)."""
+        """Human-readable phase table."""
         if not self.enabled:
             return "telemetry disabled"
         lines = [f"TrainTelemetry ({self.iterations} iterations):"]

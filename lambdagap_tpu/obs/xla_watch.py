@@ -12,6 +12,16 @@ after warmup every shape should be compiled, so a steady-state compile
 means a shape-unstable program (e.g. a non-power-of-2 pad, a closed-over
 mutable attribute) silently recompiling every iteration.
 
+Each backend compile is also sorted by what the persistent compile cache did
+for it: jax fires ``/jax/compilation_cache/compile_requests_use_cache`` when
+the request asks the cache, then ``/cache_hits`` and the duration
+``/cache_retrieval_time_sec`` when the cache served it, all on the compiling
+thread and inside the backend-compile duration that wraps them
+(``jax/_src/compiler.py:compile_or_get_cached``). A compile the cache served
+is ``loaded`` (its seconds are the retrieval); any other is ``fresh`` (a
+miss, or a request that did not use the cache). The two sum to the
+compile count and ``compile_secs``, overall and per program (``fun_name``).
+
 Nothing registers unless :meth:`install` is called (the telemetry-off path
 must add zero ``jax.monitoring`` hooks), and :meth:`uninstall` removes the
 listeners again.
@@ -39,23 +49,17 @@ def _is_transfer_event(event: str) -> bool:
     return "transfer" in event
 
 
-# jax.monitoring kwargs keys that identify WHICH executable a compile
-# event belongs to, in preference order. Current jax versions fire
-# backend_compile with empty kwargs (every compile is then an anonymous
-# per-phase count, as before), but fingerprint/module kwargs exist in the
-# instrumented builds and newer versions — when present, the watchdog
-# attributes the compile to them so `totals()["compiles_by_module"]`
-# names the recompiling program instead of just its phase.
-_MODULE_KWARGS = ("fingerprint", "module_name", "fun_name", "module",
-                  "name")
+# the persistent cache's events (plain events, then a duration on a hit)
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
-def _module_of(kwargs: Dict) -> Optional[str]:
-    for key in _MODULE_KWARGS:
-        val = kwargs.get(key)
-        if val:
-            return str(val)
-    return None
+def _add_program(by_program: Dict[str, Dict], name: str,
+                 duration: float) -> None:
+    entry = by_program.setdefault(name, {"n": 0, "secs": 0.0})
+    entry["n"] += 1
+    entry["secs"] += duration
 
 
 class XlaWatchdog:
@@ -80,9 +84,15 @@ class XlaWatchdog:
         self.steady_compiles = 0
         self.transfers = 0
         self.compiles_by_phase: Dict[str, int] = {}
-        self.compiles_by_module: Dict[str, int] = {}
         self.transfers_by_phase: Dict[str, int] = {}
         self.compile_secs = 0.0
+        # each compile is fresh or loaded (module docstring)
+        self.fresh = self.loaded = 0
+        self.fresh_secs = self.load_secs = 0.0
+        self.fresh_by_program: Dict[str, Dict] = {}
+        self.loaded_by_program: Dict[str, Dict] = {}
+        # whether the cache served the compile in flight on this thread
+        self._pending = threading.local()
         self._warnings = 0
 
     # -- lifecycle ------------------------------------------------------
@@ -122,7 +132,11 @@ class XlaWatchdog:
 
     # -- listeners ------------------------------------------------------
     def _on_event(self, event: str, **kwargs) -> None:
-        if _is_compile_event(event):
+        if event == _CACHE_REQUEST:
+            self._pending.hit = False
+        elif event == _CACHE_HIT:
+            self._pending.hit = True
+        elif _is_compile_event(event):
             self._record_compile(event, 0.0, kwargs)
         elif _is_transfer_event(event):
             with self._lock:
@@ -132,23 +146,32 @@ class XlaWatchdog:
                     self.transfers_by_phase.get(phase, 0) + 1
 
     def _on_duration(self, event: str, duration: float, **kwargs) -> None:
-        if _is_compile_event(event):
+        if event == _CACHE_RETRIEVAL:
+            self._pending.hit = True
+        elif _is_compile_event(event):
             self._record_compile(event, float(duration), kwargs)
         elif _is_transfer_event(event):
             self._on_event(event)
 
     def _record_compile(self, event: str, duration: float,
                         kwargs: Optional[Dict] = None) -> None:
-        module = _module_of(kwargs) if kwargs else None
+        program = str((kwargs or {}).get("fun_name") or "unnamed")
+        loaded = getattr(self._pending, "hit", False)
+        self._pending.hit = False
         with self._lock:
             self.compiles += 1
             self.compile_secs += duration
+            if loaded:
+                self.loaded += 1
+                self.load_secs += duration
+                _add_program(self.loaded_by_program, program, duration)
+            else:
+                self.fresh += 1
+                self.fresh_secs += duration
+                _add_program(self.fresh_by_program, program, duration)
             phase = self._phase_getter() or "outside"
             self.compiles_by_phase[phase] = \
                 self.compiles_by_phase.get(phase, 0) + 1
-            if module is not None:
-                self.compiles_by_module[module] = \
-                    self.compiles_by_module.get(module, 0) + 1
             it = self.iteration
             steady = it is not None and it >= self.warmup
             if steady:
@@ -178,6 +201,11 @@ class XlaWatchdog:
                 "compile_secs": self.compile_secs,
                 "transfers": self.transfers,
                 "compiles_by_phase": dict(self.compiles_by_phase),
-                "compiles_by_module": dict(self.compiles_by_module),
                 "transfers_by_phase": dict(self.transfers_by_phase),
+                "fresh": self.fresh, "fresh_secs": self.fresh_secs,
+                "loaded": self.loaded, "load_secs": self.load_secs,
+                "fresh_by_program": {k: dict(v) for k, v in
+                                     self.fresh_by_program.items()},
+                "loaded_by_program": {k: dict(v) for k, v in
+                                      self.loaded_by_program.items()},
             }

@@ -55,15 +55,11 @@ def test_position_leaf_keeps_its_scope_through_the_tpu_compiler(one_chip, n):
     assert compiled.memory_analysis().temp_size_in_bytes <= 4 * 4 * n
 
 
-@pytest.mark.parametrize("L", [1024, 2048])
-def test_no_gain_lookup_per_pair_cell_survives_the_tpu_compiler(one_chip, L):
-    """``istella-s``'s two longest buckets (``[51, 1024]`` and ``[1, 2048]``:
-    the configuration's fixed multiset of query lengths), target ``ndcg``,
-    truncation 30, sigmoid 1, norm: the compiled bucket kernel holds no
-    gather of a pair block's size. A lookup of ``label_gain`` per pair cell
-    compiled to two stand-alone ``f32[53477376]`` gathers in the
-    1,024-bucket (19 % of the cell's iteration; PR 34); per document it is
-    one of ``nq x L``."""
+def _istella_bucket(one_chip, L):
+    """``istella-s``'s bucket of padded length ``L`` (the configuration's
+    fixed multiset of query lengths), target ``ndcg``, truncation 30,
+    sigmoid 1, norm: its query count and the bucket program compiled for a
+    v5e, as text."""
     from benchmark import manifest
     from benchmark.datagen.mslr_like import query_lengths
     cfg = manifest.load_json("configs", "istella-s.json")
@@ -71,7 +67,6 @@ def test_no_gain_lookup_per_pair_cell_survives_the_tpu_compiler(one_chip, L):
                           cfg["assumed"]["query_length_median"],
                           cfg["assumed"]["query_length_longest"])
     nq = int(np.sum((sizes > L // 2) & (sizes <= L)))
-    assert nq == {1024: 51, 2048: 1}[L]
     obj = cfg["objective_params"]
 
     def arg(shape, dtype=jnp.float32):
@@ -83,11 +78,39 @@ def test_no_gain_lookup_per_pair_cell_survives_the_tpu_compiler(one_chip, L):
         sigmoid=obj["sigmoid"], norm=obj["norm"],
         truncation_level=obj["truncation_level"], lambdagap_weight=1.0,
         tile=None).compile().as_text()
+    return nq, text
+
+
+@pytest.mark.parametrize("L", [1024, 2048])
+def test_no_gain_lookup_per_pair_cell_survives_the_tpu_compiler(one_chip, L):
+    """``istella-s``'s two longest buckets (``[51, 1024]`` and ``[1, 2048]``):
+    the compiled bucket kernel holds no gather of a pair block's size. A
+    lookup of ``label_gain`` per pair cell compiled to two stand-alone
+    ``f32[53477376]`` gathers in the 1,024-bucket (19 % of the cell's
+    iteration); per document it is one of ``nq x L``."""
+    nq, text = _istella_bucket(one_chip, L)
+    assert nq == {1024: 51, 2048: 1}[L]
     gathers = [math.prod(int(d) for d in m.group(1).split(",") if d)
                for m in re.finditer(r"= \w+\[([\d,]*)\]\S* gather\(", text)]
     # what is left: the sort's own gathers and the per-document lookup (the
     # one-query bucket's XLA turns into selects by itself), nq x L each
     assert gathers and max(gathers) <= nq * L < L * L, gathers
+
+
+@pytest.mark.parametrize("L", [128, 2048])
+def test_the_bucket_programs_document_arrays_keep_their_rank_scopes(
+        one_chip, L):
+    """Every instruction of the compiled bucket program that moves ``[nq,
+    L]`` elements or more carries one of the ``rank_*`` scopes it was
+    traced under: ``rank_sort_device_ms`` reads the sorts, their gathers
+    and the way back by the name ``rank_sort``, and an instruction the
+    compiler made without a name would fall to no metric. 128 is the
+    bucket with the most documents (many short queries), 2,048 the one
+    with a single query."""
+    nq, text = _istella_bucket(one_chip, L)
+    assert nq > {128: 5000, 2048: 0}[L]
+    assert re.search(r"sort\(.*/rank_sort/", text)
+    assert _unnamed(text, "rank_", nq * L) == []
 
 
 def _instructions(text):
@@ -115,10 +138,11 @@ def _unnamed(text, scope, least):
         m = re.search(r"= \(?\w+\[([\d,]+)\]", line)
         if not m or FREE.search(line) \
                 or re.search(r" (copy-start|copy-done|slice-start|"
-                             r"slice-done|while|iota)\(|ConcatBitcast",
+                             r"slice-done|while|iota|tuple)\(|ConcatBitcast",
                              line):
             # (the compiler's own prefetches between memory spaces, the
-            # loop itself: nothing a selector would attribute)
+            # loop itself, a tuple of results: nothing a selector would
+            # attribute)
             continue
         if _elements(m.group(1)) >= least and f"/{scope}" not in line:
             bad.append(line.strip()[:140])

@@ -1,7 +1,7 @@
-"""Streaming Sequence construction, cv details, timers, plotting.
+"""Streaming Sequence construction, cv details, plotting.
 
 (reference: basic.py:903 Sequence + test_basic.py:139-234 Sequence cases;
-engine.py cv; USE_TIMETAG timer table; plotting.py)
+engine.py cv; plotting.py)
 """
 import numpy as np
 import pytest
@@ -64,18 +64,6 @@ def test_cv_early_stopping_uses_first_metric():
     # converged training stops early and truncates consistently
     lens = {len(v) for v in res.values()}
     assert len(lens) == 1
-
-
-def test_timer_report(monkeypatch):
-    from lambdagap_tpu.utils import timer as T
-    monkeypatch.setattr(T, "_ENABLED", True)
-    T.global_timer.reset()
-    X, y = _data(n=300)
-    lgb.train({"objective": "regression", "num_leaves": 7, "verbose": -1},
-              lgb.Dataset(X, label=y), num_boost_round=3)
-    rep = T.global_timer.report()
-    assert "tree:" in rep and "boosting: gradients" in rep
-    T.global_timer.reset()
 
 
 def test_plot_importance_without_display():

@@ -1,5 +1,6 @@
 """lambdagap_tpu.obs (graftscope): phase spans, ring buffer, JSONL schema,
-recompile watchdog, Prometheus export, serve `stats` line, timer shim.
+recompile watchdog (fresh compiles and cache loads), Prometheus export,
+serve `stats` line.
 
 The ISSUE-4 acceptance surface: per-iteration phase spans must tile the
 measured iteration wall (±10%), the emitted JSONL must validate against
@@ -370,31 +371,151 @@ def test_serve_loop_stats_lines():
     assert len(out.getvalue().strip().splitlines()) == 2
 
 
-# -- utils.timer shim (use-time enablement) -----------------------------
-def test_timer_enablement_is_use_time(monkeypatch):
-    from lambdagap_tpu.utils import timer as T
-    monkeypatch.delenv("LAMBDAGAP_TIMETAG", raising=False)
-    monkeypatch.setattr(T, "_ENABLED", False)
-    assert not T.timer_enabled()
-    # flipping the env var AFTER import takes effect immediately
-    monkeypatch.setenv("LAMBDAGAP_TIMETAG", "1")
-    assert T.timer_enabled()
-    T.global_timer.reset()
-    with T.global_timer.scope("probe"):
-        pass
-    assert T.global_timer.counts["probe"] == 1
-    T.global_timer.reset()
+# -- the watchdog's fresh compiles and cache loads -----------------------
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """A persistent compile cache of this test's own that keeps every
+    program, put back as it was afterwards."""
+    import jax
+    from jax._src import compilation_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        compilation_cache.reset_cache()
 
 
-def test_timer_shim_receives_telemetry_phases(monkeypatch):
-    from lambdagap_tpu.utils import timer as T
-    monkeypatch.setattr(T, "_ENABLED", True)
-    T.global_timer.reset()
-    _train(rounds=3)
-    rep = T.global_timer.report()
-    # legacy scope names survive via the deprecation shim
-    assert "tree:" in rep and "boosting: gradients" in rep
-    T.global_timer.reset()
+def test_the_watchdog_tells_a_fresh_compile_from_a_cache_load(
+        persistent_cache):
+    import jax
+    import jax.numpy as jnp
+    from lambdagap_tpu.obs.xla_watch import XlaWatchdog
+
+    def watched_probe(x):
+        return jnp.sin(x) * 3.0 + 1.0
+
+    x = jnp.arange(7.0)
+    dog = XlaWatchdog()
+    dog.install()
+    try:
+        jax.jit(watched_probe)(x).block_until_ready()
+        first = dog.totals()
+        jax.clear_caches()            # the next call asks the disk cache
+        jax.jit(watched_probe)(x).block_until_ready()
+    finally:
+        dog.uninstall()
+    t = dog.totals()
+    name = "jit(watched_probe)"
+    assert first["fresh_by_program"][name]["n"] == 1
+    assert name not in first["loaded_by_program"]
+    assert t["fresh_by_program"][name]["n"] == 1
+    assert t["loaded_by_program"][name]["n"] == 1
+    assert t["fresh"] + t["loaded"] == t["compiles"]
+    assert t["fresh_secs"] + t["load_secs"] == pytest.approx(
+        t["compile_secs"], rel=1e-12)
+    for kind in ("fresh", "loaded"):
+        by = t[kind + "_by_program"]
+        assert sum(v["n"] for v in by.values()) == t[kind]
+
+
+def test_a_cache_hit_belongs_to_the_thread_that_asked():
+    """The cache's events and the compile they belong to fire on one
+    thread; a compile on another thread in between stays fresh."""
+    import threading
+    from lambdagap_tpu.obs import xla_watch as xw
+    dog = xw.XlaWatchdog()
+    compile_event = "/jax/core/compile/backend_compile_duration"
+    dog._on_event(xw._CACHE_REQUEST)
+    dog._on_event(xw._CACHE_HIT)
+    other = threading.Thread(target=dog._on_duration,
+                             args=(compile_event, 2.0),
+                             kwargs={"fun_name": "jit(elsewhere)"})
+    other.start()
+    other.join()
+    dog._on_duration(xw._CACHE_RETRIEVAL, 0.25)
+    dog._on_duration(compile_event, 0.5, fun_name="jit(here)")
+    dog._on_duration(compile_event, 1.0, fun_name="jit(here)")
+    t = dog.totals()
+    assert (t["fresh"], t["loaded"], t["compiles"]) == (2, 1, 3)
+    assert (t["fresh_secs"], t["load_secs"], t["compile_secs"]) == \
+        (3.0, 0.5, 3.5)
+    assert t["fresh_by_program"] == {"jit(elsewhere)": {"n": 1, "secs": 2.0},
+                                     "jit(here)": {"n": 1, "secs": 1.0}}
+    assert t["loaded_by_program"] == {"jit(here)": {"n": 1, "secs": 0.5}}
+
+
+def test_the_record_carries_the_runs_compiles(tmp_path):
+    """Each record's ``compiles.run`` is what came before the first record
+    plus the records' own deltas up to it, and the log validates."""
+    out = str(tmp_path / "run.jsonl")
+    b = _train({"telemetry_out": out}, rounds=4)
+    recs = list(b._booster.telemetry.records)
+    assert [r["iter"] for r in recs] == [0, 1, 2, 3]
+    ints = ("total", "fresh", "loaded")
+    secs = (("secs", "secs"), ("fresh_secs", "fresh_secs"),
+            ("load_secs", "load_secs"))
+    first = recs[0]["compiles"]
+    before = {k: first["run"][k] - first[k] for k in ints}
+    before.update({k: first["run"][k] - first[d] for k, d in secs})
+    assert first["total"] > 0 and first["fresh"] > 0
+    for k in range(len(recs)):
+        c = recs[k]["compiles"]
+        run = c["run"]
+        for f in ints:
+            assert run[f] == before[f] + sum(
+                r["compiles"][f] for r in recs[:k + 1])
+        for f, d in secs:
+            assert run[f] == pytest.approx(before[f] + sum(
+                r["compiles"][d] for r in recs[:k + 1]), abs=1e-5)
+        assert c["fresh"] + c["loaded"] == c["total"]
+        assert run["fresh"] + run["loaded"] == run["total"]
+        assert run["fresh_secs"] + run["load_secs"] == pytest.approx(
+            run["secs"], abs=1e-5)
+        for kind, n in (("fresh", run["fresh"]), ("loaded", run["loaded"])):
+            assert sum(v["n"] for v in run[kind + "_by_program"].values()) \
+                == n
+    assert events.validate_file(out) == []
+
+
+def test_the_benchmark_reads_set_ups_compiles_from_the_windows_records():
+    """What the benchmark's training window does: warm up, note the
+    watchdog's totals,
+    run the window, hand the readers the window's records alone. The two
+    set-up metrics read those totals, split into fresh and loaded."""
+    from benchmark.readers import program_record
+    X, y = _data()
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1,
+              "telemetry": True}
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y))
+    tel = bst._booster.telemetry
+    for _ in range(2):
+        bst.update()
+    setup = tel.watchdog.totals()
+    for _ in range(2):
+        bst.update()
+    tel.close()
+    window = list(tel.records)[-2:]
+    got = {f: program_record.read({"reduction": "before_window", "field": f},
+                                  {"records": window})
+           for f in ("fresh_secs", "load_secs")}
+    assert setup["compiles"] > 0
+    assert got["fresh_secs"] == pytest.approx(setup["fresh_secs"], abs=1e-5)
+    assert got["load_secs"] == pytest.approx(setup["load_secs"], abs=1e-5)
+    assert got["fresh_secs"] + got["load_secs"] == pytest.approx(
+        setup["compile_secs"], abs=1e-5)
+
+
+# the reader's own cases (benchmark/tests, outside tier-1) run here too
+from benchmark.tests.test_program_record import *  # noqa: E402,F401,F403
 
 
 # -- shared reservoir ---------------------------------------------------
