@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..config import Config
 from ..obs.telemetry import device_scope
@@ -361,6 +362,30 @@ class LambdarankNDCG(RankingBase):
 _DENSE_PAIR_L = 4096
 
 
+def _score_order(scores, labels, valid):
+    """A ``[nq, L]`` bucket in score order by one stable sort along the
+    document axis, the data riding with the key (``jnp.argsort``'s order,
+    ties included): order, scores, labels, validity, valid count, best and
+    worst valid score."""
+    neg = jnp.where(valid, scores, K_MIN_SCORE)
+    pos = lax.broadcasted_iota(jnp.int32, neg.shape, 1)
+    key, order, ls, vs = lax.sort((-neg, pos, labels, valid), dimension=1,
+                                  num_keys=1, is_stable=True)
+    ss = -key               # negation is exact: no score operand to carry
+    nv = jnp.sum(vs, axis=1)
+    # ss[max(nv - 1, 0)] by a select and a max, not a gather a query
+    worst = jnp.max(jnp.where(pos == jnp.maximum(nv - 1, 0)[:, None], ss,
+                              -jnp.inf), axis=1)
+    return order, ss, ls.astype(jnp.float32), vs, nv, ss[:, 0], worst
+
+
+def _document_order(order, lam_sorted, hes_sorted):
+    """Back to document order: ``order`` is a permutation of the positions."""
+    _, lam, hes = lax.sort((order, lam_sorted, hes_sorted), dimension=1,
+                           num_keys=1)
+    return lam, hes
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("target", "sigmoid", "norm", "truncation_level",
@@ -378,7 +403,6 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
     the row axis is swept in blocks of T under the same window masks —
     peak memory O(L*T), identical arithmetic per pair — so arbitrarily
     long queries stay exact."""
-    from jax import lax
     tl = truncation_level
     if tile is not None and scores.shape[1] % tile != 0:
         # lax.dynamic_slice clamps out-of-range starts, so a non-divisor
@@ -561,32 +585,16 @@ def _lambdarank_bucket(scores, labels, valid, inv_dcg, inv_bdcg, label_gain,
             jnp.maximum(nvf * (nvf - 1.0), 1.0)
         return lam_sorted, hes_sorted, eff
 
-    def sort_query(s, l, v):
-        neg = jnp.where(v, s, K_MIN_SCORE)
-        order = jnp.argsort(-neg)              # stable: ranks by score desc
-        ss = neg[order]
-        ls = l[order].astype(jnp.float32)
-        vs = v[order]
-        nv = jnp.sum(vs)
-        best = ss[0]
-        worst = ss[jnp.maximum(nv - 1, 0)]
-        return order, ss, ls, vs, nv, best, worst
-
-    def unsort_query(order, lam_sorted, hes_sorted):
-        inv = jnp.argsort(order)               # back to document order
-        return lam_sorted[inv], hes_sorted[inv]
-
-    # one vmap a stage, each opened UNDER its scope: a scope opened inside a
-    # vmapped function is named ``vmap(<scope>)`` and no selector on a path
-    # component finds it
+    # the lattice's vmap is opened UNDER its scope: a scope opened inside a
+    # vmapped function is named ``vmap(<scope>)`` and no selector finds it
     with device_scope("rank_sort"):
-        order, ss, ls, vs, nv, best, worst = jax.vmap(sort_query)(
-            scores, labels, valid)
+        order, ss, ls, vs, nv, best, worst = _score_order(scores, labels,
+                                                          valid)
     with device_scope("rank_lattice"):
         lam_sorted, hes_sorted, eff = jax.vmap(lattice)(
             ss, ls, vs, nv, inv_dcg, inv_bdcg, best, worst)
     with device_scope("rank_sort"):
-        lam, hes = jax.vmap(unsort_query)(order, lam_sorted, hes_sorted)
+        lam, hes = _document_order(order, lam_sorted, hes_sorted)
     return lam, hes, eff
 
 

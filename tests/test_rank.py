@@ -214,3 +214,104 @@ def test_long_query_trains_without_truncation():
     # the whole query, not just a truncated prefix)
     top = np.argsort(-s)[:50]
     assert y[top].mean() > 0.5
+
+
+def _argsort_and_gather(scores, labels, valid):
+    """A bucket into score order as the kernel used to put it: an argsort a
+    query and three gathers of ``[nq, L]`` by its indices."""
+    import jax
+    import jax.numpy as jnp
+    from lambdagap_tpu.objectives.rank import K_MIN_SCORE
+
+    def sort_query(s, l, v):
+        neg = jnp.where(v, s, K_MIN_SCORE)
+        order = jnp.argsort(-neg)
+        ss = neg[order]
+        vs = v[order]
+        nv = jnp.sum(vs)
+        return (order, ss, l[order].astype(jnp.float32), vs, nv, ss[0],
+                ss[jnp.maximum(nv - 1, 0)])
+    return jax.vmap(sort_query)(scores, labels, valid)
+
+
+def _gather_back(order, lam_sorted, hes_sorted):
+    """Its way back: the inverse permutation by a second argsort, and two
+    more gathers."""
+    import jax
+    import jax.numpy as jnp
+
+    def unsort_query(order, lam, hes):
+        inv = jnp.argsort(order)
+        return lam[inv], hes[inv]
+    return jax.vmap(unsort_query)(order, lam_sorted, hes_sorted)
+
+
+def _tied_bucket(kind, L=128):
+    """A ``[nq, L]`` bucket (scores, labels, valid, inv_dcg, inv_bdcg,
+    label_gain) with tied scores, ``-0.0`` beside ``+0.0``, invalid pads
+    with stray scores; ``queries`` also holds a query of no valid document,
+    one of one, one with holes and one whose valid scores all tie."""
+    rng = np.random.RandomState(11)
+    nq = 1 if kind == "one-query" else 6
+    scores = (rng.randint(-3, 4, (nq, L)) * 0.5).astype(np.float32)
+    zero = scores == 0
+    scores[zero] = np.where(rng.rand(int(zero.sum())) < 0.5, -0.0, 0.0)
+    labels = rng.randint(0, 5, (nq, L)).astype(np.float32)
+    lengths = [L - 5] if nq == 1 else [L, 100, 0, 1, 77, 50]
+    valid = np.arange(L)[None, :] < np.asarray(lengths)[:, None]
+    scores[:, 3] = -7.0          # a worst valid score with no tie
+    if nq > 1:
+        valid[4, rng.rand(L) < 0.3] = False
+        scores[5] = 0.25
+    return (scores, labels, valid, rng.rand(nq).astype(np.float32),
+            rng.rand(nq).astype(np.float32),
+            (2.0 ** np.arange(5) - 1).astype(np.float32))
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint32) if a.dtype == np.float32 else a
+
+
+@pytest.mark.parametrize("bucket", ["queries", "one-query"])
+def test_score_order_is_argsort_and_gather_to_the_bit(bucket):
+    """The forward sort's seven outputs (the permutation, the score-ordered
+    scores, labels and validity, the valid count, the best and the worst
+    valid score) are the argsort-and-gather ones, bit for bit: the sort is
+    stable on (-score, position), as ``jnp.argsort`` is."""
+    from lambdagap_tpu.objectives.rank import _score_order
+    args = _tied_bucket(bucket)[:3]
+    got, want = _score_order(*args), _argsort_and_gather(*args)
+    assert len(got) == len(want) == 7
+    for g, w in zip(got, want):
+        assert np.array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("bucket", ["queries", "one-query"])
+@pytest.mark.parametrize("form", ["dense", "tiled"])
+@pytest.mark.parametrize("target", ["ndcg", "lambdagap-s",
+                                    "lambdagap-x-plus-plus", "precision",
+                                    "lambdaloss-arp2"])
+def test_sorting_the_data_with_its_key_keeps_every_lambda_to_the_bit(
+        target, form, bucket, monkeypatch):
+    """``_lambdarank_bucket``'s lambdas, hessians and effective pair rates
+    equal, bit for bit, those of the same kernel with its two sorts swapped
+    back for argsort-and-gather: only the op that moves the data changed."""
+    import types
+    import jax
+    from lambdagap_tpu.objectives import rank
+    args = _tied_bucket(bucket)
+    kw = dict(target=target, sigmoid=1.0, norm=True, truncation_level=20,
+              lambdagap_weight=0.5, tile=None if form == "dense" else 32)
+    got = rank._lambdarank_bucket(*args, **kw)
+    monkeypatch.setattr(rank, "_score_order", _argsort_and_gather)
+    monkeypatch.setattr(rank, "_document_order", _gather_back)
+    # a new function object: jit keeps a function's traces by its identity,
+    # and the kernel's own trace would read the swapped stages from none
+    kernel = rank._lambdarank_bucket.__wrapped__
+    old = types.FunctionType(kernel.__code__, kernel.__globals__)
+    old.__kwdefaults__ = kernel.__kwdefaults__
+    want = jax.jit(old, static_argnames=tuple(kw))(*args, **kw)
+    assert np.any(np.asarray(got[0]) != 0)
+    for g, w in zip(got, want):
+        assert np.array_equal(_bits(g), _bits(w))

@@ -364,6 +364,8 @@ def test_the_ranking_gradient_program_is_tiled_by_its_inner_scopes():
     assert kinds["rank_scatter"]["stablehlo.scatter"] == 2 * 5   # 5 buckets
     assert kinds["rank_gather"]["stablehlo.gather"] == 2 * 5
     assert kinds["rank_sort"]["stablehlo.sort"] == 2 * 5
+    # each sort carries its data with the key: nothing is gathered after
+    assert kinds["rank_sort"]["stablehlo.gather"] == 0
     assert not kinds["rank_lattice"]["stablehlo.sort"] \
         and not kinds["rank_lattice"]["stablehlo.scatter"]
 
