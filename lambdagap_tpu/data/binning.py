@@ -54,12 +54,17 @@ def _greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray,
     bounds: List[float] = []
     if num_distinct == 0:
         return [np.inf]
+    # the loops below read one element at a time: Python scalars from lists
+    # take a tenth of numpy's per-element indexing (the values are the same
+    # float64s and int64s), and this loop runs twice per feature
+    values = np.asarray(distinct_values, np.float64).tolist()
+    cnts = np.asarray(counts, np.int64).tolist()
     if num_distinct <= max_bin:
         cur_cnt = 0
         for i in range(num_distinct - 1):
-            cur_cnt += counts[i]
+            cur_cnt += cnts[i]
             if cur_cnt >= min_data_in_bin:
-                val = float(np.nextafter((distinct_values[i] + distinct_values[i + 1]) / 2.0,
+                val = float(np.nextafter((values[i] + values[i + 1]) / 2.0,
                                          np.inf))
                 if not bounds or val > bounds[-1]:
                     bounds.append(val)
@@ -69,22 +74,23 @@ def _greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray,
     if min_data_in_bin > 0:
         max_bin = max(1, min(max_bin, int(total_cnt // min_data_in_bin)))
     mean_bin_size = total_cnt / max_bin
-    is_big = counts >= mean_bin_size
-    rest_bin_cnt = max_bin - int(is_big.sum())
-    rest_sample_cnt = int(total_cnt - counts[is_big].sum())
+    big = counts >= mean_bin_size
+    rest_bin_cnt = max_bin - int(big.sum())
+    rest_sample_cnt = int(total_cnt - counts[big].sum())
     mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
+    is_big = big.tolist()
 
     upper: List[float] = []
-    lower: List[float] = [float(distinct_values[0])]
+    lower: List[float] = [values[0]]
     cur_cnt = 0
     for i in range(num_distinct - 1):
         if not is_big[i]:
-            rest_sample_cnt -= counts[i]
-        cur_cnt += counts[i]
+            rest_sample_cnt -= cnts[i]
+        cur_cnt += cnts[i]
         if (is_big[i] or cur_cnt >= mean_bin_size
                 or (is_big[i + 1] and cur_cnt >= max(1.0, mean_bin_size * 0.5))):
-            upper.append(float(distinct_values[i]))
-            lower.append(float(distinct_values[i + 1]))
+            upper.append(values[i])
+            lower.append(values[i + 1])
             if len(upper) >= max_bin - 1:
                 break
             cur_cnt = 0

@@ -113,6 +113,13 @@ def build_bundle(binned: np.ndarray, feature_bins: np.ndarray,
     N, F = binned.shape
     if F < 2:
         return None
+    # a bundle of two holds 1 + (b1 - 1) + (b2 - 1) bins: when even the two
+    # narrowest features overflow MAX_BUNDLE_BINS no bundle can form, and the
+    # conflict search over the sample would only find so (dense 255-bin
+    # columns: 1.7 s of 2,000 x 100,000 compares to bundle nothing)
+    narrow = np.sort(np.asarray(feature_bins, np.int64))[:2]
+    if int(narrow.sum()) - 1 > MAX_BUNDLE_BINS:
+        return None
     S = min(N, sample_cnt)
     step = max(N // S, 1)
     sample = binned[::step][:S]

@@ -459,8 +459,11 @@ class FusedTreeLearner(SerialTreeLearner):
         """The telemetry's per-tree work counts from ``(DeviceTree.work,
         num_leaves)`` fetched to the host: realised splits, the rows and
         ``while`` trips of the partition passes, and of the histogram
-        passes (the root's plus the smaller child's of every split).
-        Python ints: 10.5M rows x 254 splits do not fit int32."""
+        passes (the root's plus the smaller child's of every split) and,
+        under the Pallas kernel, ``hist_row_tiles``: each window's kernel
+        call's padded rows times its feature tiles, summed (a learner that
+        shards columns leaves it out). Python ints: 10.5M rows x 254 splits
+        do not fit int32."""
         work, num_leaves = host
         n = int(getattr(self, "n_loc", self.num_data))   # this shard's rows
         w = self._window(n)
@@ -469,11 +472,17 @@ class FusedTreeLearner(SerialTreeLearner):
 
         def trips(rows):
             return int(((rows + w - 1) // w).sum())
-        return {"splits": splits,
-                "partition_rows": int(part.sum()),
-                "partition_trips": trips(part),
-                "hist_rows": n + int(small.sum()),
-                "hist_trips": -(-n // w) + trips(small)}
+        out = {"splits": splits,
+               "partition_rows": int(part.sum()),
+               "partition_trips": trips(part),
+               "hist_rows": n + int(small.sum()),
+               "hist_trips": -(-n // w) + trips(small)}
+        if self.hist_impl == "pallas" \
+                and getattr(self, "feat_axis", None) is None:
+            from ..ops.hist_pallas import row_tiles
+            out["hist_row_tiles"] = out["hist_trips"] * row_tiles(
+                w, int(self.hx_rows.shape[1]), self.Bb)
+        return out
 
     def _tree_from_host(self, h) -> Tree:
         L = int(h["num_leaves"])
@@ -584,7 +593,7 @@ class FusedTreeLearner(SerialTreeLearner):
             packed_rows = None          # rows live in the carried srows
             SW = srows.shape[0]         # word-major: [SW, N + W]
         else:
-            with _scope("tree_init"):
+            with _scope("tree_init"), _scope("pack_rows"):
                 packed_rows = self._pack_rows(grad, hess, row_mask, x_rows,
                                               gq, hq, has_mask)
 
@@ -624,7 +633,8 @@ class FusedTreeLearner(SerialTreeLearner):
                 prow = unpack(srow_slice(srows_c, begin + c * W).T)
             else:
                 rows = perm_slice(perm, begin + c * W)
-                prow = unpack(packed_rows[rows])    # [W, C(+gh+mask)]
+                with _scope("hist_gather"):
+                    prow = unpack(packed_rows[rows])    # [W, C(+gh+mask)]
             valid = (c * W + lane) < count
             bins = prow[:, :C]
             if quant:
@@ -641,9 +651,10 @@ class FusedTreeLearner(SerialTreeLearner):
                     if mask_col:
                         valid = valid & (prow[:, C + q_cols] > 0)
                 else:
-                    gq_w, hq_w = gq[rows], hq[rows]
-                    if has_mask:
-                        valid = valid & row_mask[rows]
+                    with _scope("hist_gather"):
+                        gq_w, hq_w = gq[rows], hq[rows]
+                        if has_mask:
+                            valid = valid & row_mask[rows]
                 qscale = jnp.stack([gs, hs, jnp.float32(1.0)])
                 if self.hist_impl == "pallas":
                     from ..ops.hist_pallas import hist_pallas_q, pack_ghq8

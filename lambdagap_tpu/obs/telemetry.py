@@ -82,6 +82,15 @@ DEVICE_SCOPES = ("histogram", "partition", "partition_decide",
 # ``gradients`` and at most one of these (tests/test_scopes.py).
 GRADIENT_SCOPES = ("rank_gather", "rank_sort", "rank_lattice", "rank_scatter")
 
+# inner scopes of the tree program under ``tree_layout=gather`` (a tuple of
+# their own, like GRADIENT_SCOPES): ``pack_rows`` is the per-tree repack of
+# the binned rows with their gradients (``FusedTreeLearner._pack_rows``),
+# opened INSIDE ``tree_init``; ``hist_gather`` is a histogram window's row
+# gather from that matrix, opened INSIDE ``histogram``. A selector on the
+# outer scope reads them with the rest of it; the sorted layout opens
+# neither.
+TREE_INNER_SCOPES = ("pack_rows", "hist_gather")
+
 # scopes of the evaluation programs, which run only with a validation set
 # (or a training metric) attached: ``valid_score`` is the routing of a set's
 # binned rows through the iteration's tree and the add into its scores
@@ -100,11 +109,12 @@ PHASE_ANNOTATION = "lg_phase:"
 
 def device_scope(name: str):
     """``jax.named_scope(name)`` for a name of DEVICE_SCOPES,
-    GRADIENT_SCOPES or EVAL_SCOPES (and only those: the vocabulary is
-    closed, a typo fails at trace time)."""
-    if name not in DEVICE_SCOPES + GRADIENT_SCOPES + EVAL_SCOPES:
+    GRADIENT_SCOPES, TREE_INNER_SCOPES or EVAL_SCOPES (and only those: the
+    vocabulary is closed, a typo fails at trace time)."""
+    if name not in (DEVICE_SCOPES + GRADIENT_SCOPES + TREE_INNER_SCOPES
+                    + EVAL_SCOPES):
         raise ValueError(f"{name!r} is not in obs.telemetry.DEVICE_SCOPES, "
-                         "GRADIENT_SCOPES or EVAL_SCOPES")
+                         "GRADIENT_SCOPES, TREE_INNER_SCOPES or EVAL_SCOPES")
     import jax
     return jax.named_scope(name)
 

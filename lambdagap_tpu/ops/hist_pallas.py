@@ -131,6 +131,14 @@ def _pick_blocks(F: int, B: int, P: int):
     return blk, fblk
 
 
+def row_tiles(P: int, F: int, B: int) -> int:
+    """What one kernel call over ``[P, F]`` rows of ``B`` bins works
+    through: its padded rows times its feature tiles (``Fp / fblk``), the
+    telemetry's ``hist_row_tiles`` per call (``_hist_call`` pads both)."""
+    blk, fblk = _pick_blocks(F, B, P)
+    return -(-P // blk) * blk * -(-F // fblk)
+
+
 def _vmem_limit(blk: int, fblk: int, B: int, bins_itemsize: int) -> int:
     """Scoped-VMEM request from the block arithmetic: the accumulator, the
     double-buffered output/bins/channel blocks, and the worst case the

@@ -340,3 +340,54 @@ def test_the_split_loop_writes_the_childrens_histograms_in_place(
     ``%copy.307`` at 28). ``ops.histogram.write_children`` reads the parent
     once, before either write."""
     assert _whole_copies(tree_program["text"], tree_program["hist"]) == []
+
+
+def test_the_gather_tree_program_at_2000_features_writes_its_state_in_place(
+        one_chip):
+    """``epsilon-train``'s tree program (400,000 x 2,000 u8 rows, W 4,096,
+    255 leaves, the Pallas kernel, ``tree_layout=gather``; a learner built on
+    4,096 rows and lowered at the cell's N): the kernel runs 16 feature
+    tiles a window, and outside ``ENTRY`` no copy or transpose produces the
+    1.57 GB histogram state ``[256, 2000, 256, 3]`` or the packed rows
+    ``[400000, 502]``: the split loop writes both children in place at this
+    size too, and gathers a window's rows without a copy of the matrix."""
+    import lambdagap_tpu as lgb
+    from lambdagap_tpu.ops import hist_pallas
+    features, n = 2000, 400_000
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        mp.setattr(hist_pallas, "_interpret", lambda: False)
+        X = np.random.default_rng(0).normal(size=(4096, features)).astype(
+            np.float32)
+        params = {"objective": "binary", "num_leaves": 255, "max_bin": 255,
+                  "min_data_in_leaf": 1, "tpu_fused_learner": 1,
+                  "tpu_hist_impl": "pallas", "verbose": -1}
+        learner = lgb.Booster(params, lgb.Dataset(
+            X, label=(X[:, 0] > 0).astype(np.float32),
+            params=params))._booster.learner
+        assert (learner.layout, learner.hist_impl, learner.Bb) \
+            == ("gather", "pallas", 256)
+        learner.num_data = n
+        learner.chunk = learner._pick_chunk()
+        assert learner.chunk == 4096
+
+        def arg(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        q = arg((1,), jnp.int8)
+        compiled = learner._train_jit.lower(
+            arg((n,)), arg((n,)), arg((1,), jnp.bool_),
+            arg((features,), jnp.bool_), arg((n, features), jnp.uint8),
+            arg((features, n), jnp.uint8), arg((1, 1), jnp.uint32), q, q,
+            arg(()), arg(()), arg((2, 2), jnp.uint32),
+            has_mask=False).compile()
+    text = compiled.as_text()
+    assert hist_pallas.row_tiles(4096, features, 256) == 4096 * 16
+    assert "lg_hist" in text
+    hist = 256 * features * 256 * 3
+    assert _whole_copies(text, hist) == []
+    assert _whole_copies(text, n * (features + 8) // 4) == []
+    # live: the two binned matrices; temporaries: the state, the packed
+    # rows and the repack's widening (PERF.md section 7, 25 iii)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 1.7e9
+    assert mem.temp_size_in_bytes < 7e9
